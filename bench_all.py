@@ -428,8 +428,8 @@ def config4():
     # per-reconcile host work — the O(cands x nodes) compat matrix — puts
     # convergence past the budget).  The 5k story is still covered: the
     # device screen above runs at 5k, repack_reconcile_5k measures one full
-    # consolidation evaluation at 5k (the per-reconcile latency VERDICT r3
-    # asked for), and the from-scratch oracle pack bounds the achievable $.
+    # consolidation evaluation at 5k, and the from-scratch oracle pack
+    # bounds the achievable $.
     # Partial results stream to stderr so a deadline kill keeps what landed.
     import os
     import sys
@@ -571,23 +571,18 @@ def main():
     picked = [int(x) for x in args.configs.split(",") if x.strip()]
     import os
 
-    from bench import LAST_PROBE, arm_watchdog, ensure_backend
+    from bench import arm_watchdog, require_tpu
 
     arm_watchdog(float(os.environ.get("BENCH_DEADLINE_S", "3000")),
                  metric="bench_all_sweep")
-    ensure_backend()
+    # no TPU -> non-zero exit before any config runs (bench.require_tpu);
+    # every line names the device it was measured on
+    device = require_tpu()
     for n in picked:
-        try:
-            rec = CONFIGS[n]()
-        except Exception as e:  # one bad config must not kill the sweep
-            rec = {"metric": f"c{n}", "value": None, "unit": "ms",
-                   "vs_baseline": None, "error": f"{type(e).__name__}: {e}"[:500]}
-        # whether the one-per-sweep backend probe came from the PR-5
-        # verdict cache (the BENCH r05 cold-start-tax fix) — surfaced on
-        # every config line so tail parsers see it wherever they cut
-        rec = {"config": n, **rec,
-               "probe_cached": LAST_PROBE.get("cached")}
-        print(json.dumps(rec), flush=True)
+        # a config that raises ends the sweep with its traceback and a
+        # non-zero exit: the lines already printed stand, nothing is
+        # papered over with a value-less record
+        print(json.dumps({"config": n, **CONFIGS[n](), **device}), flush=True)
 
 
 if __name__ == "__main__":

@@ -152,7 +152,7 @@ def check(files) -> List[Finding]:
                 continue
             # ---- (2) unpacked float32 feasibility tensor ---------------
             # feasibility is named either in the expression itself
-            # (``_host_feasibility(st).astype(np.float32)``) or on the
+            # (``host_feasibility(st).astype(np.float32)``) or on the
             # assignment target (``feas = np.zeros(..., dtype=float32)``)
             feasy = _mentions_feas(n)
             if not feasy:

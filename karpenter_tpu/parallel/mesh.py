@@ -75,16 +75,15 @@ def make_mesh(n_devices: Optional[int] = None) -> Mesh:
     host's chips (ICI) — see ``_host_major``.
     """
     devices = jax.devices()
-    if n_devices is not None and len(devices) < n_devices:
-        # fall back to the (possibly virtualized) CPU platform — used by the
-        # multi-chip dryrun where real chips aren't available
-        try:
-            cpus = jax.devices("cpu")
-        except RuntimeError:
-            cpus = []
-        if len(cpus) >= n_devices:
-            devices = cpus
     if n_devices is not None:
+        if len(devices) < n_devices:
+            # never substitute another platform's devices: a mesh that was
+            # asked for on the chip and built on the host would measure the
+            # host.  Dryruns and tests pin JAX_PLATFORMS=cpu with
+            # --xla_force_host_platform_device_count and ask for those.
+            raise ValueError(
+                f"make_mesh({n_devices}): the default platform "
+                f"{devices[0].platform!r} has only {len(devices)} device(s)")
         devices = devices[:n_devices]
     return Mesh(_host_major(devices), (POD_AXIS, TYPE_AXIS))
 

@@ -857,6 +857,17 @@ class RemoteScheduler:
         self.client.close()
 
 
+def hydrate_node(node: SimNode, it_by_name: Dict[str, InstanceType]) -> SimNode:
+    """Re-hydrate a node decoded off the wire from the caller's catalog: the
+    wire's NewNode is placement-only (type/zone/ct/price/pod names), but
+    callers — and the ground-truth validator — read allocatable and labels."""
+    it = it_by_name.get(node.instance_type)
+    if it is not None and not node.allocatable:
+        node.allocatable = dict(it.allocatable)
+    node.stamp_labels()
+    return node
+
+
 class DeltaSession:
     """Session-stateful delta client over the Solve RPC — warm start over
     the wire (docs/ARCHITECTURE.md round 14).
@@ -1156,6 +1167,12 @@ class DeltaSession:
             solve_ms=self._last_ms,
         )
 
+    def pods(self) -> List[PodSpec]:
+        """The pod set the session currently offers the solver (the
+        established batch plus every acknowledged add, minus removals) —
+        what :meth:`result` is a solution OF."""
+        return list((self._pods or {}).values())
+
     def close(self) -> None:
         self.client.close()
 
@@ -1286,16 +1303,10 @@ class DeltaSession:
 
     def _attach(self, node: SimNode) -> SimNode:
         """Re-attach the ledger's real PodSpecs (the wire carries names)
-        and re-hydrate node fidelity from the ledger's catalog: the wire's
-        NewNode is placement-only (type/zone/ct/price/pod names), but
-        callers — and the ground-truth validator — read allocatable and
-        labels off the session's view."""
+        and re-hydrate node fidelity from the ledger's catalog
+        (:func:`hydrate_node`)."""
         node.pods = [self._pods.get(p.name, p) for p in node.pods]
-        it = self._it_by_name.get(node.instance_type)
-        if it is not None and not node.allocatable:
-            node.allocatable = dict(it.allocatable)
-        node.stamp_labels()
-        return node
+        return hydrate_node(node, self._it_by_name)
 
     def _apply_full(self, reply) -> None:
         self._assignments = dict(reply.assignments)

@@ -14,6 +14,7 @@ import logging
 import os
 import queue
 import signal
+import sys
 import threading
 import time
 import uuid
@@ -57,9 +58,16 @@ from ..obs.slo import WINDOWS as SLO_WINDOWS, SloEngine
 from ..obs.timeseries import sampler_for
 from ..obs.trace import NULL_TRACE, Tracer
 from ..parallel.forward import ResultForwarder, SlotNotOwned
+from ..solver import native as native_mod
 from ..solver.guard import DeviceHang
-from ..solver.scheduler import BatchScheduler
-from ..solver.tpu import MEGA_MAX_SLOTS, max_mega_slots, mesh_shardable
+from ..solver.scheduler import BatchScheduler, WarmupFailed
+from ..solver.tpu import (
+    MEGA_MAX_SLOTS,
+    jit_cache_dir,
+    jit_cache_entries,
+    max_mega_slots,
+    mesh_shardable,
+)
 from ..tuning import TuningController, global_knobs, tune_enabled
 from ..tuning.controller import zero_init as tuning_zero_init
 from ..tuning.knobs import Knobs
@@ -442,7 +450,7 @@ class SolvePipeline:
         self._stop.set()
         self._thread.join(timeout=5.0)
         if self._thread.is_alive():
-            # dispatcher wedged (e.g. a device fence behind a dead tunnel,
+            # dispatcher wedged (e.g. a device fence that never returns,
             # forced backend so no guard, or an H2D dispatch inside
             # scheduler.submit): fail everything still in flight so the RPC
             # threads unblock; the daemon dispatcher thread itself cannot
@@ -1749,8 +1757,10 @@ def main(argv=None) -> int:
                         help="block until the AOT bucket-grid precompile "
                              "lands (single-solve ladder + megabatch slot "
                              "rungs against the generated catalog) before "
-                             "accepting traffic; pair with --jit-cache-dir "
-                             "to skip even this across restarts")
+                             "accepting traffic, and fail start-up if any "
+                             "of those compiles failed; the persistent "
+                             "compile cache (JAX_COMPILATION_CACHE_DIR) "
+                             "skips even this across restarts")
     parser.add_argument("--small", action="store_true",
                         help="--warmup against the 20-type catalog")
     parser.add_argument("--admission", choices=["on", "off"], default=None,
@@ -1787,6 +1797,32 @@ def main(argv=None) -> int:
         # env, not a ctor param: every pipeline the service lazily
         # constructs (per backend) picks the spool up uniformly
         os.environ["KT_SESSION_DIR"] = args.session_dir
+    device = "device=unused"
+    if args.backend in ("auto", "tpu"):
+        # a device-backend sidecar serves from a TPU or not at all: with
+        # `auto`, one that found no chip would answer from the host tiers
+        # for ever without complaint (host-only runs: --backend oracle)
+        import jax
+
+        dev = jax.devices()[0]
+        if dev.platform != "tpu":
+            print(f"solver sidecar: --backend {args.backend} needs a TPU "
+                  f"but jax found platform={dev.platform!r}; refusing to "
+                  "serve", file=sys.stderr, flush=True)
+            return 2
+        device = (f"platform={dev.platform}, device_kind={dev.device_kind!r}, "
+                  f"devices={len(jax.devices())}")
+    # which host tier answers while a device program compiles behind: a
+    # missing g++ or a failed build silently turns it into the Python
+    # oracle (seconds at 50k pods) — say which one is live
+    cold_tier = "native" if native_mod.available() else (
+        f"oracle [native tier unavailable: {native_mod.load_error()}]")
+    # said BEFORE the (minutes-long) warm-up, so whoever launched this can
+    # tell at once what it is running on and where its compiles persist
+    print(f"solver sidecar starting (pid={os.getpid()}, "
+          f"backend={args.backend}, {device}, cold_tier={cold_tier}, "
+          f"compile_cache={jit_cache_dir()} "
+          f"entries={jit_cache_entries()})", flush=True)
     service = SolverService(BatchScheduler(backend=args.backend),
                             max_slots=args.max_slots,
                             max_wait_ms=args.max_wait_ms)
@@ -1796,6 +1832,7 @@ def main(argv=None) -> int:
 
         print("warmup: AOT bucket-grid precompile running "
               "(single ladder + megabatch rungs)...", flush=True)
+        t_warm = time.perf_counter()
         # warm the slot cap this server will actually SERVE: a configured
         # --max-slots / KT_MAX_SLOTS above the default rung grid would
         # otherwise hit its first full flush cold and pay the megabatch
@@ -1809,22 +1846,32 @@ def main(argv=None) -> int:
         while r < cap:
             grid.add(r)
             r *= 2
-        n = service.scheduler.precompile_buckets(
-            [Provisioner(name="default").with_defaults()],
-            generate_catalog(full=not args.small),
-            mega_slots=tuple(sorted(grid)),
-            wait=True,
-        )
-        print(f"warmup: {n} bucket programs compiled; serving", flush=True)
+        try:
+            n = service.scheduler.precompile_buckets(
+                [Provisioner(name="default").with_defaults()],
+                generate_catalog(full=not args.small),
+                mega_slots=tuple(sorted(grid)),
+                wait=True,
+            )
+        except WarmupFailed as err:
+            # a shape whose compile failed would be served from the host
+            # tiers indefinitely — that is not the sidecar --warmup promised
+            print(f"warmup FAILED, not serving: {err}", file=sys.stderr,
+                  flush=True)
+            service.close()
+            service.scheduler.stop_warms()
+            return 1
+        print(f"warmup: {n} bucket programs compiled in "
+              f"{time.perf_counter() - t_warm:.1f}s; serving", flush=True)
     server, port = make_server(service, port=args.port, host=args.host)
     # admission rides the pipeline: with KT_SOLVE_PIPELINE=0 it is inert,
     # and the startup line must not claim otherwise
     admission_live = admission_enabled() and service._pipelined
     delta_live = delta_enabled() and service._pipelined
     print(f"solver sidecar listening on {args.host}:{port} "
-          f"(backend={args.backend}, admission="
-          f"{'on' if admission_live else 'off'}, delta="
-          f"{'on' if delta_live else 'off'})")
+          f"(backend={args.backend}, {device}, cold_tier={cold_tier}, "
+          f"admission={'on' if admission_live else 'off'}, delta="
+          f"{'on' if delta_live else 'off'})", flush=True)
     if args.obs_port:
         from ..obs import default_flight
         from ..obs.export import serve as obs_serve

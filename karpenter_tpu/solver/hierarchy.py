@@ -1,8 +1,7 @@
 """Million-pod hierarchical solving: block decomposition + dual reconciliation.
 
-One flat (pods x types x domains) program holds 50k pods at 24 ms
-(docs/BENCH_RESULTS r05) but the next order of magnitude does not fit one
-scan.  This module decomposes the batch the way CvxCluster decomposes its
+One flat (pods x types x domains) program holds 50k pods, but the next
+order of magnitude does not fit one scan.  This module decomposes the batch the way CvxCluster decomposes its
 clustering objective (PAPERS.md: "100-1000x faster via decomposition"):
 
 1. **Partition** — union-find over the coupling guard's constraint
@@ -45,8 +44,8 @@ The per-wave hot path runs PACKED: feasibility as int8 and prices as bf16
 (``models/tensorize.pack_feasibility``/``pack_scores`` — ~4x fewer HBM
 bytes than the float32 layout the relax rung materializes), scored either
 by a lax program or a hand-written Pallas kernel behind ``KT_PALLAS``
-(interpreted on CPU for tier-1, real lowering on device) with byte-parity
-between the two.
+(Mosaic-lowered; tests pass ``interpret=True`` explicitly to run it on the
+CPU) with byte-parity between the two.
 
 Import-light by design: no jax at module import — the partition, the LPT
 packer and the scale model are pure numpy/stdlib so
@@ -83,12 +82,9 @@ _BIG = float(np.float32(3.0e38))
 DEFAULT_HIER_THRESHOLD = 100_000
 DEFAULT_PRICE_ITERS = 4
 
-#: the flat device reference point the dev-host scale model extrapolates
-#: from when no device measurement is supplied: 50k pods in 24 ms
-#: (docs/BENCH_RESULTS r05, config 2 steady-state)
-DEVICE_REF_PODS = 50_000
-DEVICE_REF_MS = 24.0
-
+#: what the scale model reports for a device-dependent term when the run
+#: that seeded it measured no device rate
+NOT_MEASURED = "not measured"
 
 def hier_threshold() -> int:
     """Pod count at/above which the scheduler routes hierarchically
@@ -361,13 +357,14 @@ _TILE_G = 32
 _TILE_C = 128
 
 
-def _pallas_score(Gp: int, Cp: int):
+def _pallas_score(Gp: int, Cp: int, interpret: bool = False):
     """Hand-written Pallas kernel for the packed score reduction.  Grid
     over row tiles; the price row is broadcast to every tile.  Argmin is
     expressed as min-over-matching-column-index (first-minimum tie-break,
-    identical to ``jnp.argmin``).  Interpreted off-TPU (tier-1 runs it on
-    CPU), real Mosaic lowering on device."""
-    key = ("pallas", Gp, Cp)
+    identical to ``jnp.argmin``).  Mosaic-lowered unless the caller asks
+    for ``interpret`` (the CPU tests do, explicitly — the backend is never
+    sniffed to decide it)."""
+    key = ("pallas", Gp, Cp, interpret)
     prog = _PROGRAMS.get(key)
     if prog is not None:
         return prog
@@ -376,7 +373,11 @@ def _pallas_score(Gp: int, Cp: int):
     from jax.experimental import pallas as pl
 
     def kernel(f_ref, p_ref, cost_ref, idx_ref):
-        f = f_ref[...]
+        # widen before the compare: an i1 mask made from the int8 tile
+        # keeps its (32, 128) layout, and Mosaic cannot relayout it onto
+        # the f32 (8, 128) tiles the select runs on ("Invalid relayout" on
+        # the v5e); int8 -> int32 is a supported extension
+        f = f_ref[...].astype(jnp.int32)
         p = p_ref[...].astype(jnp.float32)          # [1, Cp]
         cost = jnp.where(f > 0, jnp.broadcast_to(p, f.shape), _BIG)
         best = jnp.min(cost, axis=1, keepdims=True)
@@ -400,10 +401,10 @@ def _pallas_score(Gp: int, Cp: int):
             jax.ShapeDtypeStruct((Gp, 1), jnp.float32),
             jax.ShapeDtypeStruct((Gp, 1), jnp.int32),
         ],
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret,
     )
-    # ktlint: allow[KT008] memoized per (Gp, Cp) in _PROGRAMS — one
-    # wrapper per padded shape, created once and reused
+    # ktlint: allow[KT008] memoized per (Gp, Cp, interpret) in _PROGRAMS —
+    # one wrapper per padded shape, created once and reused
     prog = _PROGRAMS[key] = jax.jit(call)
     return prog
 
@@ -412,12 +413,14 @@ def packed_scan_scores(
     f_packed: np.ndarray,
     price_packed: np.ndarray,
     use_pallas: Optional[bool] = None,
+    interpret: bool = False,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """``(best_cost[G] f32, best_idx[G] i32)`` — cheapest feasible
     candidate per group from PACKED inputs (int8 feasibility, bf16
     prices).  ``use_pallas`` overrides ``KT_PALLAS`` (the parity harness
-    runs both); all-infeasible rows return (``3.0e38``, 0) on either
-    path."""
+    runs both); ``interpret`` runs the Pallas kernel in interpret mode
+    (CPU tests only).  All-infeasible rows return (``3.0e38``, 0) on
+    either path."""
     G, C = f_packed.shape
     if use_pallas is None:
         use_pallas = pallas_enabled()
@@ -430,7 +433,7 @@ def packed_scan_scores(
     f[:G, :C] = f_packed
     p = np.zeros((1, Cp), dtype=price_packed.dtype)
     p[0, :C] = price_packed
-    cost, idx = _pallas_score(Gp, Cp)(f, p)
+    cost, idx = _pallas_score(Gp, Cp, interpret)(f, p)
     return np.asarray(cost)[:G, 0], np.asarray(idx)[:G, 0]
 
 
@@ -624,7 +627,7 @@ def _solve_hierarchical(
 
         # ---- price ascent (fixed budget, mirror-descent schedule) ------
         from ..models.tensorize import pack_feasibility, pack_scores
-        from .relax import _host_feasibility, mirror_eta
+        from .relax import host_feasibility, mirror_eta
 
         lam = np.zeros(P, dtype=np.float64)
         f_packed: Optional[np.ndarray] = None
@@ -655,7 +658,7 @@ def _solve_hierarchical(
             # Pallas per KT_PALLAS
             adj = adj_padded[:st.C].min(axis=1)
             if f_packed is None:
-                f_packed = pack_feasibility(_host_feasibility(st))
+                f_packed = pack_feasibility(host_feasibility(st))
             _cost, best = packed_scan_scores(f_packed, pack_scores(adj))
             want_hot = np.zeros(st.G, dtype=bool)
             if st.C:
@@ -867,24 +870,26 @@ def scale_model(measured: dict, n_pods: int) -> dict:
     pod count; a block wave is ONE vmapped dispatch whose per-slot scan
     state is the block's share ``n_pods / blocks`` (slots run data-parallel
     on device), so device wave time scales with the BLOCK size, not the
-    batch — that is the whole decomposition dividend.  The device
-    per-pod rate comes from ``measured['device_per_pod_us']`` when the run
-    had a real device, else the BENCH r05 flat reference (50k in 24 ms)."""
+    batch — that is the whole decomposition dividend.  The device per-pod
+    rate is ``measured['device_per_pod_us']``, which only a run on the chip
+    can supply: without it ``wave_ms`` and ``total_ms`` are the string
+    ``NOT_MEASURED`` and only the host stages carry numbers."""
     n0 = max(1, int(measured.get("n_pods", 1)))
     blocks = max(1, int(measured.get("blocks", 1)))
     waves = max(1, int(measured.get("waves", 1)))
     s = n_pods / n0
     host_ms = (float(measured.get("partition_ms", 0.0))
                + float(measured.get("entries_ms", 0.0))) * s
-    per_pod_us = float(
-        measured.get("device_per_pod_us")
-        or DEVICE_REF_MS * 1000.0 / DEVICE_REF_PODS)
-    dispatch_ms = float(measured.get("dispatch_overhead_ms", 2.0))
-    wave_ms = per_pod_us * (n_pods / blocks) / 1000.0 + dispatch_ms
     repair_ms = float(measured.get("repair_ms", 0.0)) * s
-    total = host_ms + waves * wave_ms + repair_ms
-    return {
+    out = {
         "n_pods": int(n_pods), "blocks": blocks, "waves": waves,
-        "host_ms": round(host_ms, 2), "wave_ms": round(wave_ms, 2),
-        "repair_ms": round(repair_ms, 2), "total_ms": round(total, 2),
+        "host_ms": round(host_ms, 2), "wave_ms": NOT_MEASURED,
+        "repair_ms": round(repair_ms, 2), "total_ms": NOT_MEASURED,
     }
+    per_pod_us = measured.get("device_per_pod_us")
+    if per_pod_us:
+        dispatch_ms = float(measured.get("dispatch_overhead_ms", 2.0))
+        wave_ms = float(per_pod_us) * (n_pods / blocks) / 1000.0 + dispatch_ms
+        out["wave_ms"] = round(wave_ms, 2)
+        out["total_ms"] = round(host_ms + waves * wave_ms + repair_ms, 2)
+    return out

@@ -9,6 +9,7 @@ packed-bitmask semantics of the device path (models/vocab.py).
 from __future__ import annotations
 
 import ctypes
+import logging
 import subprocess
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence
@@ -19,9 +20,13 @@ from ..models import labels as L
 from ..models.tensorize import SolveTensors
 from .types import SimNode, SolveResult, node_classes
 
+logger = logging.getLogger(__name__)
+
 _SRC = Path(__file__).resolve().parents[2] / "native" / "ffd.cpp"
 
 _lib = None
+#: why the last :func:`available` probe came back False ("" = it did not)
+_load_error = ""
 
 #: kt_ffd_solve arity: 9 dims + 23 input arrays + 7 output arrays.  Declared
 #: so a source/binding mismatch fails loudly (ctypes arity check) instead of
@@ -82,14 +87,29 @@ def _load():
 
 
 def available() -> bool:
-    # the three real failure shapes: g++ missing / CDLL of a bad ELF
-    # (OSError, incl. FileNotFoundError), a failed compile
-    # (CalledProcessError), and a compiled .so whose exported symbols don't
-    # match this binding (AttributeError from ctypes symbol lookup)
+    """Whether the C++ tier can serve.  The three real failure shapes: g++
+    missing / CDLL of a bad ELF (OSError, incl. FileNotFoundError), a failed
+    compile (CalledProcessError), and a compiled .so whose exported symbols
+    don't match this binding (AttributeError from ctypes symbol lookup).
+    A False is never silent: the reason is logged once here and kept for
+    :func:`load_error`, which the sidecar's startup line prints — without
+    this tier a cold shape is served by the Python oracle, seconds slower."""
+    global _load_error
     try:
         return _load() is not None
-    except (OSError, subprocess.CalledProcessError, AttributeError):
+    except (OSError, subprocess.CalledProcessError, AttributeError) as err:
+        detail = getattr(err, "stderr", b"") or b""
+        reason = f"{err!r} {detail.decode(errors='replace')[-300:]}".strip()
+        if reason != _load_error:
+            _load_error = reason
+            logger.warning("native FFD tier unavailable; cold shapes fall "
+                           "to the Python oracle: %s", reason)
         return False
+
+
+def load_error() -> str:
+    """Why :func:`available` last returned False ("" when it did not)."""
+    return _load_error
 
 
 def version() -> str:

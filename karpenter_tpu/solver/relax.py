@@ -280,11 +280,15 @@ def _run_relax(req, counts, feas, alloc_inv, price, x0, relax_iters: int,
 
 # ktlint: fence the warm thunk's D2H read is the deliberate compile+fence
 # of the background relax-program warm (discarded results, warm thread)
-def warm_relax(solver, st, relax_iters: Optional[int] = None) -> bool:
+def warm_relax(solver, st, relax_iters: Optional[int] = None,
+               on_done=None) -> bool:
     """Background-compile the relax program for this tensor shape on the
     solver's warm machinery (concurrency cap, bounded queue, failure
     backoff) — the compile-behind contract: the serving path skips the
-    rung while its program is cold and never stalls on XLA."""
+    rung while its program is cold and never stalls on XLA.
+    ``on_done(sig, seconds, error)`` fires when the warm ends, as for
+    ``TpuSolver.warm_async`` — the scheduler passes its ``_warm_done`` so a
+    failed relax compile is logged, counted and fails ``--warmup``."""
     iters = iter_rung(configured_iters() if relax_iters is None
                       else relax_iters)
     sig = relax_signature(st, iters)
@@ -304,7 +308,7 @@ def warm_relax(solver, st, relax_iters: Optional[int] = None) -> bool:
         np.asarray(bx)  # fence: the compile has landed
         solver._mark_ready(sig)
 
-    return solver.warm_custom(sig, thunk)
+    return solver.warm_custom(sig, thunk, on_done=on_done)
 
 
 # ---------------------------------------------------------------------------
@@ -312,7 +316,7 @@ def warm_relax(solver, st, relax_iters: Optional[int] = None) -> bool:
 # ---------------------------------------------------------------------------
 
 
-def _host_feasibility(st) -> np.ndarray:
+def host_feasibility(st) -> np.ndarray:
     """Numpy mirror of the device feasibility (labels & fit & provisioner)
     — byte-identical semantics to ops/feasibility's gather path, cheap at
     group granularity ([G, C, K] bit gathers)."""
@@ -830,7 +834,7 @@ def _refine_inner(result: SolveResult, st, *, guard, repair_solve,
     if not elig or not freed:
         return result, "skipped", None
 
-    F = _host_feasibility(st)
+    F = host_feasibility(st)
     dims = relax_dims(st)
     Gp, Cp, R = dims["G"], dims["C"], dims["R"]
     G, C = st.G, st.C

@@ -267,7 +267,7 @@ def run_chaos(seed=42, steps=60, pods_n=1500, churn=6, schedule=None,
                 # wipe, clock jump, mid-step failure on a prior call):
                 # mirror the SAME full solve onto the oracle — identical
                 # pod list, identical order
-                o_sess.solve(list(sess._pods.values()), provs, catalog)
+                o_sess.solve(sess.pods(), provs, catalog)
                 last_resends = sess.full_resends
             else:
                 o_sess.solve_delta(added=cum_add + add, removed=cum_rm + rm)
@@ -621,7 +621,7 @@ def run_fleet(replicas=3, clients=6, pods_n=1200, pre_steps=3, post_steps=3,
             if s["sess"].full_resends > s["resends"]:
                 # the chain re-established internally: mirror the SAME
                 # full solve so both sides see identical sequences
-                s["mirror"].solve(list(s["sess"]._pods.values()), provs,
+                s["mirror"].solve(s["sess"].pods(), provs,
                                   catalog)
                 s["resends"] = s["sess"].full_resends
             else:

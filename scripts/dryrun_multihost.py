@@ -74,7 +74,6 @@ def _scenario(n_slots: int):
 def worker(args) -> int:
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     from karpenter_tpu.parallel.distributed import (
         _enable_cpu_collectives,
         assert_host_major,
@@ -170,9 +169,6 @@ def lone_ab(devices: int = 8, pairs: int = 5) -> int:
             flags + f" --xla_force_host_platform_device_count={devices}"
         ).strip()
     os.environ["JAX_PLATFORMS"] = "cpu"
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
     from karpenter_tpu.parallel.mesh import make_mesh
     from karpenter_tpu.solver.tpu import TpuSolver

@@ -9,13 +9,13 @@ Three acts, all on the dev host (JAX_PLATFORMS=cpu):
    vmapped block wave, the dual price loop (a provisioner limit is set
    tight enough to contend across blocks), warm-start repair and the
    cross-block tail repack — printing the stats the bench gates.
-3. The dev-host scale model seeded with the measured stats: the
-   projected 1M wall vs the 250 ms budget.
+3. The scale model seeded with the measured HOST stats.  The device wave
+   is "not measured" here by construction: only a run on the chip
+   (`bench.py measure_hierarchical`) supplies the per-pod device rate the
+   1M wall and its 250 ms budget are judged by.
 
 The full 1M batch never dispatches here — a CPU host neither holds the
-32-slot carry nor finishes the wave in demo time; the measured-rate
-model is the same one `bench.py measure_hierarchical` gates
-(docs/PROFILE.md round 13 for the ladder).
+32-slot carry nor finishes the wave in demo time.
 """
 
 from __future__ import annotations
@@ -127,13 +127,11 @@ def main() -> int:
          * (st.G / max(1, len(masks))),
          "repair_ms": stats_free["repair_ms"]},
         1_000_000)
-    verdict = "PASS" if model["total_ms"] < HIER_BUDGET_MS else "FAIL"
     print(f"modeled 1M wall: host {model['host_ms']:.1f} ms + "
-          f"{model['waves']} wave(s) x {model['wave_ms']:.1f} ms + "
-          f"repair {model['repair_ms']:.1f} ms -> "
-          f"{model['total_ms']:.1f} ms  "
-          f"[{verdict}: budget {HIER_BUDGET_MS:.0f} ms]")
-    return 0 if verdict == "PASS" else 1
+          f"{model['waves']} wave(s) x {model['wave_ms']} + "
+          f"repair {model['repair_ms']:.1f} ms -> {model['total_ms']}  "
+          f"[budget {HIER_BUDGET_MS:.0f} ms: judged on the chip only]")
+    return 0
 
 
 if __name__ == "__main__":

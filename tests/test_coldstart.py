@@ -153,10 +153,88 @@ class TestCompileBehind:
         assert not sched._tpu.warm_async(st)
         assert sched._tpu._failed_until  # backoff armed
 
+    def test_blocking_precompile_raises_when_a_compile_failed(
+            self, small_catalog, monkeypatch):
+        """``precompile_buckets(wait=True)`` is what ``serve --warmup``
+        blocks on: a warm-up compile that failed must not come back as
+        "N programs accepted" (the shape would be served from the host
+        tiers indefinitely) — it raises, naming the failure.  The relax
+        program (one more warm per profile shape) is part of the grid and
+        compiles fine here: only the two scan programs fail."""
+        import pytest
+
+        from karpenter_tpu.solver.scheduler import WarmupFailed
+
+        prov = Provisioner(name="default").with_defaults()
+
+        def boom(*a, **k):
+            raise RuntimeError("simulated XLA compile failure")
+
+        def failing_scheduler():
+            sched = BatchScheduler(backend="auto", registry=Registry())
+            monkeypatch.setattr(sched._tpu, "solve", boom)
+            monkeypatch.setattr(sched._tpu, "solve_many", boom)
+            return sched
+
+        with pytest.raises(WarmupFailed, match="2 of 3 bucket compiles "
+                                               "failed.*simulated XLA"):
+            failing_scheduler().precompile_buckets(
+                [prov], small_catalog, profiles=((4, 64, False),),
+                mega_slots=(2,), wait=True, timeout=120)
+        # without wait the contract is unchanged: accepted, failing behind
+        sched = failing_scheduler()
+        assert sched.precompile_buckets(
+            [prov], small_catalog, profiles=((4, 64, False),),
+            mega_slots=(2,)) == 3
+        _wait_warm(sched)
+
+    def test_blocking_precompile_raises_when_the_relax_compile_failed(
+            self, small_catalog, monkeypatch):
+        """The relax program rides ``warm_custom``, not ``warm_async``: its
+        failure must reach ``_warm_done`` all the same — logged, counted,
+        and fatal to ``--warmup`` — while the scan programs compile."""
+        import pytest
+
+        from karpenter_tpu.solver import relax
+        from karpenter_tpu.solver.scheduler import WarmupFailed
+
+        def boom(*a, **k):
+            raise RuntimeError("simulated relax lowering failure")
+
+        monkeypatch.setattr(relax, "relax_jit", boom)
+        sched = BatchScheduler(backend="auto", registry=Registry())
+        prov = Provisioner(name="default").with_defaults()
+        with pytest.raises(WarmupFailed, match="1 of 3 bucket compiles "
+                                               "failed.*relax lowering"):
+            sched.precompile_buckets(
+                [prov], small_catalog, profiles=((4, 64, False),),
+                mega_slots=(2,), wait=True, timeout=300)
+        # the two scan programs landed and were recorded; relax was not
+        assert sched.registry.histogram(
+            SOLVER_COMPILE_DURATION).count() == 2
+
+    def test_blocking_precompile_counts_compiled_programs(
+            self, small_catalog):
+        from karpenter_tpu.solver import relax
+
+        sched = BatchScheduler(backend="auto", registry=Registry())
+        prov = Provisioner(name="default").with_defaults()
+        n = sched.precompile_buckets(
+            [prov], small_catalog, profiles=((4, 64, False),),
+            mega_slots=(2,), wait=True, timeout=300)
+        # single-solve + relax + the 2-slot mega rung, every one through
+        # _warm_done: the compile histogram counts what compiled
+        assert n == 3 and sched._tpu.warm_idle()
+        assert sched.registry.histogram(
+            SOLVER_COMPILE_DURATION).count() == 3
+        (st,) = sched._profile_tensors([prov], small_catalog, (),
+                                       ((4, 64, False),))
+        assert sched._tpu.ready(relax.relax_signature(st))
+
     def test_warm_startup_uses_cluster_size(self, small_catalog, monkeypatch):
         """The warmed signatures must reflect the live cluster's NE/NR rungs
         — an operator restarting over a populated cluster warms the shapes
-        its solves will actually hit (VERDICT r3 review finding)."""
+        its solves will actually hit."""
         from karpenter_tpu.solver.tpu import SimNode
 
         # scan signatures only: relax signatures carry no NE_pad and the

@@ -623,8 +623,7 @@ class TestRepackConvergence:
         """The end-to-end repack (BASELINE config 4 at test scale): driving
         the full ladder to convergence with the device-screened loop must
         achieve >= 0.98x the savings of the oracle-driven loop, with every
-        evicted pod rebound.  The full-scale numbers live in bench_all
-        config 4 / docs/BENCH_RESULTS.md."""
+        evicted pod rebound.  The full-scale run is bench_all config 4."""
         from bench_all import _repack_to_convergence
 
         dev = _repack_to_convergence(small_catalog, 80, "auto", False)

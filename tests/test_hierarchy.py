@@ -231,14 +231,16 @@ class TestPackedScores:
         f = pack_feasibility(np.array([[0, 0], [1, 1]]))
         p = pack_scores(np.array([1.0, 2.0], dtype=np.float32))
         for use_pallas in (False, True):
-            cost, idx = hier.packed_scan_scores(f, p, use_pallas=use_pallas)
+            cost, idx = hier.packed_scan_scores(f, p, use_pallas=use_pallas,
+                                                interpret=True)
             assert cost[0] >= 1e37 and idx[0] == 0
             assert cost[1] == pytest.approx(1.0)
 
     def test_pallas_byte_parity_with_ties(self):
         f, p = self._case()
         c0, i0 = hier.packed_scan_scores(f, p, use_pallas=False)
-        c1, i1 = hier.packed_scan_scores(f, p, use_pallas=True)
+        c1, i1 = hier.packed_scan_scores(f, p, use_pallas=True,
+                                         interpret=True)
         assert c0.tobytes() == c1.tobytes()
         assert i0.tobytes() == i1.tobytes()
 
@@ -246,9 +248,18 @@ class TestPackedScores:
         # exactly one (32, 128) tile: no padding path at all
         f, p = self._case(G=32, C=128, seed=9)
         c0, i0 = hier.packed_scan_scores(f, p, use_pallas=False)
-        c1, i1 = hier.packed_scan_scores(f, p, use_pallas=True)
+        c1, i1 = hier.packed_scan_scores(f, p, use_pallas=True,
+                                         interpret=True)
         assert c0.tobytes() == c1.tobytes()
         assert i0.tobytes() == i1.tobytes()
+
+    def test_interpret_is_asked_for_never_sniffed(self):
+        # on the CPU the Mosaic path must FAIL, loudly — not quietly turn
+        # itself into interpret mode (or the lax program) by looking at
+        # the backend; chip_smoke.py runs it for real on the TPU
+        f, p = self._case()
+        with pytest.raises(ValueError, match="interpret"):
+            hier.packed_scan_scores(f, p, use_pallas=True)
 
     def test_env_flag_selects_the_kernel(self, monkeypatch):
         monkeypatch.setenv("KT_PALLAS", "1")
@@ -272,11 +283,19 @@ class TestScaleModel:
         assert m["repair_ms"] == pytest.approx(0.5 * 10.0)
         assert m["waves"] == 2 and m["blocks"] == 32
 
+    def test_no_measured_device_rate_means_not_measured(self):
+        # no default rate: a run that never saw the chip projects the host
+        # stages only and says so for everything the device decides
+        m = hier.scale_model(dict(self.MEASURED), 1_000_000)
+        assert m["wave_ms"] == m["total_ms"] == hier.NOT_MEASURED
+        assert m["host_ms"] == pytest.approx((1.0 + 3.0) * 100.0)
+
     def test_wave_scales_with_block_share_not_batch(self):
         # the decomposition dividend: device time rides n_pods / blocks
-        m32 = hier.scale_model(dict(self.MEASURED), 1_000_000)
-        m64 = hier.scale_model(dict(self.MEASURED, blocks=64), 1_000_000)
-        per_pod_us = hier.DEVICE_REF_MS * 1000.0 / hier.DEVICE_REF_PODS
+        per_pod_us = 0.48
+        measured = dict(self.MEASURED, device_per_pod_us=per_pod_us)
+        m32 = hier.scale_model(dict(measured), 1_000_000)
+        m64 = hier.scale_model(dict(measured, blocks=64), 1_000_000)
         assert m32["wave_ms"] == pytest.approx(
             per_pod_us * (1_000_000 / 32) / 1000.0 + 2.0)
         assert (m64["wave_ms"] - 2.0) == pytest.approx(
@@ -284,7 +303,7 @@ class TestScaleModel:
         assert m32["total_ms"] == pytest.approx(
             m32["host_ms"] + 2 * m32["wave_ms"] + m32["repair_ms"])
 
-    def test_measured_device_rate_overrides_the_reference(self):
+    def test_measured_device_rate_sets_the_wave(self):
         m = hier.scale_model(
             dict(self.MEASURED, device_per_pod_us=1.0,
                  dispatch_overhead_ms=0.0), 320_000)
@@ -425,8 +444,7 @@ class TestMetricsAndKnobs:
         # nothing about hierarchy's own imports
         import subprocess
         import sys
-        env = {k: v for k, v in os.environ.items()
-               if k not in ("JAX_PLATFORMS", "KT_SANITIZE")}
+        env = {k: v for k, v in os.environ.items() if k != "KT_SANITIZE"}
         code = ("import sys; import karpenter_tpu.solver.hierarchy; "
                 "sys.exit(1 if 'jax' in sys.modules else 0)")
         assert subprocess.run([sys.executable, "-c", code],

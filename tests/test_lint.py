@@ -2359,7 +2359,7 @@ class TestKT020HierarchicalPath:
         import numpy as np
 
         def score(self, st, prices):
-            feas = _host_feasibility(st).astype(np.float32)
+            feas = host_feasibility(st).astype(np.float32)
             return feas * prices
         """
         findings = lint(src, self.HIER)
@@ -2379,7 +2379,7 @@ class TestKT020HierarchicalPath:
     def test_quiet_on_packed_feasibility(self):
         src = """
         def score(self, st, adj):
-            f_packed = pack_feasibility(_host_feasibility(st))
+            f_packed = pack_feasibility(host_feasibility(st))
             return packed_scan_scores(f_packed, pack_scores(adj))
         """
         assert lint(src, self.HIER) == []

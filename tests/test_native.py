@@ -254,3 +254,26 @@ class TestRouting:
         dt = (time.perf_counter() - t0) * 1000
         assert res.n_scheduled == 10
         assert dt < 250  # whole pipeline incl. tensorize; C++ core itself is ~us
+
+
+def test_unavailable_native_tier_says_why(monkeypatch, caplog):
+    """ISSUE 21: a missing g++ or a failed build must not silently turn the
+    cold tier into the Python oracle — ``available()`` logs the reason and
+    keeps it for the sidecar's startup line (``load_error``)."""
+    import logging
+    import subprocess
+
+    from karpenter_tpu.solver import native
+
+    def no_compiler():
+        raise subprocess.CalledProcessError(
+            1, ["g++"], stderr=b"ffd.cpp:1: fatal error: boom")
+
+    monkeypatch.setattr(native, "_load", no_compiler)
+    monkeypatch.setattr(native, "_load_error", "")
+    with caplog.at_level(logging.WARNING, logger=native.__name__):
+        assert native.available() is False
+        assert native.available() is False  # same reason: logged once
+    assert "fatal error: boom" in native.load_error()
+    assert [r for r in caplog.records
+            if "native FFD tier unavailable" in r.getMessage()].__len__() == 1

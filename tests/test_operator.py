@@ -266,3 +266,27 @@ class TestLeaderElection:
         op1.shutdown()  # resigns the lease
         op2.tick()      # same instant: no TTL wait
         assert op2.elector.elected
+
+
+def test_forced_exit_on_a_hung_compile_is_nonzero():
+    """ISSUE 21: when a background compile is still running after the
+    grace, ``drain_warm_threads`` forces the exit with FORCED_EXIT_RC —
+    never with the command's own 0."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import sys, threading, time\n"
+        "from karpenter_tpu.operator import drain_warm_threads\n"
+        "threading.Thread(target=time.sleep, args=(60,),\n"
+        "                 name='tpu-solver-warm').start()\n"
+        "drain_warm_threads(grace_s=0.2)\n"
+        "sys.exit(0)\n")
+    p = subprocess.run([sys.executable, "-c", code], cwd=repo, timeout=120,
+                       capture_output=True, text=True)
+    from karpenter_tpu.operator import FORCED_EXIT_RC
+
+    assert FORCED_EXIT_RC != 0 and p.returncode == FORCED_EXIT_RC
+    assert "forcing process exit" in p.stderr

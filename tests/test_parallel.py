@@ -72,6 +72,16 @@ class TestMesh:
         assert mesh.devices.shape == (2, 1)
 
 
+def test_make_mesh_raises_rather_than_substituting_devices():
+    """ISSUE 21: asked for more devices than the default platform has,
+    make_mesh raises — it never quietly builds the mesh out of another
+    platform's (CPU) devices, which would measure the host under a chip's
+    name.  (conftest pins the CPU with 8 virtual devices.)"""
+    assert len(jax.devices()) == 8
+    with pytest.raises(ValueError, match="only 8 device"):
+        make_mesh(16)
+
+
 class TestShardedSolve:
     @pytest.mark.parametrize("n_devices", [2, 8])
     def test_sharded_matches_unsharded(self, small_catalog, n_devices):

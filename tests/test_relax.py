@@ -17,16 +17,12 @@ Coverage map:
 """
 
 import os
-import sys
 import time
 
 import numpy as np
 import pytest
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-sys.path.insert(0, os.path.dirname(__file__))
-from test_fuzz_parity import validate_solution  # noqa: E402
 
 from karpenter_tpu.metrics import (  # noqa: E402
     RELAX_DURATION,
@@ -48,6 +44,7 @@ from karpenter_tpu.models.tensorize import tensorize  # noqa: E402
 from karpenter_tpu.solver import relax  # noqa: E402
 from karpenter_tpu.solver.scheduler import BatchScheduler  # noqa: E402
 from karpenter_tpu.solver.tpu import TpuSolver  # noqa: E402
+from karpenter_tpu.solver.validate import validate_solution  # noqa: E402
 
 
 @pytest.fixture(scope="module")

@@ -2,7 +2,7 @@
 
 Reference semantics: scheduling.md:303-346 (soft spread still influences
 placement) on core's preference-relaxation ladder (one preference dropped per
-failed attempt).  Parity requirement (VERDICT r1 #6): a soft-spread workload
+failed attempt).  Parity requirement: a soft-spread workload
 distributes across zones on BOTH backends.
 """
 

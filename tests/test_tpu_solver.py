@@ -873,8 +873,7 @@ class TestCoalescing:
 
     def test_nr_estimate_exhaustion_retries_at_full_budget(self, small_catalog):
         """The NR axis is sized by an optimistic resource-only estimate
-        (docs/PROFILE.md: the worst-case one-slot-per-pod axis dominated
-        device time).  A shape the estimate undershoots — hostname
+        (the worst-case one-slot-per-pod axis dominated device time).  A shape the estimate undershoots — hostname
         anti-affinity forces ~1 pod/node where resources allow hundreds —
         must exhaust its slots and transparently re-solve at the full
         budget, placing every pod."""
