@@ -174,6 +174,10 @@ TENSORIZE_DURATION = "karpenter_solver_tensorize_duration_seconds"
 INFLIGHT_DEPTH = "karpenter_solver_inflight_depth"
 TRACE_TRACES = "karpenter_trace_traces_total"
 TRACE_SPAN_DURATION = "karpenter_trace_span_duration_seconds"
+TRACE_SPAN_SELF = "karpenter_trace_span_self_seconds_total"
+GC_PAUSE_SECONDS = "karpenter_process_gc_pause_seconds_total"
+#: the collector's generations (KT003 zero-init source)
+GC_GENERATIONS = ("0", "1", "2")
 TRACE_RING_EVICTIONS = "karpenter_trace_ring_evictions_total"
 FLIGHT_DUMPS = "karpenter_trace_flight_recorder_dumps_total"
 # ---- fleet-wide tracing (ISSUE 15: wire-propagated trace context) -------
@@ -524,6 +528,23 @@ INVENTORY = {
         "Duration of each named trace span (window / tensorize / dispatch "
         "/ fence / reseat / respond / ...), seconds — the per-phase "
         "attribution behind /tracez p50/p99."),
+    TRACE_SPAN_SELF: (
+        "counter", ("span",),
+        "Self time of each named trace span, seconds: its duration minus "
+        "the part of its own interval that its child spans cover (their "
+        "union, clipped to the parent).  Spans nest, so durations overlap "
+        "and self times do not: inside one root they sum to the root's "
+        "duration (plus the time siblings spend side by side on two "
+        "threads), and a phase recorded before the root (request_parse, "
+        "request_decode) or detached after it (response_serialize) adds "
+        "its own.  The unlabeled sample is the zero-init and stays 0."),
+    GC_PAUSE_SECONDS: (
+        "counter", ("generation",),
+        "Seconds this process spent inside the Python collector, by "
+        "generation, from one gc.callbacks entry registered by the first "
+        "enabled tracer (KT_TRACE=0: never registered, the family stays "
+        "absent).  Generation-2 pauses are also mirrored onto the "
+        "profiler's host plane as gc_gen2."),
     TRACE_RING_EVICTIONS: (
         "counter", (),
         "Traces evicted from the flight recorder's bounded ring to admit "

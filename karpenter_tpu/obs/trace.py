@@ -26,6 +26,26 @@ Design constraints, in order:
   read mid-solve by the flight recorder's anomaly dumps; all tree state is
   ``# guarded-by:`` the trace lock (KT004) and ``to_dict`` snapshots under
   it.
+- **Self time.**  Spans nest, so durations overlap; when a trace finishes
+  every closed span's duration minus the part of its own interval its
+  children cover (their union, clipped: children run on other threads and
+  recorded children may start before the root) is counted into
+  ``karpenter_trace_span_self_seconds_total{span}``.  Inside one root the
+  self times sum to the root's duration (siblings that run side by side on
+  two threads each keep the shared time, so they add it once more).
+- **One clock with the profiler.**  A span opened with :meth:`Trace.span`
+  and every :meth:`Tracer.phase` also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so a profiler session shows
+  the program's spans on the host plane beside the device's operations.
+  This module never imports jax: the annotation class is taken from
+  ``sys.modules`` and only exists where jax is already loaded.  The root is
+  not mirrored (it would cover every device gap and name them all
+  ``solve``).
+- **Collector pauses.**  The first enabled tracer of a process registers
+  one ``gc.callbacks`` entry; pauses are counted into
+  ``karpenter_process_gc_pause_seconds_total{generation}`` of every
+  registry that has an enabled tracer, and generation-2 pauses are mirrored
+  as ``gc_gen2``.  No span per collection.
 - **Context-manager lifecycle (KT007).**  ``with tracer.start(...) as
   trace:`` / ``with trace.span(...):`` are the only blessed forms — a bare
   ``Tracer.start()`` leaks an open trace on any exception path, and ktlint
@@ -34,16 +54,23 @@ Design constraints, in order:
 
 from __future__ import annotations
 
+import gc
 import itertools
 import logging
 import os
+import sys
 import threading
-from typing import Dict, List, Optional
+import time
+import weakref
+from typing import Dict, List, Optional, Tuple
 
 from ..metrics import (
+    GC_GENERATIONS,
+    GC_PAUSE_SECONDS,
     TRACE_REMOTE_OUTCOMES,
     TRACE_REMOTE_SPANS,
     TRACE_SPAN_DURATION,
+    TRACE_SPAN_SELF,
     TRACE_TRACES,
     Registry,
     registry as default_registry,
@@ -55,6 +82,65 @@ from ..utils.clock import Clock
 MAX_SPANS_PER_TRACE = 512
 
 _TRACE_IDS = itertools.count(1)
+
+
+def _annotate(name: str):
+    """An entered ``jax.profiler.TraceAnnotation(name)`` where jax is
+    already loaded in this process, else None.  With no profiler session
+    the annotation is a check of one flag; a process that never imports
+    jax (the operator, a benchmark client) pays one dict lookup."""
+    prof = sys.modules.get("jax.profiler")
+    cls = getattr(prof, "TraceAnnotation", None)
+    if cls is None:
+        return None
+    ann = cls(name)
+    ann.__enter__()
+    return ann
+
+
+def _end(ann) -> None:
+    """Close what :func:`_annotate` gave (None where there was no jax)."""
+    if ann is not None:
+        ann.__exit__(None, None, None)
+
+
+class _GcWatch:
+    """The process's one ``gc.callbacks`` entry.  Pause seconds go to every
+    registry added (weakly held: a test's private registry dies with it)."""
+
+    def __init__(self) -> None:
+        self._registries: "weakref.WeakSet[Registry]" = weakref.WeakSet()
+        self._lock = threading.Lock()
+        self._t0 = 0.0
+        self._ann = None
+        self._labels = [{"generation": g} for g in GC_GENERATIONS]
+
+    def add(self, registry: Registry) -> None:
+        counter = registry.counter(GC_PAUSE_SECONDS)
+        for labels in self._labels:
+            counter.inc(labels, value=0.0)
+        with self._lock:
+            self._registries.add(registry)
+            if self._callback not in gc.callbacks:
+                gc.callbacks.append(self._callback)
+
+    def _callback(self, phase: str, info: dict) -> None:
+        # collections do not nest and a start/stop pair runs on one thread
+        gen = info.get("generation", 0)
+        if phase == "start":
+            if gen == 2:
+                self._ann = _annotate("gc_gen2")
+            self._t0 = time.perf_counter()
+            return
+        pause = time.perf_counter() - self._t0
+        _end(self._ann)
+        self._ann = None
+        labels = self._labels[min(gen, len(self._labels) - 1)]
+        for registry in list(self._registries):
+            registry.counter(GC_PAUSE_SECONDS).inc(labels, value=pause)
+
+
+_GC_WATCH = _GcWatch()
 
 
 def replica_id() -> str:
@@ -79,7 +165,7 @@ class Span:
     (pre-closed); never constructed directly by instrumentation."""
 
     __slots__ = ("name", "span_id", "t0", "t1", "attrs", "children",
-                 "_trace")
+                 "_trace", "_ann")
 
     def __init__(self, trace: "Trace", name: str, t0: float,
                  attrs: Optional[dict] = None, span_id: str = "") -> None:
@@ -93,6 +179,8 @@ class Span:
         self.attrs: Dict[str, object] = dict(attrs or ())
         self.children: List["Span"] = []  # guarded-by the owning trace lock
         self._trace = trace
+        #: the profiler annotation mirroring a live span (Trace.span)
+        self._ann = None
 
     @property
     def done(self) -> bool:
@@ -207,6 +295,64 @@ class _NullTrace:
 NULL_TRACE = _NullTrace()
 
 
+class _Phase:
+    """A phase outside any open span (:meth:`Tracer.phase`): timed on the
+    tracer's clock and mirrored on the profiler's host plane while it
+    runs.  ``t0``/``t1`` are for :meth:`Trace.record` once the trace it
+    belongs to is open; a ``detached`` phase belongs to none and is
+    observed into the span families when it exits."""
+
+    __slots__ = ("name", "t0", "t1", "_tracer", "_detached", "_ann")
+
+    def __init__(self, tracer: "Tracer", name: str, detached: bool) -> None:
+        self.name = name
+        self.t0 = self.t1 = 0.0
+        self._tracer = tracer
+        self._detached = detached
+        self._ann = None
+
+    def annotate(self, **attrs) -> None:
+        """Attributes of the phase, kept where the phase is kept: on the
+        profiler's event."""
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
+
+    def __enter__(self) -> "_Phase":
+        self._ann = _annotate(self.name)
+        self.t0 = self._tracer.clock.now()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.t1 = self._tracer.clock.now()
+        _end(self._ann)
+        self._ann = None
+        if self._detached:
+            self._tracer._observe(self.name, self.t1 - self.t0,
+                                  self.t1 - self.t0)
+        return False
+
+
+class _NullPhase:
+    """Do-nothing phase of a disabled tracer."""
+
+    __slots__ = ()
+
+    name = ""
+    t0 = t1 = 0.0
+
+    def annotate(self, **attrs) -> None:
+        return None
+
+    def __enter__(self) -> "_NullPhase":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        return False
+
+
+NULL_PHASE = _NullPhase()
+
+
 class Trace:
     """One solve's span tree.  Context manager: exiting closes the root and
     hands the finished trace to the tracer (metrics + flight recorder)."""
@@ -260,6 +406,7 @@ class Trace:
                       span_id=f"s{self._n_spans}")
             parent.children.append(sp)
         stack.append(sp)
+        sp._ann = _annotate(name)
         return sp
 
     def record(self, name: str, t0: float, t1: float, **attrs):
@@ -279,6 +426,8 @@ class Trace:
         return sp
 
     def _close_span(self, span: Span) -> None:
+        _end(span._ann)
+        span._ann = None
         with self._lock:
             if span.t1 is None:
                 span.t1 = self._clock.now()
@@ -326,6 +475,31 @@ class Trace:
     def span_names(self) -> List[str]:
         return [sp.name for sp in self.spans()]
 
+    def closed_spans(self) -> List[Tuple[str, float, float]]:
+        """``(name, duration_s, self_s)`` of every closed span.  Self time
+        is the duration minus the union of the closed children's intervals
+        clipped to the span's own: children on other threads overlap, and a
+        recorded child may lie before its parent (it then covers nothing of
+        it and keeps its whole duration as its own)."""
+        out: List[Tuple[str, float, float]] = []
+        with self._lock:
+            stack = [self.root]
+            while stack:
+                sp = stack.pop()
+                stack.extend(sp.children)
+                if sp.t1 is None:
+                    continue
+                covered, edge = 0.0, sp.t0
+                for c0, c1 in sorted((c.t0, min(c.t1, sp.t1))
+                                     for c in sp.children
+                                     if c.t1 is not None):
+                    if c1 > edge:
+                        covered += c1 - max(c0, edge)
+                        edge = c1
+                dur = sp.duration_s
+                out.append((sp.name, dur, max(0.0, dur - covered)))
+        return out
+
     def to_dict(self) -> dict:
         """JSON-ready snapshot; safe to call mid-solve (anomaly dumps
         serialize in-flight traces — open spans carry ``end: null``)."""
@@ -350,7 +524,8 @@ class Tracer:
     ``KT_TRACE_SAMPLE_EVERY``) keeps one trace in every N starts, for
     high-rate deployments where even ring churn matters.  Finished traces
     are counted (``karpenter_trace_traces_total``), their spans observed
-    into ``karpenter_trace_span_duration_seconds{span=...}``, and handed to
+    into ``karpenter_trace_span_duration_seconds{span=...}`` and
+    ``karpenter_trace_span_self_seconds_total{span=...}``, and handed to
     the attached :class:`~karpenter_tpu.obs.recorder.FlightRecorder`.
     """
 
@@ -388,6 +563,29 @@ class Tracer:
         for outcome in TRACE_REMOTE_OUTCOMES:
             remote.inc({"outcome": outcome}, value=0.0)
         self.registry.histogram(TRACE_SPAN_DURATION)
+        self.registry.counter(TRACE_SPAN_SELF).inc(value=0.0)
+        if self.enabled:
+            _GC_WATCH.add(self.registry)
+
+    def phase(self, name: str, detached: bool = False):
+        """Time a phase that no open span can hold — the RPC's door, before
+        the root opens and after it has closed — as ``with
+        tracer.phase("request_decode") as ph:``.  The caller attaches it to
+        the request's tree with ``trace.record(ph.name, ph.t0, ph.t1)``;
+        ``detached=True`` is for a phase whose trace has already finished
+        (gRPC serialises the reply after the handler returns): it lands in
+        the span families alone.  :data:`NULL_PHASE` when disabled."""
+        if not self.enabled:
+            return NULL_PHASE
+        return _Phase(self, name, detached)
+
+    def _observe(self, name: str, duration_s: float, self_s: float) -> None:
+        labels = {"span": name}
+        self.registry.histogram(TRACE_SPAN_DURATION).observe(
+            max(0.0, duration_s), labels)
+        # span names are runtime data: the zero-init is the unlabeled sample
+        self.registry.counter(TRACE_SPAN_SELF).inc(
+            labels, value=max(0.0, self_s))
 
     def start(self, name: str, **attrs):
         """Begin a trace — ALWAYS as ``with tracer.start(...) as trace:``
@@ -448,10 +646,8 @@ class Tracer:
     def _finish(self, trace: Trace) -> None:
         trace.finish()
         self.registry.counter(TRACE_TRACES).inc()
-        hist = self.registry.histogram(TRACE_SPAN_DURATION)
-        for sp in trace.spans():
-            if sp.done:
-                hist.observe(sp.duration_s, {"span": sp.name})
+        for name, duration_s, self_s in trace.closed_spans():
+            self._observe(name, duration_s, self_s)
         for sink in self._sinks:
             try:
                 sink(trace)

@@ -713,25 +713,31 @@ class RemoteScheduler:
             # context crosses with the request, so the sidecar's trace
             # opens as a CHILD of this span (same trace id, remote parent
             # linked) instead of an unrelated tree — /fleetz renders the
-            # operator hop and the sidecar hop as one request
+            # operator hop and the sidecar hop as one request.  Its three
+            # children split it: "encode" and "decode" are this process's
+            # codec, "rpc" is everything else (both serialisations, both
+            # transports, the whole sidecar).
             with trace.span("remote", target=self.target) as span:
                 wire_tid, wire_parent = trace.wire_context()
-                req = codec.encode_request(
-                    pods, provisioners, instance_types,
-                    existing_nodes=existing_nodes, daemonsets=daemonsets,
-                    unavailable=unavailable, allow_new_nodes=allow_new_nodes,
-                    max_new_nodes=max_new_nodes, backend=self.backend,
-                    priority=self.priority,
-                    deadline_ms=(self.deadline_s * 1000.0
-                                 if self.deadline_s else None),
-                    trace_id=wire_tid, parent_span=wire_parent,
-                )
+                with trace.span("encode", n_pods=len(pods)):
+                    req = codec.encode_request(
+                        pods, provisioners, instance_types,
+                        existing_nodes=existing_nodes, daemonsets=daemonsets,
+                        unavailable=unavailable,
+                        allow_new_nodes=allow_new_nodes,
+                        max_new_nodes=max_new_nodes, backend=self.backend,
+                        priority=self.priority,
+                        deadline_ms=(self.deadline_s * 1000.0
+                                     if self.deadline_s else None),
+                        trace_id=wire_tid, parent_span=wire_parent,
+                    )
                 # the wire deadline budget also bounds the RPC itself: a
                 # caller with 250ms left must not block 60s on the channel
                 rpc_timeout = (min(self.client.timeout, self.deadline_s)
                                if self.deadline_s else None)
                 try:
-                    resp = self.client.solve_raw(req, timeout=rpc_timeout)
+                    with trace.span("rpc"):
+                        resp = self.client.solve_raw(req, timeout=rpc_timeout)
                 except grpc.RpcError as err:
                     code = (err.code()
                             if callable(getattr(err, "code", None)) else None)
@@ -796,12 +802,14 @@ class RemoteScheduler:
                     served_by = getattr(resp, "replica_id", "") or ""
                     if served_by:
                         span.annotate(replica=served_by)
-                    result = codec.decode_response(resp)
-                    # re-attach real PodSpecs to returned nodes (wire carries
-                    # names only)
-                    by_name = {p.name: p for p in pods}
-                    for node in result.nodes:
-                        node.pods = [by_name.get(p.name, p) for p in node.pods]
+                    with trace.span("decode"):
+                        result = codec.decode_response(resp)
+                        # re-attach real PodSpecs to returned nodes (wire
+                        # carries names only)
+                        by_name = {p.name: p for p in pods}
+                        for node in result.nodes:
+                            node.pods = [by_name.get(p.name, p)
+                                         for p in node.pods]
                     return result
         self.registry.counter(REMOTE_FALLBACK_SOLVES).inc()
         # recovery-outcome funnel (KT016): every local-fallback serve IS a

@@ -56,7 +56,7 @@ from ..obs import protocol, tracer_for
 from ..obs.occupancy import OccupancyAccountant
 from ..obs.slo import WINDOWS as SLO_WINDOWS, SloEngine
 from ..obs.timeseries import sampler_for
-from ..obs.trace import NULL_TRACE, Tracer
+from ..obs.trace import NULL_PHASE, NULL_TRACE, Tracer
 from ..parallel.forward import ResultForwarder, SlotNotOwned
 from ..solver import native as native_mod
 from ..solver.guard import DeviceHang
@@ -623,11 +623,18 @@ class SolvePipeline:
         bucket = getattr(self.scheduler, "bucket_key", None)
         if bucket is None:
             return None
+        # the probe hardens, routes and TENSORIZES the batch (the real
+        # solve's tensorize is then a cache hit): for a fresh 50k-pod batch
+        # it is the host build itself, so it gets a span of its own
+        trace = kwargs.get("trace") or NULL_TRACE
         # the probe itself never fails a request (bucket_key boxes its own
         # errors and returns None), but a facade without that contract must
         # not take the dispatcher down either
         try:
-            return bucket(kwargs)
+            with trace.span("bucket") as span:
+                key = bucket(kwargs)
+                span.annotate(bucketed=key is not None)
+            return key
         # ktlint: allow[KT005] probe failure = unbatchable, logged at the
         # scheduler layer; the request solves on the single path
         except Exception:
@@ -1377,6 +1384,9 @@ class SolverService:
         # /tracez sees exactly what its scheduler recorded
         self.tracer = tracer or getattr(
             self.scheduler, "tracer", None) or tracer_for(self.registry)
+        #: request_parse timestamps on their way from gRPC's deserialiser
+        #: to the handler, by id(request) (parse_request)
+        self._parse_times: dict = {}
         self._schedulers = {"": self.scheduler}  # guarded-by: _direct_lock
         # KT_SOLVE_PIPELINE=0 falls back to direct, lock-serialized solves
         self._pipelined = os.environ.get("KT_SOLVE_PIPELINE", "1") != "0"
@@ -1550,25 +1560,57 @@ class SolverService:
                     return max(0.0, float(rem))
         return None
 
+    # ---- the door: gRPC's own (de)serialisation, timed --------------------
+    def parse_request(self, data: bytes) -> pb.SolveRequest:
+        """Solve's ``request_deserializer`` (``make_server``), as the
+        ``request_parse`` phase.  gRPC runs it on the server's completion-
+        queue thread, not on the pool thread that then runs the handler,
+        so the timestamps cross to :meth:`Solve` by the request object's
+        id (the object lives from here to the handler's return)."""
+        with self.tracer.phase("request_parse") as ph:
+            request = pb.SolveRequest.FromString(data)
+        if ph is not NULL_PHASE:
+            if len(self._parse_times) >= 1024:
+                # only RPCs cancelled between parse and handler stay behind
+                self._parse_times.clear()
+            self._parse_times[id(request)] = (ph.t0, ph.t1)
+        return request
+
+    def serialize_response(self, resp: pb.SolveResponse) -> bytes:
+        """Solve's ``response_serializer``, as the detached
+        ``response_serialize`` phase: gRPC calls it on the handler's pool
+        thread after the handler has returned and the trace has finished."""
+        with self.tracer.phase("response_serialize", detached=True) as ph:
+            data = resp.SerializeToString()
+            ph.annotate(bytes=len(data))
+        return data
+
     def Solve(self, request: pb.SolveRequest, context) -> pb.SolveResponse:
-        kwargs = codec.decode_request(request)
-        # gang audit at the door (ISSUE 20, docs/GANGS.md): a malformed
-        # gang (members disagreeing on gang_size, oversubscribed roster)
-        # refuses WHOLE with INVALID_ARGUMENT before admission ever queues
-        # it — the gang is one ticket, so refusal is all-or-nothing too.
-        # A well-formed request stays one admission unit either way: a
-        # shed sheds the whole request, gangs included.
-        try:
-            gangmod.validate_batch(kwargs.get("pods", ()))
-        except gangmod.GangValidationError as err:
-            if context is None:
-                raise
-            context.abort(grpc.StatusCode.INVALID_ARGUMENT, str(err))
-        sess = codec.decode_delta_fields(request)
+        t_door = self.tracer.clock.now()
+        # the door (docs/OBSERVABILITY.md): decoding the request runs
+        # before the root span can open (the root adopts the wire trace
+        # context, which is IN the request), so it is timed as a phase and
+        # recorded into the tree once the root exists
+        with self.tracer.phase("request_decode") as door:
+            kwargs = codec.decode_request(request)
+            # gang audit at the door (ISSUE 20, docs/GANGS.md): a malformed
+            # gang (members disagreeing on gang_size, oversubscribed
+            # roster) refuses WHOLE with INVALID_ARGUMENT before admission
+            # ever queues it — the gang is one ticket, so refusal is
+            # all-or-nothing too.  A well-formed request stays one
+            # admission unit either way: a shed sheds the whole request,
+            # gangs included.
+            try:
+                gangmod.validate_batch(kwargs.get("pods", ()))
+            except gangmod.GangValidationError as err:
+                if context is None:
+                    raise
+                context.abort(grpc.StatusCode.INVALID_ARGUMENT, str(err))
+            sess = codec.decode_delta_fields(request)
+            wire_trace, wire_parent = codec.decode_trace_fields(request)
         sched = self._scheduler_for(request.backend)
         pclass = parse_class(getattr(request, "priority_class", ""))
         deadline_s = self._deadline_of(request, context)
-        wire_trace, wire_parent = codec.decode_trace_fields(request)
         # one trace per RPC, threaded through the pipeline's dispatch/
         # finalize boundary via the kwargs dict (the dispatcher records the
         # queue-wait "window" span on it; the scheduler opens tensorize/
@@ -1601,6 +1643,11 @@ class SolverService:
                    if gangmod.gang_enabled()
                    and gangmod.has_gangs(kwargs.get("pods", ())) else {}),
             ) as trace:
+                parsed = self._parse_times.pop(id(request), None)
+                if parsed is not None:
+                    trace.record("request_parse", *parsed)
+                trace.record("request_decode", door.t0, door.t1,
+                             n_pods=len(kwargs.get("pods", ())))
                 kwargs["trace"] = trace
                 if self._pipelined:
                     pipe = self._pipeline_for(sched)
@@ -1643,7 +1690,11 @@ class SolverService:
                     # correlation keys on it
                     resp.replica_id = self.tracer.replica
             slo_outcome = "ok"
-            slo_ms = float(getattr(result, "solve_ms", 0.0) or 0.0) or None
+            # door to door on the tracer's clock: start of request_decode
+            # to the close of the root.  (result.solve_ms is the host
+            # clock around the device fence on the device tier — 15 ms of
+            # a request the client waits 3.9 s for.)
+            slo_ms = (self.tracer.clock.now() - t_door) * 1000.0
         except SolveDeadlineError as err:
             # shed BEFORE tensorize/dispatch: the wire contract is
             # DEADLINE_EXCEEDED for expired budgets, RESOURCE_EXHAUSTED for
@@ -1702,8 +1753,8 @@ def make_server(
     handlers = {
         "Solve": grpc.unary_unary_rpc_method_handler(
             service.Solve,
-            request_deserializer=pb.SolveRequest.FromString,
-            response_serializer=pb.SolveResponse.SerializeToString,
+            request_deserializer=service.parse_request,
+            response_serializer=service.serialize_response,
         ),
         "Warm": grpc.unary_unary_rpc_method_handler(
             service.Warm,

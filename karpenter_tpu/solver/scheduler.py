@@ -956,7 +956,8 @@ class BatchScheduler:
         t0 = time.perf_counter()
         trace = trace or NULL_TRACE
         trace.annotate(backend=self.backend, n_pods=len(pods))
-        hardened = [_harden_preferences(p) for p in pods]
+        with trace.span("harden"):
+            hardened = [_harden_preferences(p) for p in pods]
         try:
             # the dispatch span covers tensorize + H2D + device enqueue on
             # the async path; on the sync/oracle path it covers the whole
@@ -1397,9 +1398,11 @@ class BatchScheduler:
             return result  # the rung refines the device scan only
         if not allow_new_nodes or max_new_nodes is not None:
             return result
-        tpu_pods = [p for p in hardened if not device_inexpressible(p)]
-        if (not tpu_pods or len(tpu_pods) <= self.native_batch_limit
-                or batch_needs_oracle(hardened)):
+        with trace.span("carve"):
+            tpu_pods = [p for p in hardened if not device_inexpressible(p)]
+            scan_refinable = (len(tpu_pods) > self.native_batch_limit
+                              and not batch_needs_oracle(hardened))
+        if not scan_refinable:
             # small batches are oracle-grade already (and under auto the
             # oracle served them — no scan to refine); the rung targets
             # LARGE unconstrained groups on every backend, so forced-tpu
@@ -1875,9 +1878,11 @@ class BatchScheduler:
         fences the async dispatch (the pipelined-overlap window lives
         between the two)."""
         trace = trace or NULL_TRACE
-        # carve out pods the device solver can't express (rare shapes only)
-        tpu_pods = [p for p in pods if not device_inexpressible(p)]
-        cpu_pods = [p for p in pods if device_inexpressible(p)]
+        # carve out pods the device solver can't express (rare shapes
+        # only — but finding that out is two passes over the batch)
+        with trace.span("carve"):
+            tpu_pods = [p for p in pods if not device_inexpressible(p)]
+            cpu_pods = [p for p in pods if device_inexpressible(p)]
 
         # positive affinity couples the two batches: whichever side's
         # affinity selectors match the other side's pods must solve SECOND,

@@ -1,0 +1,171 @@
+#!/usr/bin/env python3
+"""One cell of the benchmark with the CLIENT traced too (ISSUE 25).
+
+    chiprun -- python scripts/trace_probe.py --workload c2.burst [--seed N]
+
+The benchmark's readers see the sidecar's ``/metrics`` only, so its
+``client_ms`` is a subtraction.  This drives the same cell through the
+benchmark's own entry (``benchmarks/run.py run_cell``: same sidecar, traffic,
+warm-up, window and comparison) and hands every ``RemoteScheduler.solve`` a
+trace of a client-side tracer, so the client's ``encode``/``rpc``/``decode``
+spans can be set beside that number.  It also scrapes the sidecar after each
+request (outside the request's wall, inside the window: this is a probe, not
+a measurement of ``solve_ms``), which gives per request the door spans, the
+root and the collector's pauses on both sides — the position pattern of a
+pass, split by side.
+
+Prints one JSON object (also written to
+``chiprun_out/trace_probe.<cell>.<platform>.json``): the run's metrics as the benchmark read them, the sidecar's spans per request
+(duration and self time), the client's spans per request, and the per-request
+rows.  Needs a TPU exactly as the benchmark does.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmarks")
+
+M_SUM = "karpenter_trace_span_duration_seconds_sum"
+M_COUNT = "karpenter_trace_span_duration_seconds_count"
+M_SELF = "karpenter_trace_span_self_seconds_total"
+M_GC = "karpenter_process_gc_pause_seconds_total"
+
+
+def by_label(samples: list, name: str, label: str) -> dict:
+    return {lab[label]: v for n, lab, v in samples
+            if n == name and label in lab}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=2147483659)
+    ap.add_argument("--seconds", type=float, default=12.0)
+    ap.add_argument("--platform", default="tpu")
+    ap.add_argument("--scale", type=float, default=1.0)
+    args = ap.parse_args(argv)
+    sidecar_env = dict(os.environ)
+    os.environ["JAX_PLATFORMS"] = "cpu"  # this process is the operator
+    for path in (BENCH, ROOT):
+        if path not in sys.path:
+            sys.path.insert(0, path)
+    import run
+    import scrape as S
+    from karpenter_tpu.metrics import Registry
+    from karpenter_tpu.obs import FlightRecorder, Tracer
+
+    creg = Registry()
+    ctracer = Tracer(registry=creg, flight=FlightRecorder(registry=creg))
+    rows: list = []
+    state: dict = {}
+
+    def server_now() -> dict:
+        samples = S.scrape(state["run"].sidecar.metrics_url)
+        return {"sum": by_label(samples, M_SUM, "span"),
+                "gc": by_label(samples, M_GC, "generation")}
+
+    def client_gc() -> dict:
+        return {g: creg.counter(M_GC).get({"generation": g}) for g in "012"}
+
+    def tamper(remote):
+        inner = remote.solve
+
+        def solve(pods, provisioners, catalog, **kw):
+            if "before" not in state:
+                state["before"] = server_now()
+            gc0 = client_gc()
+            with ctracer.start("provision", n_pods=len(pods)) as trace:
+                res = inner(pods, provisioners, catalog, trace=trace, **kw)
+            gc1, after = client_gc(), server_now()
+            before, state["before"] = state["before"], after
+            spans = {n: d for n, d, _s in trace.closed_spans()}
+            rows.append({
+                "wall_ms": trace.duration_s * 1000.0,
+                "client_ms": {k: spans.get(k, 0.0) * 1000.0 for k in
+                              ("remote", "encode", "rpc", "decode")},
+                "client_gc_ms": {g: (gc1[g] - gc0[g]) * 1000.0 for g in gc1},
+                "server_ms": {
+                    k: (after["sum"].get(k, 0.0)
+                        - before["sum"].get(k, 0.0)) * 1000.0
+                    for k in ("request_parse", "request_decode", "solve",
+                              "response_serialize")},
+                "server_gc_ms": {
+                    g: (after["gc"].get(g, 0.0)
+                        - before["gc"].get(g, 0.0)) * 1000.0
+                    for g in sorted(after["gc"])},
+            })
+            return res
+
+        remote.solve = solve
+        return remote
+
+    # the benchmark's own window scrapes, as its readers were handed them
+    window: dict = {}
+    read_layer_metrics = run.read_layer_metrics
+
+    def capture(bench, workload, ctx):
+        window.update(ctx)
+        return read_layer_metrics(bench, workload, ctx)
+
+    run.read_layer_metrics = capture
+    run_init = run.Run.__init__
+
+    def remember(self, *a, **kw):
+        run_init(self, *a, **kw)
+        state["run"] = self
+
+    run.Run.__init__ = remember
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    try:
+        line = run.run_cell(bench, args.workload, args.seed, args.seconds, 0,
+                            platform=args.platform, scale=args.scale,
+                            tamper=tamper, sidecar_env=sidecar_env)
+    except run.RunFailed as err:
+        print(f"probe failed: {err}", file=sys.stderr, flush=True)
+        return 1
+    n = line["attempted"]
+    timed = rows[-n:]
+    before, after = window["before"], window["after"]
+    spans = {}
+    for name in sorted(by_label(after, M_COUNT, "span")):
+        count = S.delta(before, after, M_COUNT, span=name)
+        if count:
+            spans[name] = {
+                "per_request": count / n,
+                "duration_ms": S.delta(before, after, M_SUM,
+                                       span=name) / n * 1000.0,
+                "self_ms": S.delta(before, after, M_SELF,
+                                   span=name) / n * 1000.0}
+
+    def mean(key, sub):
+        return sum(r[key][sub] for r in timed) / n
+
+    out = {
+        "workload": args.workload, "seed": args.seed, "requests": n,
+        "correct": line["correct"], "device": line["device"],
+        "metrics": {k: v["value"] for k, v in line["metrics"].items()},
+        "sidecar_spans": spans,
+        "client_spans_ms": {k: mean("client_ms", k) for k in
+                            ("remote", "encode", "rpc", "decode")},
+        "client_gc_ms": {g: mean("client_gc_ms", g) for g in "012"},
+        "requests_in_order": timed,
+    }
+    out["client_encode_plus_decode_ms"] = (
+        out["client_spans_ms"]["encode"] + out["client_spans_ms"]["decode"])
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    name = f"trace_probe.{args.workload}.{args.platform}.json"
+    with open(os.path.join(ROOT, "chiprun_out", name), "w") as f:
+        json.dump(out, f, indent=1)
+    print(json.dumps(out))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
