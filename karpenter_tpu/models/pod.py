@@ -19,6 +19,7 @@ from .requirements import EXISTS, IN, Requirement, Requirements
 from .resources import ResourceList
 
 _pod_counter = itertools.count()
+_new_pod = object.__new__
 
 
 @dataclass(frozen=True)
@@ -121,7 +122,22 @@ class PodAffinityTerm:
 
 @dataclass
 class PodSpec:
-    """One pending pod as seen by the scheduler."""
+    """One pending pod as seen by the scheduler.
+
+    Field containers are shared between pods and are replaced, never
+    written in place.  A pod's ``labels``, ``requests``, ``node_selector``,
+    its lists of terms, tolerations, spreads and claims, and the frozen
+    objects inside them may be the very objects of another pod: the
+    sidecar decodes the pods of one deployment from one template
+    (:meth:`like`, ``service/codec.PodTemplates``), and in-process callers
+    hand in pods built around one ``LabelSelector``.  Whoever needs a pod
+    with another value copies the pod and REBINDS the field to a new
+    container (``q = copy.copy(p); q.node_selector = {**p.node_selector,
+    ...}``), then drops the memoised ``_group_key`` of the copy — as
+    ``_harden_preferences``, the gang epilogue and
+    ``VolumeTopology.inject`` do.  ``p.labels[k] = v`` on a pod someone
+    else built changes its siblings too (``tests/test_codec_templates.py``
+    holds the package to this)."""
 
     name: str = ""
     namespace: str = "default"
@@ -157,6 +173,24 @@ class PodSpec:
     def __post_init__(self) -> None:
         if not self.name:
             self.name = f"pod-{self.uid}"
+
+    def template(self) -> dict:
+        """This pod's field values, for :meth:`like`: a private snapshot,
+        so nothing memoised on the pod afterwards (``_group_key``) and no
+        later rebinding of its fields reaches the pods stamped from it."""
+        fields = dict(self.__dict__)
+        fields.pop("_group_key", None)
+        return fields
+
+    @staticmethod
+    def like(fields: dict, name: str) -> "PodSpec":
+        """A pod with ``fields`` (a :meth:`template`), its own non-empty
+        ``name`` and the next ``uid``: equal to what the constructor gives
+        for the same values, but SHARING the template's containers (class
+        docstring) instead of building twenty objects again."""
+        pod = _new_pod(PodSpec)
+        pod.__dict__ = {**fields, "name": name, "uid": next(_pod_counter)}
+        return pod
 
     # ---- requirement extraction --------------------------------------
     def scheduling_requirements(self, relax_preferred: int = 0) -> List[Requirements]:

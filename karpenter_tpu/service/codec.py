@@ -270,6 +270,97 @@ def decode_pod(p: pb.Pod) -> PodSpec:
     )
 
 
+class PodTemplates:
+    """The pod shapes of ONE request: a ``PodSpec`` is built once per
+    distinct shape, not once per pod.
+
+    A Solve carries the pods of a few deployments (20 in a 50,000-pod
+    batch), and pods of one deployment differ in ``name`` only.  A pod's
+    shape key is its own serialized bytes after the leading ``name`` field:
+    equal keys mean equal bytes, so equal fields.  The first pod of a key
+    goes through :func:`decode_pod`; every later one is stamped from that
+    pod's field values (``PodSpec.like``) with its own name and the next
+    uid, and SHARES the first one's containers — the copy-on-write
+    convention stated at ``PodSpec``.  A key that differs for two equal
+    pods (map order) costs a plain decode, never a wrong answer.
+
+    The table watches its own hit share: if, ``PROBE`` pods into the
+    request, fewer than half were hits, the rest decode plainly (a batch
+    of all-distinct pods would only pay for the keys).  One table serves
+    one request — ``pods``, the pods of ``existing_nodes``, ``daemonsets``
+    — and is dropped with it; nothing is kept between requests."""
+
+    #: pods a request decodes before the table judges its hit share
+    PROBE = 512
+
+    def __init__(self) -> None:
+        self._fields: Dict[bytes, dict] = {}
+        self._seen = 0
+        self._live = True
+        #: pods stamped from a template / pods that went through decode_pod
+        self.templated_pods = 0
+        self.plain_pods = 0
+
+    @property
+    def templates(self) -> int:
+        """Distinct shapes the table holds (it stops adding to them once
+        it has given up on the request)."""
+        return len(self._fields)
+
+    @staticmethod
+    def _key(p: pb.Pod, name: str) -> Optional[bytes]:
+        """``p``'s bytes after its ``name`` field, or None where they do
+        not start with exactly that field (tag 0x0a, varint length, the
+        name): an empty name (proto3 leaves the field out, and the
+        constructor then names the pod by its uid), a name of 16 KiB."""
+        data = p.SerializeToString()
+        raw = name.encode()
+        n = len(raw)
+        if 0 < n < 0x80:
+            at = 2
+            ok = data[1] == n
+        elif 0x80 <= n < 0x4000:
+            at = 3
+            ok = data[1] == (n & 0x7F) | 0x80 and data[2] == n >> 7
+        else:
+            return None
+        if ok and data[0] == 0x0A and data.startswith(raw, at):
+            return data[at + n:]
+        return None
+
+    def decode(self, pods) -> List[PodSpec]:
+        """``[decode_pod(p) for p in pods]``, field for field and uid for
+        uid, by template."""
+        if not self._live:
+            self.plain_pods += len(pods)
+            return [decode_pod(p) for p in pods]
+        fields, key_of, like = self._fields, self._key, PodSpec.like
+        seen, hits = self._seen, 0
+        out: List[PodSpec] = []
+        it = iter(pods)
+        for p in it:
+            name = p.name
+            key = key_of(p, name)
+            shape = None if key is None else fields.get(key)
+            if shape is not None:
+                out.append(like(shape, name))
+                hits += 1
+            else:
+                pod = decode_pod(p)
+                if key is not None:
+                    fields[key] = pod.template()
+                out.append(pod)
+            seen += 1
+            if seen == self.PROBE and 2 * (self.templated_pods + hits) < seen:
+                self._live = False
+                out.extend(decode_pod(q) for q in it)
+                break
+        self._seen = seen
+        self.templated_pods += hits
+        self.plain_pods += len(out) - hits
+        return out
+
+
 def decode_instance_type(it: pb.InstanceType) -> InstanceType:
     return InstanceType(
         name=it.name,
@@ -323,7 +414,8 @@ def decode_provisioner(p: pb.Provisioner) -> Provisioner:
     )
 
 
-def decode_node(n: pb.ExistingNode) -> SimNode:
+def decode_node(n: pb.ExistingNode,
+                templates: Optional[PodTemplates] = None) -> SimNode:
     return SimNode(
         instance_type=n.instance_type,
         provisioner=n.provisioner,
@@ -333,19 +425,25 @@ def decode_node(n: pb.ExistingNode) -> SimNode:
         allocatable=_qdict(n.allocatable),
         labels=dict(n.labels),
         taints=[Taint(t.key, t.effect, t.value) for t in n.taints],
-        pods=[decode_pod(p) for p in n.pods],
+        pods=(PodTemplates() if templates is None
+              else templates).decode(n.pods),
         existing=True,
         name=n.name,
     )
 
 
-def decode_request(req: pb.SolveRequest):
+def decode_request(req: pb.SolveRequest,
+                   templates: Optional[PodTemplates] = None):
+    """``templates``: the caller's table for THIS request, handed in only
+    so that its counts can be read afterwards (``SolverService.Solve``)."""
+    if templates is None:
+        templates = PodTemplates()
     return dict(
-        pods=[decode_pod(p) for p in req.pods],
+        pods=templates.decode(req.pods),
         provisioners=[decode_provisioner(p) for p in req.provisioners],
         instance_types=[decode_instance_type(t) for t in req.instance_types],
-        existing_nodes=[decode_node(n) for n in req.existing_nodes],
-        daemonsets=[decode_pod(p) for p in req.daemonsets],
+        existing_nodes=[decode_node(n, templates) for n in req.existing_nodes],
+        daemonsets=templates.decode(req.daemonsets),
         unavailable={(u.instance_type, u.zone, u.capacity_type) for u in req.unavailable},
         allow_new_nodes=req.allow_new_nodes,
         max_new_nodes=req.max_new_nodes if req.has_max_new_nodes else None,
@@ -446,11 +544,12 @@ def decode_delta_reply(resp: pb.SolveResponse):
 
 
 def decode_warm_request(req: pb.WarmRequest):
+    templates = PodTemplates()
     return dict(
         provisioners=[decode_provisioner(p) for p in req.provisioners],
         instance_types=[decode_instance_type(t) for t in req.instance_types],
-        daemonsets=[decode_pod(p) for p in req.daemonsets],
-        existing_nodes=[decode_node(n) for n in req.existing_nodes],
+        daemonsets=templates.decode(req.daemonsets),
+        existing_nodes=[decode_node(n, templates) for n in req.existing_nodes],
     )
 
 

@@ -49,6 +49,8 @@ from ..metrics import (
     OCCUPANCY_DELTA_INLINE,
     OCCUPANCY_DEVICE_BUSY,
     OCCUPANCY_SLOT_FILL,
+    REQUEST_DECODE_HOW,
+    REQUEST_DECODE_PODS,
     Registry,
     registry as default_registry,
 )
@@ -1387,6 +1389,9 @@ class SolverService:
         #: request_parse timestamps on their way from gRPC's deserialiser
         #: to the handler, by id(request) (parse_request)
         self._parse_times: dict = {}
+        for how in REQUEST_DECODE_HOW:
+            self.registry.counter(REQUEST_DECODE_PODS).inc(
+                {"how": how}, value=0)
         self._schedulers = {"": self.scheduler}  # guarded-by: _direct_lock
         # KT_SOLVE_PIPELINE=0 falls back to direct, lock-serialized solves
         self._pipelined = os.environ.get("KT_SOLVE_PIPELINE", "1") != "0"
@@ -1592,7 +1597,12 @@ class SolverService:
         # context, which is IN the request), so it is timed as a phase and
         # recorded into the tree once the root exists
         with self.tracer.phase("request_decode") as door:
-            kwargs = codec.decode_request(request)
+            # one table of pod shapes per request, dropped with it
+            shapes = codec.PodTemplates()
+            kwargs = codec.decode_request(request, shapes)
+            decoded = self.registry.counter(REQUEST_DECODE_PODS)
+            decoded.inc({"how": "templated"}, value=shapes.templated_pods)
+            decoded.inc({"how": "plain"}, value=shapes.plain_pods)
             # gang audit at the door (ISSUE 20, docs/GANGS.md): a malformed
             # gang (members disagreeing on gang_size, oversubscribed
             # roster) refuses WHOLE with INVALID_ARGUMENT before admission
@@ -1647,7 +1657,9 @@ class SolverService:
                 if parsed is not None:
                     trace.record("request_parse", *parsed)
                 trace.record("request_decode", door.t0, door.t1,
-                             n_pods=len(kwargs.get("pods", ())))
+                             n_pods=len(kwargs.get("pods", ())),
+                             templates=shapes.templates,
+                             templated_pods=shapes.templated_pods)
                 kwargs["trace"] = trace
                 if self._pipelined:
                     pipe = self._pipeline_for(sched)

@@ -12,7 +12,8 @@ spans can be set beside that number.  It also scrapes the sidecar after each
 request (outside the request's wall, inside the window: this is a probe, not
 a measurement of ``solve_ms``), which gives per request the door spans, the
 root and the collector's pauses on both sides — the position pattern of a
-pass, split by side.
+pass, split by side — and how many of its pods the sidecar stamped from a
+template (``request_decode.hit_share``).
 
 Prints one JSON object (also written to
 ``chiprun_out/trace_probe.<cell>.<platform>.json``): the run's metrics as the benchmark read them, the sidecar's spans per request
@@ -34,6 +35,7 @@ M_SUM = "karpenter_trace_span_duration_seconds_sum"
 M_COUNT = "karpenter_trace_span_duration_seconds_count"
 M_SELF = "karpenter_trace_span_self_seconds_total"
 M_GC = "karpenter_process_gc_pause_seconds_total"
+M_DECODED = "karpenter_solver_request_decode_pods_total"
 
 
 def by_label(samples: list, name: str, label: str) -> dict:
@@ -67,7 +69,8 @@ def main(argv=None) -> int:
     def server_now() -> dict:
         samples = S.scrape(state["run"].sidecar.metrics_url)
         return {"sum": by_label(samples, M_SUM, "span"),
-                "gc": by_label(samples, M_GC, "generation")}
+                "gc": by_label(samples, M_GC, "generation"),
+                "decoded": by_label(samples, M_DECODED, "how")}
 
     def client_gc() -> dict:
         return {g: creg.counter(M_GC).get({"generation": g}) for g in "012"}
@@ -98,6 +101,10 @@ def main(argv=None) -> int:
                     g: (after["gc"].get(g, 0.0)
                         - before["gc"].get(g, 0.0)) * 1000.0
                     for g in sorted(after["gc"])},
+                "server_decoded_pods": {
+                    how: after["decoded"].get(how, 0.0)
+                    - before["decoded"].get(how, 0.0)
+                    for how in sorted(after["decoded"])},
             })
             return res
 
@@ -144,6 +151,9 @@ def main(argv=None) -> int:
                 "self_ms": S.delta(before, after, M_SELF,
                                    span=name) / n * 1000.0}
 
+    decoded = {how: S.delta(before, after, M_DECODED, how=how) / n
+               for how in by_label(after, M_DECODED, "how")}
+
     def mean(key, sub):
         return sum(r[key][sub] for r in timed) / n
 
@@ -152,6 +162,14 @@ def main(argv=None) -> int:
         "correct": line["correct"], "device": line["device"],
         "metrics": {k: v["value"] for k, v in line["metrics"].items()},
         "sidecar_spans": spans,
+        # beside request_decode: how many of a request's pods were stamped
+        # from a template (a sidecar without the family reads nothing)
+        "request_decode": {
+            "duration_ms": spans.get("request_decode", {}).get("duration_ms"),
+            "pods_per_request": decoded,
+            "hit_share": (decoded.get("templated", 0.0)
+                          / sum(decoded.values()) if any(decoded.values())
+                          else None)},
         "client_spans_ms": {k: mean("client_ms", k) for k in
                             ("remote", "encode", "rpc", "decode")},
         "client_gc_ms": {g: mean("client_gc_ms", g) for g in "012"},

@@ -136,7 +136,11 @@ def test_inventory_metrics_are_emitted(small_catalog):
     # their emission is asserted by tests/test_split_topology.py:118-144 and
     # tests/test_service.py:217-232, so this single-process scenario carves
     # them out rather than spinning up a gRPC sidecar here
-    from karpenter_tpu.metrics import REMOTE_DEGRADED, REMOTE_FALLBACK_SOLVES
+    from karpenter_tpu.metrics import (
+        REMOTE_DEGRADED,
+        REMOTE_FALLBACK_SOLVES,
+        REQUEST_DECODE_PODS,
+    )
 
     # likewise the admission family: emitted by the solver SERVICE's
     # AdmissionControl (one per SolvePipeline), which this in-process
@@ -204,10 +208,15 @@ def test_inventory_metrics_are_emitted(small_catalog):
     replay_family = {m for m in INVENTORY
                      if m.startswith("karpenter_replay_")}
 
+    # the door's pod counter (ISSUE 26) is service-side as well: zero-
+    # inited where SolverService is constructed and moved by every Solve
+    # RPC, both asserted by tests/test_codec_templates.py (the ``served``
+    # fixture and test_the_door_counts_what_it_stamped)
     missing = (set(INVENTORY) - emitted - admission_family - delta_family
                - resilience_family - fleet_family - multihost_shim
                - replay_family - slo_family - tuning_family
-               - {REMOTE_DEGRADED, REMOTE_FALLBACK_SOLVES})
+               - {REMOTE_DEGRADED, REMOTE_FALLBACK_SOLVES,
+                  REQUEST_DECODE_PODS})
     assert not missing, (
         f"documented metrics never emitted: {sorted(missing)} "
         f"(warm debug: in_flight={auto_sched._tpu.compiles_in_flight()} "

@@ -289,9 +289,9 @@ def test_the_slo_engine_is_fed_door_to_door_not_the_fence(
                                        tracer=tracer), registry=reg)
     inner = codec.decode_request
 
-    def slow_decode(request):
+    def slow_decode(request, *table):
         clock.advance(2.0)  # the 50,000 pods of the north-star request
-        return inner(request)
+        return inner(request, *table)
 
     monkeypatch.setattr(codec, "decode_request", slow_decode)
     prov = Provisioner(name="default").with_defaults()
