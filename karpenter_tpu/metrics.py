@@ -185,6 +185,14 @@ REQUEST_DECODE_PODS = "karpenter_solver_request_decode_pods_total"
 #: field by field: the first pod of each shape, a pod whose bytes give no
 #: shape key, and every pod after the table gave up on its request)
 REQUEST_DECODE_HOW = ("templated", "plain")
+# ---- the device scan's axes (solver/tpu.py TpuSolver._count_scan) --------
+SCAN_AXIS = "karpenter_solver_scan_axis_total"
+#: what a device scan ran at (KT003 zero-init source): 'groups' (serial
+#: steps that carry pods: one per distinct pod shape), 'groups_padded' (the
+#: steps the compiled program takes: the G rung), 'node_slots' (the NR rung
+#: every step carries) and 'nodes_used' (slots in use when the scan ended)
+SCAN_AXES = ("groups", "groups_padded", "node_slots", "nodes_used")
+SCAN_SLOT_RETRIES = "karpenter_solver_scan_slot_retries_total"
 TRACE_RING_EVICTIONS = "karpenter_trace_ring_evictions_total"
 FLIGHT_DUMPS = "karpenter_trace_flight_recorder_dumps_total"
 # ---- fleet-wide tracing (ISSUE 15: wire-propagated trace context) -------
@@ -565,6 +573,30 @@ INVENTORY = {
         "up.  Pods of one deployment differ in name only, so a healthy "
         "provisioning batch reads almost all 'templated' (50,000 pods in "
         "20 deployments: 49,980).  No table outlives its request."),
+    SCAN_AXIS: (
+        "counter", ("axis",),
+        "What the device scans ran at, summed over device solves (one "
+        "increment per axis per fenced scan — the single, the pipelined "
+        "and each megabatch slot; a slot retry counts both scans): "
+        "'groups' — pod groups of the batch, one serial scan step each "
+        "(a group is a set of pods with equal PodSpec.group_key(), so "
+        "every Deployment is its own); 'groups_padded' — the steps the "
+        "compiled program takes (solve_dims' G rung; the difference is "
+        "padding); 'node_slots' — the NR rung, the node rows every step "
+        "carries, from _nr_estimate or the full budget; 'nodes_used' — "
+        "slots in use when the scan ended, existing nodes included.  "
+        "Divide by the solves of the same window for per-solve means; "
+        "node_slots far above nodes_used is an estimate that charges "
+        "device time for rows nothing lands on."),
+    SCAN_SLOT_RETRIES: (
+        "counter", (),
+        "Device scans whose optimistic node-slot axis (_nr_estimate) ran "
+        "out with pods still unplaced, so the batch was solved again at "
+        "the full budget (or, under compile-behind with that program "
+        "cold, served from the warm tier while it compiles).  The shape "
+        "family goes straight to the full program from then on.  Rare "
+        "by construction — the estimate is doubled; a steady rise means "
+        "the estimate is short for this fleet's shapes."),
     TRACE_RING_EVICTIONS: (
         "counter", (),
         "Traces evicted from the flight recorder's bounded ring to admit "

@@ -26,6 +26,7 @@ Greedy smallest-first within each bucket; deterministic.
 
 from __future__ import annotations
 
+from bisect import insort
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -233,7 +234,7 @@ def coalesce_new_nodes(
             smallest-first window would fill with ONE service's nodes, whose
             pairs all violate the per-node cap; rotating group combos puts
             mergeable cross-service partners inside the window."""
-            base = sorted(lst, key=lambda n: (size_of(n), n.name))
+            base = sorted(lst, key=plain_key)
             if not host_active:
                 return base
             seen: Dict[frozenset, int] = {}
@@ -296,25 +297,32 @@ def coalesce_new_nodes(
                 _size[id(n)] = got
             return got
 
+        def plain_key(n: SimNode) -> tuple:
+            return size_of(n), n.name
+
         def eval_pairs(window: List[SimNode]) -> None:
             """Fill pair_best for every uncached pair in the window.  Only
             pairs touching a node new to the window since the last eval can
             be uncached (pair feasibility is unaffected by other merges), so
             enumeration is O(new x W), not O(W^2) per scan."""
             w = len(window)
-            new_ix = [i for i in range(w) if id(window[i]) not in _seen]
+            ids = [id(n) for n in window]
+            new_ix = [i for i in range(w) if ids[i] not in _seen]
             if not new_ix:
                 return
             new_set = set(new_ix)
-            fresh = []
+            fresh, keys = [], []  # (i, j) with i < j, and the pair's _pkey
             for i in new_ix:
+                ia = ids[i]
                 for j in range(w):
                     if j == i or (j in new_set and j < i):
                         continue
-                    a, b = (i, j) if i < j else (j, i)
-                    if _pkey(window[a], window[b]) not in pair_best:
-                        fresh.append((a, b))
-            _seen.update(id(window[i]) for i in new_ix)
+                    ib = ids[j]
+                    key = (ia, ib) if ia < ib else (ib, ia)
+                    if key not in pair_best:
+                        fresh.append((i, j) if i < j else (j, i))
+                        keys.append(key)
+            _seen.update(ids[i] for i in new_ix)
             if not fresh:
                 return
             ai = np.asarray([i for i, _ in fresh])
@@ -348,14 +356,13 @@ def coalesce_new_nodes(
             ks = np.empty(len(fresh), dtype=np.int64)
             if hits.size:
                 ks[hits] = np.where(ok[hits], c_price[None, :], np.inf).argmin(axis=1)
-            for p, (i, j) in enumerate(fresh):
-                a, b = window[i], window[j]
-                if any_p[p]:
-                    pair_best[_pkey(a, b)] = (float(c_price[ks[p]]), int(ks[p]))
-                    partners.setdefault(id(a), set()).add(id(b))
-                    partners.setdefault(id(b), set()).add(id(a))
-                else:
-                    pair_best[_pkey(a, b)] = None
+            for p in np.flatnonzero(~any_p).tolist():
+                pair_best[keys[p]] = None
+            for p in hits.tolist():
+                ia, ib = keys[p]
+                pair_best[keys[p]] = (float(c_price[ks[p]]), int(ks[p]))
+                partners.setdefault(ia, set()).add(ib)
+                partners.setdefault(ib, set()).add(ia)
 
         group = order_nodes(group)
         while len(group) >= 2:
@@ -404,20 +411,29 @@ def coalesce_new_nodes(
                 _hstate[id(node)] = (ca + cb, np.minimum(pa, pb))
             if node_groups is not None:
                 node_groups[id(node)] = set(groups_of(a) | groups_of(b))
+            # one hop each; an absorbed node may itself be a prior
+            # replacement, so the chains are followed once, at the end
             renames[a.name] = node.name
             renames[b.name] = node.name
-            # an absorbed node may itself be a prior replacement:
-            # forward earlier renames pointing at it
-            for old, tgt in list(renames.items()):
-                if tgt in (a.name, b.name):
-                    renames[old] = node.name
             # absorbed nodes leave the partner graph (their ids must not
             # surface as hits in later scans)
             for gone in (id(a), id(b)):
                 for other in partners.pop(gone, ()):  # symmetric cleanup
                     partners.get(other, set()).discard(gone)
-            group = order_nodes(
-                [n for idx, n in enumerate(group) if idx not in (i, j)] + [node]
-            )
+            del group[j], group[i]  # i < j, both inside the window
+            if host_active:
+                group = order_nodes(group + [node])
+            else:
+                # the plain order is a total one (names are unique), so
+                # taking two out and putting one in keeps the list what a
+                # full sort would give — at a bisection, not a sort of the
+                # bucket, per merge (a long-tailed batch merges thousands
+                # of fragments per bucket)
+                insort(group, node, key=plain_key)
         out.extend(group)
+    # forward every absorbed name to the node that finally holds its pods:
+    # a replacement absorbed later was entered later, so walking the map
+    # backwards finds each target already resolved
+    for old in reversed(renames):
+        renames[old] = renames.get(renames[old], renames[old])
     return out, renames

@@ -318,26 +318,20 @@ def warm_relax(solver, st, relax_iters: Optional[int] = None,
 
 def host_feasibility(st) -> np.ndarray:
     """Numpy mirror of the device feasibility (labels & fit & provisioner)
-    — byte-identical semantics to ops/feasibility's gather path, cheap at
-    group granularity ([G, C, K] bit gathers)."""
+    — byte-identical semantics to ops/feasibility's gather path.  The label
+    and provisioner part is ``coalesce.label_feasibility``, which the
+    extraction of the same tensors has already computed and cached on
+    them."""
+    from .coalesce import label_feasibility
+
     G, C = st.G, st.C
     if G == 0 or C == 0:
         return np.zeros((G, C), dtype=bool)
-    K = st.pm.shape[1]
-    vw = np.asarray(st.cand_vw)                      # [C, K]
-    vb = np.asarray(st.cand_vb).astype(np.uint32)
-    g_idx = np.arange(G)[:, None, None]              # [G, 1, 1]
-    k_idx = np.arange(K)[None, None, :]              # [1, 1, K]
-    words = np.asarray(st.pm)[g_idx, k_idx, vw[None, :, :]]  # [G, C, K]
-    bits = ((words >> vb[None, :, :]) & np.uint32(1)).astype(bool)
-    lab = np.all(bits | ~np.asarray(st.key_check)[None, None, :], axis=2)
     req = np.asarray(st.requests, dtype=np.float32)  # [G, R]
     alloc = np.asarray(st.cand_alloc, dtype=np.float32)
     fit = np.all((req[:, None, :] <= alloc[None, :, :] + 1e-6)
                  | (req[:, None, :] <= 0), axis=2)
-    gp = np.asarray(st.gp_ok)[np.arange(G)[:, None],
-                              np.asarray(st.cand_prov)[None, :]]
-    return lab & fit & gp
+    return label_feasibility(st) & fit
 
 
 def _host_dom_ok(st) -> np.ndarray:
@@ -814,7 +808,7 @@ def refine(
         try:
             out, outcome, ratio = _refine_inner(
                 result, st, guard=guard, repair_solve=repair_solve,
-                iters=iters)
+                iters=iters, span=span)
         # ktlint: allow[KT005] the rung is an optimization layer: any
         # failure ships the proven scan solution and counts as fallback
         except Exception:
@@ -829,8 +823,12 @@ def refine(
 
 
 def _refine_inner(result: SolveResult, st, *, guard, repair_solve,
-                  iters: int):
+                  iters: int, span):
     elig, freed, lifted, seats = eligible_partition(st, result)
+    # what the rung's device program runs over: the groups it may re-seat
+    # and their pods on the nodes it may free
+    span.annotate(groups=len(lifted),
+                  eligible_pods=sum(len(pods) for pods in lifted.values()))
     if not elig or not freed:
         return result, "skipped", None
 

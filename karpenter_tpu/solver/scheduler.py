@@ -473,7 +473,7 @@ class BatchScheduler:
         self.compile_behind = (
             _compile_behind_enabled() if compile_behind is None else compile_behind
         )
-        self._tpu = TpuSolver()
+        self._tpu = TpuSolver(registry=self.registry)
         # change-gated stall logging; _start_warm runs at fence time, and
         # WHICH thread fences depends on the caller (pipeline dispatcher vs
         # direct RPC threads under KT_SOLVE_PIPELINE=0) — a cheap lock makes

@@ -54,6 +54,13 @@ import numpy as np
 from .. import faults as faults_mod
 from ..models import labels as L
 from ..models.tensorize import NO_SELECTOR, SolveTensors
+from ..metrics import (
+    SCAN_AXES,
+    SCAN_AXIS,
+    SCAN_SLOT_RETRIES,
+    Registry,
+    registry as default_registry,
+)
 from ..obs.trace import NULL_TRACE
 from ..utils.clock import Clock
 from ..ops.masks import (
@@ -155,7 +162,19 @@ def _nr_estimate(st: SolveTensors, NE: int, node_budget: int) -> int:
     summed, doubled (zone splits/interleave slack), plus slack.  Hostname
     caps are deliberately ignored (capped groups share rows with other
     groups); when the estimate is genuinely short the solve detects slot
-    exhaustion and retries once at the full budget (TpuSolver.solve)."""
+    exhaustion and retries once at the full budget (TpuSolver.solve),
+    counted in ``karpenter_solver_scan_slot_retries_total``.
+
+    Rounded up per GROUP on purpose, also where most groups are a handful
+    of pods: what has to fit is the slots the SCAN opens, not the nodes
+    the answer keeps after ``coalesce``.  A step seats its group on the
+    room earlier groups left and buys right-sized nodes for the rest, so a
+    long-tailed batch opens about one node per tiny group (1,640
+    deployments of 250/30/5 pods: 2,880-2,930 slots in use when the scan
+    ends, 256-1,250 nodes in the answer; this estimate 3,470, rung 4,608).
+    Pooling groups of equal requests before rounding up gives 506-520
+    there, and every such request then runs twice, the second time at the
+    full budget (``karpenter_solver_scan_axis_total`` shows both)."""
     if node_budget <= 2048:  # min rung: estimate can't help
         return node_budget
     # memoized on the tensors: solve()/signature()/prepare each consult the
@@ -1278,9 +1297,16 @@ class TpuSolver:
     #: compile of CPU on every solve of that shape)
     WARM_FAILURE_BACKOFF = 300.0
 
-    def __init__(self, clock: Optional[Clock] = None) -> None:
+    def __init__(self, clock: Optional[Clock] = None,
+                 registry: Optional[Registry] = None) -> None:
         import threading
 
+        # the scan's axes and its slot retries, zero-initialised so both
+        # families exist from the first scrape (KT003)
+        self.registry = registry or default_registry
+        for axis in SCAN_AXES:
+            self.registry.counter(SCAN_AXIS).inc({"axis": axis}, value=0.0)
+        self.registry.counter(SCAN_SLOT_RETRIES).inc(value=0.0)
         # persistent compile cache: every process that constructs a solver
         # shares previously compiled XLA programs — a restarted replica
         # skips the compile (bench.py measure_cold_restart gates it)
@@ -1851,6 +1877,21 @@ class TpuSolver:
         )
         return run, init, NE, est_dims, full_dims, full_nr
 
+    def _count_scan(self, st: SolveTensors, dims: dict, n_used: int,
+                    span) -> None:
+        """One finished device scan, by the axes it ran at: the real and the
+        padded number of serial steps, the node slots every step carries and
+        the slots in use when it ended (existing nodes included, as in
+        ``NR``; the host's ``coalesce`` may merge them into fewer nodes).
+        ``dims`` is the program that ran (the estimate's, or the full
+        budget's on a retry) and ``span`` the one that fenced it."""
+        axes = {"groups": st.G, "groups_padded": dims["G"],
+                "node_slots": dims["NR"], "nodes_used": n_used}
+        counter = self.registry.counter(SCAN_AXIS)
+        for axis in SCAN_AXES:
+            counter.inc({"axis": axis}, value=float(axes[axis]))
+        span.annotate(S=dims["S"], **axes)
+
     # ktlint: fence reads two scalars off the finished carry to decide the
     # slot-exhaustion retry — the solve is already fenced by its caller
     def _maybe_retry_exhausted(
@@ -1874,6 +1915,7 @@ class TpuSolver:
         if n_used_v < est_dims["NR"] or infeasible_v <= 0:
             return None
         full_key = _dims_key(full_dims)
+        self.registry.counter(SCAN_SLOT_RETRIES).inc()
         with self._lock:
             self._nr_exhausted.add(_dims_key(est_dims))
             full_ready = full_key in self._ready
@@ -1921,7 +1963,7 @@ class TpuSolver:
             run, init, NE, est_dims, full_dims, full_nr = self._prepare_dispatch(
                 st, existing_nodes, max_nodes, track_assignments, mesh, full_nr,
             )
-        with trace.span("device_execute", full_nr=full_nr):
+        with trace.span("device_execute", full_nr=full_nr) as span:
             if self._faults:
                 self._faults.fire("dispatch")     # dispatch_exc raises here
             carry, ys = run(init)
@@ -1932,7 +1974,9 @@ class TpuSolver:
             # D2H fence: reading a 4-byte result of the scan back cannot
             # complete before the program has, and the extraction below
             # needs the carry on the host anyway
-            np.asarray(carry[7])
+            n_used = int(np.asarray(carry[7]))
+            self._count_scan(st, full_dims if full_nr else est_dims, n_used,
+                             span)
         compile_ms = (time.perf_counter() - t0) * 1000.0
         solve_ms = compile_ms
         # mark ready the key of the program that ACTUALLY compiled (a fresh
@@ -2467,12 +2511,14 @@ class PendingTpuSolve:
         if self._out is not None:
             return self._out
         s = self.solver
-        with self.trace.span("device_fence"):
+        with self.trace.span("device_fence") as span:
             if s._faults:
                 effect = s._faults.fire("fence")  # device_hang raises here
                 if effect is not None and effect.kind == "slow_fence":
                     s._faults.sleep(effect)
-            np.asarray(self.carry[7])  # the one D2H fence
+            n_used = int(np.asarray(self.carry[7]))  # the one D2H fence
+            s._count_scan(self.st, self.full_dims if self.full_nr
+                          else self.est_dims, n_used, span)
         elapsed_ms = (time.perf_counter() - self.t0) * 1000.0
         s._mark_ready(_dims_key(self.full_dims if self.full_nr
                                 else self.est_dims))
@@ -2581,7 +2627,7 @@ class PendingMegaSolve:
         for i, e in enumerate(self.entries):
             r = e["r"]
             trace = r["trace"] or NULL_TRACE
-            trace.record(
+            span = trace.record(
                 "megabatch", self.t_starts[i], trace.now(),
                 slot=i, slots=self.B_pad, occupied=self.B,
             )
@@ -2597,6 +2643,8 @@ class PendingMegaSolve:
                 continue
             carry_i = tuple(x[i] for x in carry_rows)
             ys_i = ys_rows[i] if ys_rows is not None else None
+            s._count_scan(r["st"], e["full_dims"] if e["full_nr"]
+                          else e["est_dims"], int(carry_i[7]), span)
             try:
                 retried = s._maybe_retry_exhausted(
                     carry_i, e["est_dims"], e["full_dims"], e["full_nr"],
