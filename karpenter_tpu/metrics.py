@@ -193,6 +193,11 @@ SCAN_AXIS = "karpenter_solver_scan_axis_total"
 #: every step carries) and 'nodes_used' (slots in use when the scan ended)
 SCAN_AXES = ("groups", "groups_padded", "node_slots", "nodes_used")
 SCAN_SLOT_RETRIES = "karpenter_solver_scan_slot_retries_total"
+# ---- the host's merge pass over a scan's new nodes (solver/coalesce.py) --
+COALESCE = "karpenter_solver_coalesce_total"
+#: what a pass saw and did (KT003 zero-init source): 'nodes_in' (new nodes
+#: the scan opened) and 'merges' (each takes one node off the answer)
+COALESCE_WHAT = ("nodes_in", "merges")
 TRACE_RING_EVICTIONS = "karpenter_trace_ring_evictions_total"
 FLIGHT_DUMPS = "karpenter_trace_flight_recorder_dumps_total"
 # ---- fleet-wide tracing (ISSUE 15: wire-propagated trace context) -------
@@ -597,6 +602,18 @@ INVENTORY = {
         "family goes straight to the full program from then on.  Rare "
         "by construction — the estimate is doubled; a steady rise means "
         "the estimate is short for this fleet's shapes."),
+    COALESCE: (
+        "counter", ("what",),
+        "The host's merge pass over the new nodes of a device scan "
+        "(solver/coalesce.py, inside TpuSolver's extraction; one "
+        "increment per extracted scan — the single, the pipelined and "
+        "each megabatch slot): 'nodes_in' — new nodes the scan opened; "
+        "'merges' — pairs of them replaced by one node of a larger type "
+        "at no higher price, so nodes_in less merges is what the answer "
+        "keeps.  A work count: for one input it reads the same whatever "
+        "the pass costs (the `coalesce` span times it); merges close to "
+        "nodes_in is a scan that opened a node per tiny group and left "
+        "the packing to the host."),
     TRACE_RING_EVICTIONS: (
         "counter", (),
         "Traces evicted from the flight recorder's bounded ring to admit "

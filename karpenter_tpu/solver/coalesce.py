@@ -10,28 +10,54 @@ interruption exposure — so after extraction the solver merges same-
 whenever:
 
 - the larger type's allocatable fits the combined used resources (including
-  the pod-density row), and
+  the pod-density row) and every group on either node admits it, and
 - its price is <= the sum of the replaced nodes' prices (NEVER spends $ —
   in-family pricing is linear, so 2x 4xlarge -> 1x 8xlarge is exact), and
 - the provisioner either has no finite limits or the replacement's raw
   capacity does not exceed the replaced capacity (limits bind on capacity),
   and
-- no group in the solve carries hostname-scoped constraints (hostname
-  anti-affinity/spread caps are per-NODE: merging two nodes that each hold
-  one matching pod would co-locate them; zone-scoped constraints are safe —
-  merging preserves the zone).
+- on every hostname slot either node's groups cap (anti-affinity, spread
+  maxSkew), the COMBINED count of matching pods stays within the stricter
+  cap.  Untracked solves (no per-node groups) with any hostname-scoped
+  constraint skip the pass; zone-scoped constraints are safe, a merge keeps
+  the zone.
 
-Greedy smallest-first within each bucket; deterministic.
+The merge rule, per bucket: keep the nodes in order — smallest first, and
+in hostname-capped solves round-robin across group combinations — look at
+the FRAG_WINDOW first of them, take the first pair (smallest i, then
+smallest j > i) that some candidate can hold, replace both by the cheapest
+such candidate, put it back in order, look again; stop when no pair of the
+window merges.  Greedy and deterministic.  How it is held: a bucket's nodes
+are rows 0..n-1 of dense arrays (used resources, price, candidate
+feasibility, raw capacity, hostname counts and caps) and every merge writes
+ONE new row (sum / AND / min of two); a pair's verdict — its cheapest
+candidate, or none — is computed once, when the later of the two first
+enters the window, for all pairs new to the window in one broadcast over
+the candidates in price order (less those an earlier one beats on every
+count), and lives in a matrix addressed by the two nodes' window slots, so
+the first hit is an ``argmax`` over its upper triangle (a node the capped
+order pushes out of the window keeps its slot, and on its way back is not
+asked again about nodes that came in meanwhile: the rule of the loop this
+replaced, kept so that the answers are its answers); the order is a sorted list of ``(rank, size, name)`` keys kept by
+bisection, where a merge re-ranks only the later members of the
+combinations it takes from and adds to.  Merged nodes are rows and a name
+until the bucket is done: only the survivors become ``SimNode`` objects.
+
+``TpuSolver._extract`` wraps the pass in a ``coalesce`` span (``nodes_in``,
+``nodes_out``, ``merges``, ``buckets``) and counts it in
+``karpenter_solver_coalesce_total{what="nodes_in"|"merges"}``.
 """
 
 from __future__ import annotations
 
-from bisect import insort
-from typing import Dict, List, Optional, Sequence, Tuple
+from bisect import bisect_left, insort
+from collections import Counter
+from itertools import chain
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from .types import SimNode
+from .types import SimNode, next_node_name
 
 #: prov_limits entries at/above this are "no limit" sentinels
 _NO_LIMIT = 3.0e37
@@ -80,23 +106,6 @@ def hostname_constrained(st) -> bool:
     )
 
 
-def hostname_capped_groups(st) -> set:
-    """Group indices whose hostname rules CAP pods per node (spread maxSkew,
-    anti-affinity) — a merge combining two nodes' counts can violate these,
-    so nodes holding them are frozen out of coalescing.  Positive hostname
-    affinity (g_host_paff) is NOT capping: it wants matching pods together,
-    and merging only ever adds pods to a node, so it cannot break (fuzz
-    seed 23: one paff group used to disable coalescing for the whole solve,
-    stranding mergeable fragments in every other group)."""
-    return set(np.flatnonzero(np.asarray(st.g_host_spread) >= 0).tolist())
-
-
-def _pkey(a: SimNode, b: SimNode) -> tuple:
-    """Order-free identity key for the symmetric pair-feasibility cache."""
-    ia, ib = id(a), id(b)
-    return (ia, ib) if ia < ib else (ib, ia)
-
-
 def _domain_index(st, zone: str, ct: str) -> Optional[int]:
     try:
         zi = st.zone_names.index(zone)
@@ -106,20 +115,23 @@ def _domain_index(st, zone: str, ct: str) -> Optional[int]:
     return zi * max(1, len(st.ct_names)) + ci
 
 
-def apply_coalesce(st, nodes, used_rows, node_groups, assignments):
+def apply_coalesce(st, nodes, used_rows, node_groups, assignments, span=None):
     """Shared tier epilogue: run the merge pass and repoint assignments of
     absorbed nodes at their replacements.  Both the device tier
     (tpu._extract) and the native tier (native.solve_tensors_native) call
     this so the cold-start answer and the warm answer stay the same
-    coalescing contract."""
-    if len(nodes) < 2:
-        return nodes
-    nodes, renames = coalesce_new_nodes(st, nodes, used_rows,
-                                        node_groups=node_groups)
-    if renames:
-        for pod_name, node_name in list(assignments.items()):
-            if node_name in renames:
-                assignments[pod_name] = renames[node_name]
+    coalescing contract.  ``span``, where given, is told what the pass did."""
+    n_in, buckets = len(nodes), 0
+    if n_in >= 2:
+        nodes, renames, buckets = coalesce_new_nodes(
+            st, nodes, used_rows, node_groups=node_groups)
+        if renames:
+            for pod_name, node_name in list(assignments.items()):
+                if node_name in renames:
+                    assignments[pod_name] = renames[node_name]
+    if span is not None:
+        span.annotate(nodes_in=n_in, nodes_out=len(nodes),
+                      merges=n_in - len(nodes), buckets=buckets)
     return nodes
 
 
@@ -128,19 +140,18 @@ def coalesce_new_nodes(
     nodes: List[SimNode],
     used_rows: Dict[int, np.ndarray],  # id(node) -> used resource row [R]
     node_groups: Optional[Dict[int, set]] = None,  # id(node) -> {group idx}
-) -> Tuple[List[SimNode], Dict[str, str]]:
-    """Merge mergeable new nodes; returns (new node list, renames) where
-    ``renames`` maps absorbed old node names -> their replacement's name.
+) -> Tuple[List[SimNode], Dict[str, str], int]:
+    """Merge mergeable new nodes; returns (new node list, renames, buckets)
+    where ``renames`` maps absorbed old node names -> their replacement's
+    name and ``buckets`` counts the (provisioner, zone, capacity-type)
+    buckets the nodes fell in.  Every merge takes one node off the list.
     Pods are moved onto the replacement nodes; callers fix assignments via
     the rename map.  ``node_groups`` scopes the label-feasibility check to
     the groups actually placed on each node; without it (untracked solves)
     the merge target must be feasible for EVERY group in the solve."""
-    capped = hostname_capped_groups(st)
-    if node_groups is None:
-        # untracked solves can't scope the check per node: all-or-nothing
-        if hostname_constrained(st):
-            return nodes, {}
-        capped = set()
+    # untracked solves can't scope the check per node: all-or-nothing
+    if node_groups is None and hostname_constrained(st):
+        return nodes, {}, 0
     # per-node hostname bookkeeping for capped solves: a merge is legal when,
     # for every hostname slot either node's groups cap, the COMBINED count of
     # slot-matching pods stays within the stricter cap (anti-affinity
@@ -148,39 +159,24 @@ def coalesce_new_nodes(
     # from g_sel_match at group granularity — no per-pod selector matching.
     # This is what lets bench config 3 (every pod hostname-anti) coalesce its
     # 1-pod-per-service fragments into shared nodes at equal-or-lower price.
+    # Positive hostname affinity (g_host_paff) needs no cap: it wants
+    # matching pods together, and merging only ever ADDS co-residents (fuzz
+    # seed 23: one paff group used to disable coalescing for the whole solve).
     g_hs = np.asarray(st.g_host_spread)
-    g_hc = np.asarray(st.g_host_cap)
-    host_active = bool(capped) and (g_hs >= 0).any()
+    g_hc = np.asarray(st.g_host_cap, dtype=np.float64)
+    sel = np.asarray(st.g_sel_match)
+    host_active = node_groups is not None and bool((g_hs >= 0).any())
     pod_group: Dict[str, int] = {}
     if host_active:
         for gi, g in enumerate(st.groups):
             for p in g.pods:
                 pod_group[p.name] = gi
-    S_all = st.g_sel_match.shape[0]
-
-    def _host_state(n: SimNode):
-        """(counts[S], caps[S]) for one node; caps inf where unconstrained."""
-        cnt = np.zeros(S_all, dtype=np.int64)
-        cap = np.full(S_all, np.inf)
-        for p in n.pods:
-            gi = pod_group.get(p.name)
-            if gi is None:
-                # a pod outside this solve (shouldn't happen for new nodes):
-                # be conservative, forbid merging this node
-                cap[:] = -1.0
-                return cnt, cap
-            cnt += st.g_sel_match[:, gi]
-            s = int(g_hs[gi])
-            if s >= 0:
-                cap[s] = min(cap[s], float(g_hc[gi]))
-            # positive hostname affinity (g_host_paff) needs no cap: it wants
-            # matching pods together, and merging only ever ADDS co-residents
-        return cnt, cap
     F = label_feasibility(st)                             # [G, C]
-    all_groups = frozenset(range(F.shape[0]))
+    G = F.shape[0]
+    F_distinct = np.array(list({row.tobytes(): row for row in F}.values()))
+    all_groups = frozenset(range(G))
+    R = np.asarray(st.cand_alloc).shape[1]
 
-    # candidate rows by provisioner, cheapest-capacity order is not needed:
-    # we pick the cheapest feasible replacement by price
     by_prov: Dict[str, List[int]] = {}
     for ci, (prov, _it) in enumerate(st.cand_names):
         by_prov.setdefault(prov, []).append(ci)
@@ -202,238 +198,242 @@ def coalesce_new_nodes(
         limited = bool((np.asarray(st.prov_limits)[pi] < _NO_LIMIT).any())
         # bucket-local candidate table (spot pricing is NOT linear in size —
         # zonal discounts vary per type — so the cheapest feasible
-        # replacement can come from any family)
+        # replacement can come from any family), in price order: the
+        # cheapest feasible candidate is the FIRST feasible one, and a pair
+        # only has to look at the candidates its two prices can pay for
         cand_ix = np.asarray([ci for ci in cands if st.cand_avail[ci, di]],
                              dtype=np.int64)
         if cand_ix.size == 0:
             out.extend(group)
             continue
-        c_alloc = np.asarray(st.cand_alloc)[cand_ix]          # [K, R]
-        c_cap = np.asarray(st.cand_cap)[cand_ix]              # [K, R]
-        c_price = np.asarray(st.cand_price)[cand_ix, di]      # [K]
+        cand_ix = cand_ix[np.argsort(np.asarray(st.cand_price)[cand_ix, di],
+                                     kind="stable")]
+        # (float32 tables, compared with float64 rows: widened once, here)
+        c_price = np.asarray(st.cand_price)[cand_ix, di].astype(np.float64)
+        c_room = (np.asarray(st.cand_alloc)[cand_ix] + 1e-6   # [R, K]
+                  ).T.astype(np.float64)
+        c_cap = np.ascontiguousarray(np.asarray(st.cand_cap)[cand_ix].T)
         c_F = F[:, cand_ix]                                   # [G, K]
+        # a candidate is never the first feasible one where an earlier one
+        # has at least its room, at most its capacity and every group that
+        # admits it: such candidates leave the table (425 -> ~50 types) —
+        # where the first window alone holds more pairs than the table has
+        # candidates; under that the pruning costs more than it saves
+        n = len(group)
+        if min(n, FRAG_WINDOW) ** 2 > 2 * cand_ix.size:
+            admits = F_distinct[:, cand_ix].astype(np.float32)
+            beaten = np.triu((1.0 - admits).T @ admits == 0, 1)  # [earlier, k]
+            for r in range(R):
+                beaten &= c_room[r][:, None] >= c_room[r]
+                if limited:
+                    beaten &= c_cap[r][:, None] <= c_cap[r]
+            keep = ~beaten.any(axis=0)
+            cand_ix, c_price, c_F = cand_ix[keep], c_price[keep], c_F[:, keep]
+            c_room, c_cap = c_room[:, keep], c_cap[:, keep]
 
-        def groups_of(n: SimNode) -> frozenset:
-            if node_groups is None:
-                return all_groups
-            return frozenset(node_groups.get(id(n), all_groups))
+        # the bucket's dense state: rows 0..n-1 are the scan's nodes as they
+        # arrive, every merge appends one (2n-1 at most)
+        K, N = cand_ix.size, 2 * n - 1
+        names = [x.name for x in group]
+        used = np.empty((N, R))
+        used[:n] = [used_rows[id(x)] for x in group]
+        size = used[:n].sum(axis=1).tolist()              # the order's key
+        price = np.empty(N)
+        price[:n] = [x.price for x in group]
+        # candidate feasibility: AND over the node's groups (c_F[union].all
+        # == c_F[a].all & c_F[b].all, so a merged row is an AND of two)
+        feas = np.empty((N, K), dtype=bool)
+        if node_groups is None:
+            feas[:n] = c_F.all(axis=0)
+        else:
+            # one reduceat over every node's rows; row G (all true) closes
+            # each segment so none is empty, row G+1 is a node the caller
+            # did not track: every group
+            rows = np.vstack([c_F, np.ones((1, K), dtype=bool),
+                              c_F.all(axis=0)[None]])
+            segs = [(*gs, G) if gs is not None else (G + 1,)
+                    for gs in (node_groups.get(id(x)) for x in group)]
+            starts = np.cumsum([0] + [len(s) for s in segs[:-1]])
+            feas[:n] = np.logical_and.reduceat(
+                rows[np.fromiter(chain.from_iterable(segs), np.intp)],
+                starts, axis=0)
+        if limited:
+            cap = np.empty((N, R), dtype=np.float32)
+            cap[:n] = [st.capacity_row(x.instance_type, x.allocatable)
+                       for x in group]
+        if host_active:
+            # (counts[S], caps[S]) per node; caps inf where unconstrained
+            hcnt = np.zeros((N, sel.shape[0]), dtype=np.int64)
+            hcap = np.full((N, sel.shape[0]), np.inf)
+            for x, node in enumerate(group):
+                took = Counter(pod_group.get(p.name) for p in node.pods)
+                if None in took:
+                    # a pod outside this solve (shouldn't happen for new
+                    # nodes): be conservative, forbid merging this node
+                    hcap[x] = -1.0
+                    continue
+                gs = np.fromiter(took, np.intp, len(took))
+                hcnt[x] = sel[:, gs] @ np.fromiter(took.values(), np.int64,
+                                                   len(took))
+                capping = gs[g_hs[gs] >= 0]
+                np.minimum.at(hcap[x], g_hs[capping], g_hc[capping])
 
-        _hstate: Dict[int, tuple] = {}
+        def verdicts(x: np.ndarray, y: np.ndarray) -> np.ndarray:
+            """The cheapest candidate that can replace nodes x[p] and y[p]
+            together, -1 where none can.  Symmetric, and unaffected by other
+            merges: asked once per pair."""
+            pay = price[x] + price[y]
+            top = max(1, int(np.searchsorted(c_price, pay.max() + 1e-9,
+                                             side="right")))
+            need = used[x] + used[y]                              # [P, R]
+            ok = feas[x, :top] & feas[y, :top]                    # [P, top]
+            for r in range(R):
+                ok &= c_room[r, :top] >= need[:, r, None]
+            ok &= c_price[:top] <= pay[:, None] + 1e-9
+            if limited:
+                capb = cap[x] + cap[y]
+                for r in range(R):
+                    ok &= c_cap[r, :top] <= capb[:, r, None] + 1e-6
+            if host_active:
+                # hostname caps: combined slot-matching counts must respect
+                # the stricter of the two nodes' caps on every slot
+                ok &= (hcnt[x] + hcnt[y] <= np.minimum(hcap[x], hcap[y])
+                       ).all(axis=1)[:, None]
+            return np.where(ok.any(axis=1), ok.argmax(axis=1), -1)
 
-        def host_state(n: SimNode) -> tuple:
-            got = _hstate.get(id(n))
-            if got is None:
-                got = _host_state(n)
-                _hstate[id(n)] = got
-            return got
+        # Scan order.  Plain solves: smallest-first.  Hostname-capped
+        # solves: same, but round-robin across group combinations — the
+        # solver creates one group's fragments consecutively, so a
+        # smallest-first window would fill with ONE service's nodes, whose
+        # pairs all violate the per-node cap; rotating group combos puts
+        # mergeable cross-service partners inside the window.  A key is
+        # (rank inside its combination, size, name, row); names are unique,
+        # so the order is total and a bisection keeps the list what a full
+        # sort would give.
+        keys = sorted((0, size[x], names[x], x) for x in range(n))
+        if host_active:
+            combo = [frozenset(node_groups.get(id(x), all_groups))
+                     for x in group]
+            members: Dict[frozenset, list] = {}  # -> [(size, name, row)]
+            for at, (_r, s, name, x) in enumerate(keys):
+                mem = members.setdefault(combo[x], [])
+                keys[at] = (len(mem), s, name, x)
+                mem.append((s, name, x))
+            keys.sort()
 
-        def order_nodes(lst: List[SimNode]) -> List[SimNode]:
-            """Scan order.  Plain solves: smallest-first.  Hostname-capped
-            solves: same, but round-robin across group combinations — the
-            solver creates one group's fragments consecutively, so a
-            smallest-first window would fill with ONE service's nodes, whose
-            pairs all violate the per-node cap; rotating group combos puts
-            mergeable cross-service partners inside the window."""
-            base = sorted(lst, key=plain_key)
-            if not host_active:
-                return base
-            seen: Dict[frozenset, int] = {}
-            ranked = []
-            for n in base:
-                key = frozenset(groups_of(n))
-                r = seen.get(key, 0)
-                seen[key] = r + 1
-                ranked.append((r, size_of(n), n.name, n))
-            ranked.sort(key=lambda t: t[:3])
-            return [t[3] for t in ranked]
-
-        # per-node precomputes, cached by identity (merged nodes get entries
-        # as they're created): candidate-feasibility row (AND over the node's
-        # groups — c_F[union].all == c_F[a].all & c_F[b].all, so pair
-        # feasibility is a cheap elementwise AND) and the raw-capacity row
-        # for limit-bound buckets
-        c_F_all = c_F.all(axis=0)
-        _nF: Dict[int, np.ndarray] = {}
-        _ncap: Dict[int, np.ndarray] = {}
-
-        def node_F(n: SimNode) -> np.ndarray:
-            got = _nF.get(id(n))
-            if got is None:
-                gs = groups_of(n)
-                got = c_F_all if gs == all_groups else c_F[sorted(gs)].all(axis=0)
-                _nF[id(n)] = got
-            return got
-
-        def node_cap(n: SimNode) -> np.ndarray:
-            got = _ncap.get(id(n))
-            if got is None:
-                got = st.capacity_row(n.instance_type, n.allocatable)
-                _ncap[id(n)] = got
-            return got
+        def rerank(mem: list, lo: int, was: int, now: int) -> None:
+            """One combination's members from ``lo`` on change rank, from
+            ``was`` to ``now`` past their place in ``mem``."""
+            for q in range(lo, len(mem)):
+                del keys[bisect_left(keys, (q + was, *mem[q]))]
+                insort(keys, (q + now, *mem[q]))
 
         # smallest-first pair scan: any pair may merge (a cpu-heavy and a
         # mem-heavy fragment can share one node even when two same-size
         # fragments can't), so failure of one pair doesn't end the bucket.
-        # The scan is windowed to the FRAG_WINDOW smallest nodes — fragments
+        # The scan is windowed to the FRAG_WINDOW first nodes — fragments
         # live at the small end, and an unwindowed pair scan over a 50k-pod
         # solve's hundreds of nodes would cost more host time than the solve.
-        # Pair feasibility is symmetric and unaffected by OTHER merges, so
-        # it's cached by node-identity pair and evaluated in one batched
-        # numpy pass per scan (the round-4 cold-path regression was this
-        # loop in per-pair Python).  Merge order is unchanged: first
-        # (i, then smallest j) feasible pair, cheapest candidate, resort,
-        # rescan.
-        pair_best: Dict[tuple, Optional[tuple]] = {}  # (ida,idb) -> (price,k)|None
-        partners: Dict[int, set] = {}  # node id -> ids with a feasible merge
-        _seen: set = set()           # node ids whose window pairs are cached
-        _size: Dict[int, float] = {}  # node id -> used magnitude (sort key)
-        _pinned: List[SimNode] = []  # absorbed nodes held alive: cache keys are
-        # id()s — a GC'd node's id could be reused by a later merged node
-
-        def size_of(n: SimNode) -> float:
-            got = _size.get(id(n))
-            if got is None:
-                got = float(used_rows[id(n)].sum())
-                _size[id(n)] = got
-            return got
-
-        def plain_key(n: SimNode) -> tuple:
-            return size_of(n), n.name
-
-        def eval_pairs(window: List[SimNode]) -> None:
-            """Fill pair_best for every uncached pair in the window.  Only
-            pairs touching a node new to the window since the last eval can
-            be uncached (pair feasibility is unaffected by other merges), so
-            enumeration is O(new x W), not O(W^2) per scan."""
-            w = len(window)
-            ids = [id(n) for n in window]
-            new_ix = [i for i in range(w) if ids[i] not in _seen]
-            if not new_ix:
-                return
-            new_set = set(new_ix)
-            fresh, keys = [], []  # (i, j) with i < j, and the pair's _pkey
-            for i in new_ix:
-                ia = ids[i]
-                for j in range(w):
-                    if j == i or (j in new_set and j < i):
-                        continue
-                    ib = ids[j]
-                    key = (ia, ib) if ia < ib else (ib, ia)
-                    if key not in pair_best:
-                        fresh.append((i, j) if i < j else (j, i))
-                        keys.append(key)
-            _seen.update(ids[i] for i in new_ix)
-            if not fresh:
-                return
-            ai = np.asarray([i for i, _ in fresh])
-            bj = np.asarray([j for _, j in fresh])
-            used_w = np.stack([used_rows[id(n)] for n in window])     # [W,R]
-            price_w = np.asarray([n.price for n in window])
-            F_w = np.stack([node_F(n) for n in window])               # [W,K]
-            need = used_w[ai] + used_w[bj]                            # [P,R]
-            ok = F_w[ai] & F_w[bj]                                    # [P,K]
-            R = need.shape[1]
-            for r in range(R):
-                ok &= c_alloc[None, :, r] + 1e-6 >= need[:, r, None]
-            ok &= c_price[None, :] <= (price_w[ai] + price_w[bj])[:, None] + 1e-9
-            if limited:
-                cap_w = np.stack([node_cap(n) for n in window])
-                capb = cap_w[ai] + cap_w[bj]
-                for r in range(R):
-                    ok &= c_cap[None, :, r] <= capb[:, r, None] + 1e-6
-            if host_active:
-                # hostname caps: combined slot-matching counts must respect
-                # the stricter of the two nodes' caps on every slot
-                hcnt = np.stack([host_state(n)[0] for n in window])  # [W,S]
-                hcap = np.stack([host_state(n)[1] for n in window])  # [W,S]
-                pair_ok = (
-                    hcnt[ai] + hcnt[bj]
-                    <= np.minimum(hcap[ai], hcap[bj])
-                ).all(axis=1)
-                ok &= pair_ok[:, None]
-            any_p = ok.any(axis=1)
-            hits = np.flatnonzero(any_p)
-            ks = np.empty(len(fresh), dtype=np.int64)
-            if hits.size:
-                ks[hits] = np.where(ok[hits], c_price[None, :], np.inf).argmin(axis=1)
-            for p in np.flatnonzero(~any_p).tolist():
-                pair_best[keys[p]] = None
-            for p in hits.tolist():
-                ia, ib = keys[p]
-                pair_best[keys[p]] = (float(c_price[ks[p]]), int(ks[p]))
-                partners.setdefault(ia, set()).add(ib)
-                partners.setdefault(ib, set()).add(ia)
-
-        group = order_nodes(group)
-        while len(group) >= 2:
-            win = min(len(group), FRAG_WINDOW)
-            window = group[:win]
-            eval_pairs(window)
-            hit = None
-            for i in range(win - 1):
-                ps = partners.get(id(window[i]))
-                if not ps:
-                    continue
-                for j in range(i + 1, win):
-                    if id(window[j]) in ps:
-                        best = pair_best[_pkey(window[i], window[j])]
-                        hit = (i, j, best[1],
-                               used_rows[id(window[i])] + used_rows[id(window[j])])
-                        break
-                if hit is not None:
-                    break
-            if hit is None:
+        # A node takes a slot of the verdict matrix when it first enters the
+        # window and keeps it until it is absorbed (the capped order can
+        # push a node out of the window and let it back in).
+        verdict = np.full((FRAG_WINDOW, FRAG_WINDOW), -1, np.int32)
+        later = np.triu(np.ones((FRAG_WINDOW, FRAG_WINDOW), dtype=bool), 1)
+        slot = np.full(N, -1, dtype=np.intp)
+        free = list(range(len(verdict)))
+        merged: Dict[int, tuple] = {}  # row -> (row a, row b, candidate)
+        while len(keys) >= 2:
+            wid = np.array([key[3] for key in keys[:FRAG_WINDOW]])
+            fresh = slot[wid] < 0
+            if fresh.any():
+                for row in wid[fresh].tolist():
+                    if not free:  # nodes pushed out of the window hold slots
+                        had = len(verdict)
+                        verdict = np.pad(verdict, (0, had), constant_values=-1)
+                        free.extend(range(had, 2 * had))
+                    slot[row] = s = free.pop()
+                    verdict[s] = verdict[:, s] = -1
+                at = np.flatnonzero(fresh)
+                # pairs that touch a fresh node, each once
+                p, q = np.nonzero((np.arange(wid.size) > at[:, None]) | ~fresh)
+                x, y = wid[at[p]], wid[q]
+                verdict[slot[x], slot[y]] = verdict[slot[y], slot[x]] = (
+                    verdicts(x, y))
+            ws = slot[wid]
+            hits = (verdict[ws[:, None], ws] >= 0) & later[:ws.size, :ws.size]
+            i, j = divmod(int(hits.argmax()), wid.size)
+            if not hits[i, j]:
                 break
-            i, j, k, need = hit
-            a, b = group[i], group[j]
-            _pinned.extend((a, b))
-            ci = int(cand_ix[k])
-            _prov, type_name = st.cand_names[ci]
+            a, b = int(wid[i]), int(wid[j])
+            k = int(verdict[ws[i], ws[j]])
+            m = len(names)
+            merged[m] = (a, b, k)
+            # names are a tie-break of the order and part of the answer:
+            # one a merge, drawn at the merge
+            names.append(next_node_name())
+            used[m] = used[a] + used[b]
+            size.append(float(used[m].sum()))
+            price[m] = c_price[k]
+            feas[m] = feas[a] & feas[b]
+            if limited:
+                cap[m] = st.capacity_row(st.cand_names[cand_ix[k]][1], None)
+            # one hop each; an absorbed node may itself be a prior
+            # replacement, so the chains are followed once, at the end
+            renames[names[a]] = renames[names[b]] = names[m]
+            free += (int(slot[a]), int(slot[b]))
+            if not host_active:
+                del keys[j], keys[i]  # i < j, both inside the window
+                insort(keys, (0, size[m], names[m], m))
+                continue
+            hcnt[m] = hcnt[a] + hcnt[b]
+            hcap[m] = np.minimum(hcap[a], hcap[b])
+            combo.append(combo[a] | combo[b])
+            for gone in (a, b):  # later members of its combination move up
+                mem = members[combo[gone]]
+                r = bisect_left(mem, (size[gone], names[gone], gone))
+                del keys[bisect_left(keys, (r, *mem[r]))], mem[r]
+                rerank(mem, r, 1, 0)
+            mem = members.setdefault(combo[m], [])
+            r = bisect_left(mem, (size[m], names[m], m))
+            rerank(mem, r, 0, 1)  # ... and of the one it joins, down
+            mem.insert(r, (size[m], names[m], m))
+            insort(keys, (r, *mem[r]))
+
+        def pods_of(x: int) -> list:
+            """a's pods, then b's, down the tree of merges under row x."""
+            pods, todo = [], [x]
+            while todo:
+                x = todo.pop()
+                if x < n:
+                    pods.extend(group[x].pods)
+                else:
+                    todo.extend(merged[x][1::-1])  # b under a: a pops first
+            return pods
+
+        for _r, _s, name, x in keys:
+            if x < n:
+                out.append(group[x])
+                continue
+            ci = int(cand_ix[merged[x][2]])
             node = SimNode(
-                instance_type=type_name,
+                instance_type=st.cand_names[ci][1],
                 provisioner=prov,
                 zone=zone,
                 capacity_type=ct,
-                price=float(c_price[k]),
+                price=float(price[x]),
                 allocatable={
                     st.vocab.resources[r]: float(st.cand_alloc[ci, r])
-                    for r in range(st.cand_alloc.shape[1])
+                    for r in range(R)
                 },
                 existing=False,
+                name=name,
             )
             node.stamp_labels()
-            node.pods = list(a.pods) + list(b.pods)
-            used_rows[id(node)] = need
-            _nF[id(node)] = node_F(a) & node_F(b)
-            if host_active:
-                ca, pa = host_state(a)
-                cb, pb = host_state(b)
-                _hstate[id(node)] = (ca + cb, np.minimum(pa, pb))
-            if node_groups is not None:
-                node_groups[id(node)] = set(groups_of(a) | groups_of(b))
-            # one hop each; an absorbed node may itself be a prior
-            # replacement, so the chains are followed once, at the end
-            renames[a.name] = node.name
-            renames[b.name] = node.name
-            # absorbed nodes leave the partner graph (their ids must not
-            # surface as hits in later scans)
-            for gone in (id(a), id(b)):
-                for other in partners.pop(gone, ()):  # symmetric cleanup
-                    partners.get(other, set()).discard(gone)
-            del group[j], group[i]  # i < j, both inside the window
-            if host_active:
-                group = order_nodes(group + [node])
-            else:
-                # the plain order is a total one (names are unique), so
-                # taking two out and putting one in keeps the list what a
-                # full sort would give — at a bisection, not a sort of the
-                # bucket, per merge (a long-tailed batch merges thousands
-                # of fragments per bucket)
-                insort(group, node, key=plain_key)
-        out.extend(group)
+            node.pods = pods_of(x)
+            out.append(node)
     # forward every absorbed name to the node that finally holds its pods:
     # a replacement absorbed later was entered later, so walking the map
     # backwards finds each target already resolved
     for old in reversed(renames):
         renames[old] = renames.get(renames[old], renames[old])
-    return out, renames
+    return out, renames, len(buckets)

@@ -55,6 +55,8 @@ from .. import faults as faults_mod
 from ..models import labels as L
 from ..models.tensorize import NO_SELECTOR, SolveTensors
 from ..metrics import (
+    COALESCE,
+    COALESCE_WHAT,
     SCAN_AXES,
     SCAN_AXIS,
     SCAN_SLOT_RETRIES,
@@ -1301,12 +1303,15 @@ class TpuSolver:
                  registry: Optional[Registry] = None) -> None:
         import threading
 
-        # the scan's axes and its slot retries, zero-initialised so both
-        # families exist from the first scrape (KT003)
+        # the scan's axes, its slot retries and the merge pass over its
+        # nodes, zero-initialised so the families exist from the first
+        # scrape (KT003)
         self.registry = registry or default_registry
         for axis in SCAN_AXES:
             self.registry.counter(SCAN_AXIS).inc({"axis": axis}, value=0.0)
         self.registry.counter(SCAN_SLOT_RETRIES).inc(value=0.0)
+        for what in COALESCE_WHAT:
+            self.registry.counter(COALESCE).inc({"what": what}, value=0.0)
         # persistent compile cache: every process that constructs a solver
         # shares previously compiled XLA programs — a restarted replica
         # skips the compile (bench.py measure_cold_restart gates it)
@@ -2010,7 +2015,7 @@ class TpuSolver:
         with trace.span("extract"):
             return self._extract(
                 st, carry, ys if track_assignments else None, existing_nodes,
-                NE, solve_ms, compile_ms,
+                NE, solve_ms, compile_ms, trace,
             )
 
     def solve_async(
@@ -2369,7 +2374,8 @@ class TpuSolver:
     # ktlint: fence extraction reads the whole carry back to host — it runs
     # strictly after the fence, on already-transferred results
     def _extract(
-        self, st, carry, ys, existing_nodes, NE, solve_ms, compile_ms
+        self, st, carry, ys, existing_nodes, NE, solve_ms, compile_ms,
+        trace=NULL_TRACE,
     ) -> TpuSolveOutput:
         (res, row_zone, row_dom, row_cand, row_price, selcnt, active,
          n_used, zc, tot, prov_used, infeasible) = [np.asarray(x) for x in carry]
@@ -2451,8 +2457,13 @@ class TpuSolver:
                     np.asarray(st.cand_alloc[ci], dtype=np.float64)
                     - np.asarray(res[si], dtype=np.float64)
                 )
-        new_nodes = apply_coalesce(st, new_nodes, used_rows, node_groups,
-                                   assignments)
+        n_in = len(new_nodes)
+        with trace.span("coalesce") as span:
+            new_nodes = apply_coalesce(st, new_nodes, used_rows, node_groups,
+                                       assignments, span)
+        counter = self.registry.counter(COALESCE)
+        counter.inc({"what": "nodes_in"}, value=float(n_in))
+        counter.inc({"what": "merges"}, value=float(n_in - len(new_nodes)))
 
         result = SolveResult(
             nodes=new_nodes,
@@ -2536,6 +2547,7 @@ class PendingTpuSolve:
             self._out = s._extract(
                 self.st, self.carry, self.ys if self.track else None,
                 self.existing_nodes, self.NE, elapsed_ms, elapsed_ms,
+                self.trace,
             )
         return self._out
 
@@ -2668,7 +2680,7 @@ class PendingMegaSolve:
             with trace.span("extract", slot=i):
                 outputs.append(s._extract(
                     r["st"], carry_i, ys_i, r["existing_nodes"], e["NE"],
-                    elapsed_ms, elapsed_ms,
+                    elapsed_ms, elapsed_ms, trace,
                 ))
         if owners is not None and self.registry is not None:
             from ..metrics import MULTIHOST_SLOTS

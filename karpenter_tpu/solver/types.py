@@ -27,6 +27,11 @@ def _next_node_idx() -> int:
         return idx
 
 
+def next_node_name() -> str:
+    """The next auto-name: what a SimNode built without one gets."""
+    return f"node-{_next_node_idx()}"
+
+
 def advance_node_counter(floor: int) -> None:
     """Ensure future auto-named SimNodes get indices STRICTLY ABOVE
     ``floor``.  Session restore (service/delta.py) needs this: a restarted
@@ -64,7 +69,7 @@ class SimNode:
 
     def __post_init__(self) -> None:
         if not self.name:
-            self.name = f"node-{_next_node_idx()}"
+            self.name = next_node_name()
 
     def used(self) -> ResourceList:
         out: ResourceList = {L.RESOURCE_PODS: float(len(self.pods))}

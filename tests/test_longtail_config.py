@@ -287,25 +287,20 @@ def test_the_rungs_of_the_benchmarks_configurations(harness, config, G,
 
 def test_coalesce_keeps_the_order_a_full_sort_gives(namespace, monkeypatch):
     """The scan opens ~530 slots here and ``coalesce`` merges them into ~50
-    nodes, hundreds of merges a bucket.  It keeps each bucket ordered by
-    putting a merged node in at a bisection; the reference is the loop it
-    replaced — sort the whole bucket again after every merge — and an
-    absorbed name has to lead to the node that finally holds its pods."""
-    import copy as copy_mod
-
+    nodes, hundreds of merges a bucket.  It keeps each bucket's order as a
+    sorted list of keys and puts a merged node in at a bisection; the
+    reference is the loop of before PR 28 (``tests/coalesce_reference.py``)
+    made to sort the whole bucket again after every merge — and an absorbed
+    name has to lead to the node that finally holds its pods."""
+    import coalesce_reference
     from karpenter_tpu.solver import coalesce
 
     seen = {}
     real = coalesce.coalesce_new_nodes
 
     def capture(st, nodes, used_rows, node_groups=None):
-        twins = [copy_mod.copy(n) for n in nodes]
-        for n, t in zip(nodes, twins):
-            t.pods = list(n.pods)
-        seen["args"] = (
-            st, twins, {id(t): used_rows[id(n)].copy()
-                        for n, t in zip(nodes, twins)},
-            {id(t): set(node_groups[id(n)]) for n, t in zip(nodes, twins)})
+        seen["args"] = coalesce_reference.twin(st, nodes, used_rows,
+                                               node_groups)
         seen["got"] = real(st, nodes, used_rows, node_groups=node_groups)
         return seen["got"]
 
@@ -321,9 +316,10 @@ def test_coalesce_keeps_the_order_a_full_sort_gives(namespace, monkeypatch):
         lst.append(node)
         lst.sort(key=key)
 
-    monkeypatch.setattr(coalesce, "insort", sort_again)
-    want_nodes, want_renames = real(st, twins, rows, node_groups=groups)
-    got_nodes, got_renames = seen["got"]
+    monkeypatch.setattr(coalesce_reference, "insort", sort_again)
+    want_nodes, want_renames = coalesce_reference.reference_coalesce(
+        st, twins, rows, node_groups=groups)
+    got_nodes, got_renames, _buckets = seen["got"]
 
     def canon(nodes):
         return sorted((n.instance_type, n.zone, n.capacity_type,
