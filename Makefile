@@ -55,8 +55,11 @@ battletest: lint
 	KT_SANITIZE=1 KT_BATTLE_SEEDS=24 KT_FUZZ_SEEDS=40 $(PYTHON) -m pytest tests/test_battle.py tests/test_fuzz_parity.py -q
 	KT_SANITIZE=1 $(PYTHON) -m pytest tests/ -q
 
+# one cell of the benchmark the driver runs (BENCHMARK.json lists them
+# all): the served path on one TPU, one JSON line last; exits non-zero
+# without a TPU
 bench:
-	$(PYTHON) bench.py
+	$(PYTHON) benchmarks/run.py --workload c2.burst --seed 1 --seconds 45 --trace 0
 
 # observability demo (docs/OBSERVABILITY.md): run the fake-cloud operator
 # demo with tracing on and print a /tracez + /statusz snapshot — per-span
@@ -152,8 +155,8 @@ multihost-dryrun:
 # group shape into megabatch blocks, run a CPU-sized hierarchical solve
 # end to end (one vmapped block wave, dual price loop under a contended
 # provisioner limit, warm-start repair + cross-block tail repack), and
-# judge the dev-host 1M scale model against the 250 ms budget — the
-# same model bench.py measure_hierarchical gates in check_budgets.
+# print the dev-host 1M scale model (its device wave is "not measured":
+# no run on the chip reaches this path).
 hier-demo:
 	JAX_PLATFORMS=cpu $(PYTHON) scripts/hier_demo.py
 
@@ -163,7 +166,7 @@ hier-demo:
 # judged on the learned posture with the controller off — then print the
 # before/after knob table and the throughput / critical-p99 scoreboard.
 # Exits non-zero if the learned posture breaks the never-worse contract
-# (the same gates bench.py check_budgets enforces).
+# (the three constants at the top of scripts/tune_demo.py).
 tune-demo:
 	JAX_PLATFORMS=cpu $(PYTHON) scripts/tune_demo.py
 

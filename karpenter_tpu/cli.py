@@ -6,7 +6,6 @@ adds the operational entry points around it):
 - ``demo``         run the full controller loop against the fake cloud
 - ``solve``        one-shot batch solve of a scenario JSON (or a generated one)
 - ``serve``        start the gRPC solver sidecar
-- ``bench``        run the BASELINE benchmark configs
 - ``metrics-doc``  regenerate docs/METRICS.md from the metric inventory
 - ``version``      print the package version
 
@@ -21,7 +20,6 @@ import argparse
 import json
 import os
 import sys
-from pathlib import Path
 
 from . import __version__
 
@@ -123,19 +121,6 @@ def cmd_serve(args) -> int:
     return serve_main(argv)
 
 
-def cmd_bench(args) -> int:
-    import subprocess
-
-    # bench_all.py lives at the repo root, not in the installed package
-    script = Path(__file__).resolve().parent.parent / "bench_all.py"
-    if not script.exists():
-        print("bench_all.py not found (benchmarks run from a repo checkout, "
-              "not an installed package)", file=sys.stderr)
-        return 2
-    return subprocess.call([sys.executable, str(script),
-                            "--configs", args.configs], cwd=str(script.parent))
-
-
 def cmd_metrics_doc(args) -> int:
     from .metrics import INVENTORY
 
@@ -234,10 +219,6 @@ def main(argv=None) -> int:
     v.add_argument("--small", action="store_true",
                    help="--warmup against the 20-type catalog")
     v.set_defaults(fn=cmd_serve)
-
-    b = sub.add_parser("bench", help="run BASELINE benchmark configs")
-    b.add_argument("--configs", default="1,2,3,4,5,6")
-    b.set_defaults(fn=cmd_bench)
 
     m = sub.add_parser("metrics-doc", help="regenerate docs/METRICS.md")
     m.add_argument("--out", default="docs/METRICS.md")

@@ -128,9 +128,6 @@ class DeprovisioningController:
         self.unavailable = getattr(provisioning, "unavailable", None)
         self._last_seqnum = -1
         self._last_action_at = 0.0
-        # per-phase wall-time accumulators (repack bench tick breakdown)
-        self.phase_s: Dict[str, float] = {}
-        self.phase_n: Dict[str, int] = {}
         self._single_cursor = 0  # rotating single-consolidation resume point
         self._last_eval_at = -1e18
         # sweep metrics must exist from construction (KT003)
@@ -334,13 +331,6 @@ class DeprovisioningController:
         terms = pod.scheduling_requirements()
         return any(reqs.compatible(node.labels) is None for reqs in terms)
 
-    def _phase(self, name: str, seconds: float) -> None:
-        """Accumulate per-phase wall time for the repack bench's tick
-        breakdown (screen / exact-confirm / prefix-search / ...); cheap dict
-        adds, reset by the harness."""
-        self.phase_s[name] = self.phase_s.get(name, 0.0) + seconds
-        self.phase_n[name] = self.phase_n.get(name, 0) + 1
-
     def _consolidation(self) -> Optional[Action]:
         pending = self.state.pending_pods()
         if pending:
@@ -391,23 +381,17 @@ class DeprovisioningController:
                         if ns.node.name in idx_of]
             # compat rows are computed only for candidate sources
             # (O(|cands| x N) host work, not O(N^2))
-            t0 = time.perf_counter()
             compat = compat_matrix(all_nodes, sources=cand_idx)
-            self._phase("compat_matrix", time.perf_counter() - t0)
             singles = [[i] for i in cand_idx] if run_single else []
             multis = self._multi_subsets(cand_idx, cands, idx_of) if run_multi else []
-            t0 = time.perf_counter()
             screen = screen_subset_deletes(all_nodes, singles + multis, compat,
                                            pmax_total=SCREEN_PMAX)
-            self._phase("device_screen", time.perf_counter() - t0)
 
             if multis:
-                t0 = time.perf_counter()
                 attempt = self._confirm_subsets(
                     cands, all_nodes, idx_of, multis,
                     screen.deletable[len(singles):],
                 )
-                self._phase("confirm_subsets", time.perf_counter() - t0)
                 if attempt is not None:
                     attempt = self._escalate_capped_delete(cands, attempt)
                     return attempt
@@ -425,13 +409,11 @@ class DeprovisioningController:
                 # like the serial loop it replaces
                 for lo in range(0, len(screened), SWEEP_MAX_SLOTS):
                     chunk = screened[lo:lo + SWEEP_MAX_SLOTS]
-                    t0 = time.perf_counter()
                     attempts = self._simulate_batch(
                         [[ns] for ns in chunk],
                         stop_on=lambda a: a is not None
                         and a.kind == "delete",
                     )
-                    self._phase("screened_confirm", time.perf_counter() - t0)
                     for attempt in attempts:
                         if attempt is not None and attempt.kind == "delete":
                             return attempt
@@ -439,9 +421,7 @@ class DeprovisioningController:
 
         # 2b) multi-node: binary search the largest disruption-cost prefix
         #     that can be deleted together with <=1 replacement
-        t0 = time.perf_counter()
         best_multi = self._prefix_search(cands, 2, len(cands))
-        self._phase("prefix_search", time.perf_counter() - t0)
         if best_multi is not None:
             return best_multi
 
@@ -451,34 +431,30 @@ class DeprovisioningController:
         #    resumes where it left off) because each try is a full what-if;
         #    an unbounded sweep over a big fleet's candidates costs minutes
         #    per reconcile while finding nothing on converged fleets
-        t0 = time.perf_counter()
-        try:
-            from ..solver.consolidation import SWEEP_MAX_SLOTS
+        from ..solver.consolidation import SWEEP_MAX_SLOTS
 
-            n = len(cands)
-            start = self._single_cursor % n
-            budget = min(SINGLE_TRIES_PER_PASS, n)
-            window = [cands[(start + k) % n][1] for k in range(budget)]
-            # the rotating window rides the sweep: each chunk is one
-            # vmapped dispatch instead of up to SWEEP_MAX_SLOTS sequential
-            # what-ifs; the first candidate (in rotation order) whose
-            # what-if confirms wins, exactly like the serial loop
-            tried = 0
-            for lo in range(0, budget, SWEEP_MAX_SLOTS):
-                chunk = window[lo:lo + SWEEP_MAX_SLOTS]
-                attempts = self._simulate_batch(
-                    [[ns] for ns in chunk],
-                    stop_on=lambda a: a is not None,
-                )
-                for j, attempt in enumerate(attempts):
-                    if attempt is not None:
-                        self._single_cursor = start + lo + j + 1
-                        return attempt
-                tried += len(chunk)
-            self._single_cursor = start + tried
-            return None
-        finally:
-            self._phase("single_fallback", time.perf_counter() - t0)
+        n = len(cands)
+        start = self._single_cursor % n
+        budget = min(SINGLE_TRIES_PER_PASS, n)
+        window = [cands[(start + k) % n][1] for k in range(budget)]
+        # the rotating window rides the sweep: each chunk is one
+        # vmapped dispatch instead of up to SWEEP_MAX_SLOTS sequential
+        # what-ifs; the first candidate (in rotation order) whose
+        # what-if confirms wins, exactly like the serial loop
+        tried = 0
+        for lo in range(0, budget, SWEEP_MAX_SLOTS):
+            chunk = window[lo:lo + SWEEP_MAX_SLOTS]
+            attempts = self._simulate_batch(
+                [[ns] for ns in chunk],
+                stop_on=lambda a: a is not None,
+            )
+            for j, attempt in enumerate(attempts):
+                if attempt is not None:
+                    self._single_cursor = start + lo + j + 1
+                    return attempt
+            tried += len(chunk)
+        self._single_cursor = start + tried
+        return None
 
     def _prefix_search(self, cands, lo: int, hi: int) -> Optional[Action]:
         """Binary-search the largest disruption-cost prefix of ``cands`` that
@@ -513,9 +489,7 @@ class DeprovisioningController:
                      if ns.node.name in names)
         if n_pods < int(0.7 * SCREEN_PMAX):
             return attempt  # genuinely small: the screen wasn't the binder
-        t0 = time.perf_counter()
         bigger = self._prefix_search(cands, len(attempt.nodes) + 1, len(cands))
-        self._phase("escalate_search", time.perf_counter() - t0)
         # compare SAVINGS, not node counts: candidates are disruption-ordered,
         # so a longer prefix of cheap nodes can be worth less than a confirmed
         # per-type subset of expensive ones
@@ -615,9 +589,7 @@ class DeprovisioningController:
         target_names = {ns.node.name for ns in targets}
         pods: List[PodSpec] = [p for ns in targets for p in ns.node.pods
                                if not p.is_daemon]
-        t0 = time.perf_counter()
         result = self._solve_what_if(pods, target_names)
-        self._phase("what_if_solve", time.perf_counter() - t0)
         return self._action_from_what_if(targets, result)
 
     def _action_from_what_if(
@@ -711,7 +683,6 @@ class DeprovisioningController:
                 if isinstance(res, BaseException):
                     return False
                 return stop_on(action_at(pos, res))
-        t0 = time.perf_counter()
         with trace.span("what_if_sweep", n_candidates=len(cands)):
             sweep = sweep_what_ifs(
                 self.scheduler, all_nodes, cands,
@@ -723,7 +694,6 @@ class DeprovisioningController:
                 registry=self.registry, trace=trace,
                 stop_on=sweep_stop,
             )
-        self._phase("what_if_sweep", time.perf_counter() - t0)
         for pos, i in enumerate(order):
             res = sweep.results[pos]
             if res is None:

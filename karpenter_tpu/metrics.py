@@ -502,7 +502,7 @@ INVENTORY = {
         "cross-request continuous-batching path: one vmapped program solves "
         "every slot in a single device round trip; serial fallbacks while a "
         "slot-rung program compiles behind observe 1 per dispatch).  "
-        "sum/count is the bench's batch_occupancy_mean."),
+        "sum/count is the mean occupancy."),
     MEGABATCH_FLUSH: (
         "counter", ("reason",),
         "Coalescer batch flushes by reason: 'full' (max-slots reached), "
@@ -861,8 +861,7 @@ INVENTORY = {
     WARMSTART_DURATION: (
         "histogram", (),
         "Wall time of one warm-start delta step (bookkeeping + any "
-        "subproblem solve), seconds — the bench gates its p50 at 1 ms on "
-        "the steady-state host path."),
+        "subproblem solve), seconds."),
     WARMSTART_DISPLACED: (
         "histogram", (),
         "Pods the delta step had to (re-)place: added pods plus pods "
@@ -954,8 +953,7 @@ INVENTORY = {
     TS_SAMPLE_DURATION: (
         "histogram", (),
         "Wall time of one sampler tick (registry snapshot + occupancy "
-        "hooks), seconds — the sampler's own cost, gated <=2% of serving "
-        "by bench.py measure_ts_overhead."),
+        "hooks), seconds — the sampler's own cost."),
     SLO_REQUESTS: (
         "counter", ("class", "outcome"),
         "Solve RPCs by priority class and SLO outcome: 'ok' served, "
@@ -1017,8 +1015,7 @@ INVENTORY = {
     TUNING_STEP_DURATION: (
         "histogram", (),
         "Wall time of one controller decision (windowed reads + SLO "
-        "evaluation + the move), seconds — the controller's own cost, "
-        "gated <= 2% of serving by bench.py measure_tuning."),
+        "evaluation + the move), seconds — the controller's own cost."),
     FLEET_PEER_FETCH: (
         "counter", ("outcome",),
         "Per-peer /fleetz fan-out fetches by outcome ('ok' / 'timeout' "

@@ -1,8 +1,9 @@
 """Named provisioning scenarios, built from nothing but their arguments.
 
-The one place the benches (``bench.py``, ``bench_all.py``), the on-chip
-smoke (``chip_smoke.py``) and the profiling scripts get their pod sets from,
-so "config 2" means the same 50,000 pods everywhere.  Pure model code: no
+The one place the on-chip smoke (``chip_smoke.py``), the profiling scripts
+and the tests get their pod sets from, so "config 2" means the same 50,000
+pods everywhere (``benchmarks/gen.py`` keeps its own copy of the c2/c3
+shapes: the benchmark imports nothing of the program it measures).  Pure model code: no
 jax, no solver import.
 """
 

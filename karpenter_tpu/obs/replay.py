@@ -1,7 +1,7 @@
 """Trace-replay harness — recorded or synthetic traffic through the real
 gRPC stack (ISSUE 15; the ROADMAP-item-5 prerequisite).
 
-The self-tuning controller the roadmap wants cannot be bench-gated
+The self-tuning controller the roadmap wants cannot be judged
 against uniform load: the knobs it tunes (coalescer wait/slots, brownout
 thresholds) only matter under traffic that looks like production —
 bursts, diurnal swings, session churn.  This module closes that gap with
@@ -21,10 +21,11 @@ three pieces:
   shared pool, and every request's scheduled-vs-actual send lag is
   observed into ``karpenter_replay_lag_seconds``.
 - **Fidelity** — :func:`fidelity` compares the replayed inter-arrival
-  distribution and class mix against the capture, so ``bench.py``'s
-  ``measure_replay_fidelity`` can GATE that the harness reproduces the
-  traffic it claims to (a replay that silently serializes into uniform
-  load would bless knob settings against the wrong workload).
+  distribution and class mix against the capture, so a caller
+  (``scripts/replay_traffic.py``, ``tests/test_fleet_trace.py``) can check
+  that the harness reproduces the traffic it claims to (a replay that
+  silently serializes into uniform load would bless knob settings against
+  the wrong workload).
 
 Wire-level tracing rides for free: the sessions the replayer drives are
 ordinary ``DeltaSession``\\ s, so every replayed request propagates trace
@@ -228,7 +229,7 @@ def load_capture(path: str) -> Tuple[List[dict], dict]:
 
 
 def default_pods_factory(n: int, tag: str):
-    """Unconstrained churn pods (the bench's warm-start shape: a few
+    """Unconstrained churn pods (the warm-start shape: a few
     deployment families, no topology) — replay captures carry SHAPES,
     so the payload is synthesized to match the pod count."""
     from ..models.pod import PodSpec
@@ -444,8 +445,8 @@ class Replayer:
             implicit = self._implicit_establishes
         outcomes: Dict[str, int] = {}
         classes: Dict[str, int] = {}
-        # per-class latency + outcome breakdown: the self-tuning bench
-        # gate (bench.py measure_tuning) judges CRITICAL p99 and sheds
+        # per-class latency + outcome breakdown: the self-tuning verdict
+        # (scripts/tune_demo.py) judges CRITICAL p99 and sheds
         # separately — aggregate wall_ms would let a tuned run trade
         # critical latency for batch throughput and still pass
         by_class: Dict[str, dict] = {}
@@ -482,9 +483,7 @@ def fidelity(records: List[dict], report: dict) -> dict:
     """How faithfully the replay reproduced the capture, in VIRTUAL time
     (achieved offsets are scaled back by the speedup, so the numbers
     compare to the capture directly): relative error of the
-    inter-arrival p50/p90, the class mix, and the error count.  The
-    bench gate (``measure_replay_fidelity``) fails on mix drift, errors,
-    or p50 error past its tolerance."""
+    inter-arrival p50/p90, the class mix, and the error count."""
     planned_ts = sorted(float(r.get("t", 0.0)) for r in records)
     planned_ia = sorted(_interarrivals(planned_ts))
     achieved_ia = sorted(_interarrivals(sorted(report["achieved"])))

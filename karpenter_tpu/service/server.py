@@ -290,7 +290,7 @@ class SolvePipeline:
         self._fwd_pclass: dict = {}
         # admission control (docs/ADMISSION.md): the bounded priority queue
         # + breaker + brownout front door.  None = construct from env
-        # (KT_ADMISSION=0 disables); False = force off (bench A/B runs).
+        # (KT_ADMISSION=0 disables); False = force off.
         # Disabled keeps the raw FIFO above verbatim — byte-identical to
         # the pre-admission path.
         if admission is None and admission_enabled():

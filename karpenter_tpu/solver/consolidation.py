@@ -534,7 +534,6 @@ def sweep_what_ifs(
     device_ok = (
         scheduler.backend in ("auto", "tpu")
         and mesh_shardable(mesh)
-        and scheduler._tensorize_cache is not None
         and (scheduler.backend == "tpu" or not scheduler._guard.enabled
              or scheduler._guard.healthy)
     )

@@ -520,7 +520,8 @@ def solve_hierarchical(
     Returns ``None`` when flat is the right (or only warm) program — the
     scheduler falls through to ``_solve_tpu``; the metrics label says why.
     ``stats``, when given, receives per-stage timings and dispatch counts
-    (the bench gate asserts exactly ONE dispatch per block wave).
+    (``tests/test_hierarchy.py`` asserts exactly ONE dispatch per block
+    wave).
 
     Re-entrancy: repair re-seats stragglers through ``scheduler._solve_once``
     — if that inner solve routed hierarchically again (a straggler batch at/
@@ -863,8 +864,8 @@ def _solve_hierarchical(
 
 def scale_model(measured: dict, n_pods: int) -> dict:
     """Project the hierarchical wall at ``n_pods`` from one measured run —
-    pure host math (no jax), shared by ``bench.measure_hierarchical`` and
-    ``scripts/profile_solve.py --hier``.
+    pure host math (no jax), used by ``scripts/profile_solve.py --hier``
+    and ``scripts/hier_demo.py``.
 
     Stage scaling: partition/entry build and repair are host-linear in the
     pod count; a block wave is ONE vmapped dispatch whose per-slot scan

@@ -98,14 +98,6 @@ def _delta_local_enabled() -> bool:
     return os.environ.get("KT_DELTA_LOCAL", "1") != "0"
 
 
-def _compile_behind_enabled() -> bool:
-    """Measurement escape hatch: KT_COMPILE_BEHIND=0 serves cold shapes from
-    the warm tier WITHOUT starting the background compile — used by the
-    cold-start benchmark subprocess, which exits right after one solve and
-    must not wait out a 40 s XLA compile at interpreter shutdown."""
-    return os.environ.get("KT_COMPILE_BEHIND", "1") != "0"
-
-
 def _soft_spreads(pod: PodSpec):
     return [t for t in pod.topology_spread if not t.hard]
 
@@ -458,7 +450,7 @@ class BatchScheduler:
         registry: Optional[Registry] = None,
         mesh=None,
         native_batch_limit: int = NATIVE_BATCH_LIMIT,
-        compile_behind: Optional[bool] = None,  # None: KT_COMPILE_BEHIND env
+        compile_behind: bool = True,
         tracer: Optional[Tracer] = None,
     ) -> None:
         assert backend in ("auto", "tpu", "native", "oracle")
@@ -470,9 +462,7 @@ class BatchScheduler:
         self.tracer = tracer if tracer is not None else tracer_for(self.registry)
         self.mesh = mesh
         self.native_batch_limit = native_batch_limit
-        self.compile_behind = (
-            _compile_behind_enabled() if compile_behind is None else compile_behind
-        )
+        self.compile_behind = compile_behind
         self._tpu = TpuSolver(registry=self.registry)
         # change-gated stall logging; _start_warm runs at fence time, and
         # WHICH thread fences depends on the caller (pipeline dispatcher vs
@@ -484,12 +474,8 @@ class BatchScheduler:
         # blocking precompile reports instead of "N accepted"
         self._warm_errors: List[str] = []      # guarded-by: _cold_lock
         # incremental host tensorize: group-level tensors built once per
-        # batch shape, reused across solves (models/tensorize.TensorizeCache;
-        # KT_TENSORIZE_CACHE=0 forces the from-scratch path for A/B runs)
-        self._tensorize_cache: Optional[TensorizeCache] = (
-            TensorizeCache()
-            if os.environ.get("KT_TENSORIZE_CACHE", "1") != "0" else None
-        )
+        # batch shape, reused across solves (models/tensorize.TensorizeCache)
+        self._tensorize_cache = TensorizeCache()
         # hang protection for the auto policy's device dispatches (a PJRT
         # call that never returns must degrade the reconcile loop to the
         # warm host tiers, not freeze it — see solver/guard.py); forced
@@ -880,9 +866,6 @@ class BatchScheduler:
             # per-request probe is one attribute read; the pipeline counts
             # the resulting single-request flushes under mesh_serial
             return None
-        if self._tensorize_cache is None:
-            return None  # bucketing leans on cached tensorize; without it
-            # the probe would pay a full host build per queued request
         pods = list(kwargs.get("pods") or ())
         if not pods or not kwargs.get("allow_new_nodes", True):
             return None
@@ -1408,9 +1391,6 @@ class BatchScheduler:
             # LARGE unconstrained groups on every backend, so forced-tpu
             # small-batch tests/fuzz keep byte-stable scan results
             return result
-        if self._tensorize_cache is None:
-            return result  # without cached tensorize the probe would pay
-            # a full host build per solve — not the rung's trade
         guarded = self.backend == "auto" and self._guard.enabled
         if result.served_cold or (guarded and not self._guard.healthy):
             relax_mod.record_outcome(self.registry, "skipped")
@@ -1832,17 +1812,10 @@ class BatchScheduler:
         Returns (tensors, seconds spent)."""
         t0 = time.perf_counter()
         with trace.span("tensorize") as span:
-            if self._tensorize_cache is not None:
-                st, tier = self._tensorize_cache.tensorize(
-                    pods, provisioners, instance_types,
-                    daemonsets=daemonsets, unavailable=unavailable,
-                )
-            else:
-                st = tensorize(
-                    pods, provisioners, instance_types,
-                    daemonsets=daemonsets, unavailable=unavailable,
-                )
-                tier = "off"
+            st, tier = self._tensorize_cache.tensorize(
+                pods, provisioners, instance_types,
+                daemonsets=daemonsets, unavailable=unavailable,
+            )
             span.annotate(tier=tier)
         dt = time.perf_counter() - t0
         self.registry.histogram(TENSORIZE_DURATION).observe(dt)

@@ -1089,7 +1089,7 @@ def mega_key_at_slots(key: tuple, slots: int, mesh) -> tuple:
 def multihost_fence_enabled() -> bool:
     """Per-host megabatch fences (read only the process-addressable slot
     shards) — default on; ``KT_MULTIHOST=0`` forces the legacy whole-batch
-    readback (the bench A/B and an emergency kill switch)."""
+    readback (an emergency kill switch)."""
     import os
 
     return os.environ.get("KT_MULTIHOST", "1") != "0"
@@ -1314,7 +1314,7 @@ class TpuSolver:
             self.registry.counter(COALESCE).inc({"what": what}, value=0.0)
         # persistent compile cache: every process that constructs a solver
         # shares previously compiled XLA programs — a restarted replica
-        # skips the compile (bench.py measure_cold_restart gates it)
+        # skips the compile
         _init_jit_cache()
         # injectable clock for the warm-failure backoff (tests advance a
         # FakeClock past WARM_FAILURE_BACKOFF instead of sleeping it out)

@@ -2,7 +2,7 @@
 
 Not a comparison with the oracle but the ground-truth rules a placement
 must satisfy, checked from the result alone.  Shared by the fuzz suites,
-``scripts/fuzz_sweep.py``, the bench's relax gate and ``chip_smoke.py`` —
+``scripts/fuzz_sweep.py`` and ``chip_smoke.py`` —
 the one place that says what a *valid* answer is, independent of which
 tier (device scan, relax rung, native, oracle) produced it.
 """

@@ -14,8 +14,8 @@ Three tiers, cheapest first (``DeltaOutcome.mode``):
   first-fit into the surviving nodes' residual capacity with a vectorized
   numpy pass (label/taint compatibility via ``node_classes`` memoization,
   resources via one ``[N, R]`` residual matrix carried incrementally across
-  the delta chain).  Sub-millisecond on the CPU dev host — the steady-state
-  p50 the bench gates (``measure_warmstart``).
+  the delta chain).  The steady-state tier; its latency through the served
+  path is unmeasured until the ``c2.reconcile`` cell runs (PERF.md §7).
 - **scan** — displaced pods that carry their own constraints (or need new
   nodes) are solved by the regular device scan *seeded from the previous
   assignment*: the subproblem's existing-node tensors (residuals, selector
@@ -31,11 +31,12 @@ Three tiers, cheapest first (``DeltaOutcome.mode``):
   conservative: falling back costs latency, never correctness.
 
 Cost parity vs the from-scratch solve is pinned by ``scripts/fuzz_sweep.py
---delta`` (random add/remove/ICE chains) and gated in ``bench.py`` at the
-existing ``cost_ratio <= 1.02`` ceiling.  When the perturbation is disjoint
-(no displaced pod interacts with a surviving placement), untouched
-assignments are byte-identical to the previous solve BY CONSTRUCTION — the
-incremental step never moves a pod it did not have to.
+--delta`` (random add/remove/ICE chains, every step validated and held to
+that script's cost ceiling); no cell of ``BENCHMARK.json`` holds it to the
+``cost_ratio <= 1.02`` bound until ``c2.reconcile`` runs (PERF.md §7).  When
+the perturbation is disjoint (no displaced pod interacts with a surviving
+placement), untouched assignments are byte-identical to the previous solve
+BY CONSTRUCTION — the incremental step never moves a pod it did not have to.
 
 Ownership contract: ``delta_solve`` CONSUMES ``prev`` — the surviving node
 objects and the assignments dict are carried into the returned result (and

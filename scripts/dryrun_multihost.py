@@ -26,9 +26,8 @@ Modes:
         # per-host fence (KT_MULTIHOST=1) vs whole-batch readback (=0)
         # on a lone 1-slot meshed flush — the latency-tax gate's input
 
-``bench.py measure_multihost_fence`` runs both modes in subprocesses and
-gates the numbers in ``check_budgets``; ``make multihost-dryrun`` runs the
-launcher in CI.  Machine-readable verdicts: one ``MHOSTW {...}`` JSON line
+``make multihost-dryrun`` runs the launcher in CI; the A/B's latency tax
+is unmeasured on the chip (four chips: nothing run, PERF.md §7).  Machine-readable verdicts: one ``MHOSTW {...}`` JSON line
 per worker, one ``MHOST {...}`` summary from the launcher, one
 ``LONE_AB {...}`` from the A/B mode.
 """

@@ -95,7 +95,7 @@ catalog = generate_catalog(full=False)
 
 
 #: per-step cost-parity ceiling for the delta chains.  Wider than the 1.02
-#: production gate (bench.py measure_warmstart, steady-state churn) on
+#: bound of the served cells (BENCHMARK.json cost_ratio_max) on
 #: purpose: the fuzz perturbs TINY clusters adversarially — a 1-pod removal
 #: can strand half a node, which is a rounding error at 20k pods but several
 #: percent of a 20-pod scenario's bill; the KT_DELTA_MAX_FRAC fallback
@@ -423,13 +423,23 @@ def _hier_fuzz_scenario(seed: int, disjoint: bool):
     return pods
 
 
+def _placement_canon(result):
+    """Node-name-independent placement view: pod -> (instance type, zone,
+    capacity type, co-resident pod multiset).  Two solves are
+    placement-identical iff the canon maps match — node NAMES always
+    differ (the process-global SimNode counter)."""
+    by_node = {n.name: (n.instance_type, n.zone, n.capacity_type,
+                        tuple(sorted(p.name for p in n.pods)))
+               for n in result.nodes}
+    return {pn: by_node.get(nn) for pn, nn in result.assignments.items()}
+
+
 def run_hier_seeds(n_seeds: int) -> int:
     """Hierarchical-decomposition fuzz (ISSUE 16); returns the number of
     failing seeds.  Per seed: disjoint byte-parity, component-never-split
     under forced block pressure, repair completeness vs flat."""
     import numpy as np
 
-    from bench import _placement_canon
     from karpenter_tpu.models.provisioner import Provisioner
     from karpenter_tpu.models.tensorize import tensorize
     from karpenter_tpu.solver import hierarchy as H
@@ -681,7 +691,7 @@ for suite in suites:
     mean = sum(vals) / max(len(vals), 1)
     worst = sorted(ratios.items(), key=lambda kv: -kv[1])[:5]
     extra = ""
-    if cached and sched is not None and sched._tensorize_cache is not None:
+    if cached and sched is not None:
         c = sched._tensorize_cache
         extra = f" cache_hits={c.hits} misses={c.misses}"
     print(f"{suite}: n={len(vals)} mean={mean:.4f} worst={worst}{extra}")

@@ -8,11 +8,12 @@ Three acts, all on the dev host (JAX_PLATFORMS=cpu):
 2. A real hierarchical solve on a CPU-sized overlapping batch — one
    vmapped block wave, the dual price loop (a provisioner limit is set
    tight enough to contend across blocks), warm-start repair and the
-   cross-block tail repack — printing the stats the bench gates.
+   cross-block tail repack — printing the stats
+   ``tests/test_hierarchy.py`` asserts on.
 3. The scale model seeded with the measured HOST stats.  The device wave
    is "not measured" here by construction: only a run on the chip
-   (`bench.py measure_hierarchical`) supplies the per-pod device rate the
-   1M wall and its 250 ms budget are judged by.
+   supplies the per-pod device rate the 1M wall and its 250 ms budget are
+   judged by, and no cell of BENCHMARK.json reaches this path yet.
 
 The full 1M batch never dispatches here — a CPU host neither holds the
 32-slot carry nor finishes the wave in demo time.
@@ -115,8 +116,7 @@ def main() -> int:
           f"({len(res.nodes)} nodes, {len(res.infeasible)} infeasible)")
 
     # ---- act 3: the dev-host 1M projection ----------------------------
-    # seeded from the UNCONTENDED measured stats — the same construction
-    # `bench.py measure_hierarchical` gates (its scenario carries no
+    # seeded from the UNCONTENDED measured stats (that scenario carries no
     # binding provisioner limit; the contended run above is the price-
     # loop showcase, and its capacity-shortage repair is not a property
     # of the 1M shape)

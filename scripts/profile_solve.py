@@ -123,9 +123,8 @@ def top_kernels(xplane_path: str, k: int = 10):
 #: measured dev-host entry-build rate (ms per group): the per-block entry
 #: construction (counts mask + zone-share suffix projection) is group-
 #: count-bound numpy work, but building it needs the solver's jax-backed
-#: base arrays — this script stays jax-free, so it projects from the rate
-#: bench.measure_hierarchical measured on the CPU dev host
-#: (21.7 ms / 400 groups)
+#: base arrays — this script stays jax-free, so it projects from a rate
+#: once read on a CPU dev host (21.7 ms / 400 groups)
 _ENTRIES_MS_PER_GROUP = 0.055
 
 
@@ -134,8 +133,9 @@ def _profile_hier() -> int:
     is numpy: scenario build, tensorize, constraint-reachability
     partition, LPT block packing, and the scale-model wall projection.
     The entry build and the block wave need jax (they are projected from
-    measured rates instead); ``bench.py measure_hierarchical`` owns the
-    measured end-to-end numbers.  Asserts jax was never imported."""
+    measured rates instead); nothing measures the hierarchical path end to
+    end (no cell of BENCHMARK.json reaches it).  Asserts jax was never
+    imported."""
     from karpenter_tpu.models import labels as L
     from karpenter_tpu.models.catalog import DEFAULT_ZONES, generate_catalog
     from karpenter_tpu.models.pod import (LabelSelector, PodSpec,
@@ -210,10 +210,6 @@ def main(argv=None) -> int:
     ap.add_argument("--pods", type=int, default=50_000)
     ap.add_argument("--trace-dir", default="/tmp/kt-trace")
     ap.add_argument("--repeats", type=int, default=5)
-    ap.add_argument("--delta", action="store_true",
-                    help="also profile the warm-start delta chain "
-                         "(steady-state churn p50/p99 + mode mix) and the "
-                         "batched consolidation sweep")
     ap.add_argument("--lint-surface", action="store_true",
                     help="dump the KT014 compile-surface audit as JSON — "
                          "the runtime-constructible signature vocabulary "
@@ -243,7 +239,7 @@ def main(argv=None) -> int:
     if args.hier:
         return _profile_hier()
 
-    from bench import build_scenario
+    from karpenter_tpu.models.scenarios import config2_scenario
 
     import jax
     import jax.numpy as jnp
@@ -272,7 +268,7 @@ def main(argv=None) -> int:
     # same pending set; shape tier = fresh pod objects, same shapes)
     from karpenter_tpu.models.tensorize import TensorizeCache
 
-    pods, provs, catalog = build_scenario()
+    pods, provs, catalog = config2_scenario()
     if args.pods != 50_000:
         pods = pods[:args.pods]
     t0 = time.perf_counter()
@@ -286,7 +282,7 @@ def main(argv=None) -> int:
     _st2, tier = cache.tensorize(pods, provs, catalog)
     out["tensorize_steady_ms"] = round((time.perf_counter() - t0) * 1000.0, 2)
     out["tensorize_steady_tier"] = tier
-    pods_fresh = build_scenario()[0]
+    pods_fresh = config2_scenario()[0]
     if args.pods != 50_000:
         pods_fresh = pods_fresh[:args.pods]
     t0 = time.perf_counter()
@@ -344,17 +340,6 @@ def main(argv=None) -> int:
         gz = sorted(glob.glob(os.path.join(args.trace_dir, "**", "*.json.gz"),
                               recursive=True), key=os.path.getmtime)
         out["trace_file"] = gz[-1] if gz else None
-
-    # 6. warm-start delta chain + batched consolidation sweep (ISSUE 6):
-    # the same measurements the bench gates, sized down to the profiled
-    # pod count — the per-mode mix tells you whether a chain is riding the
-    # host fast path or repeatedly falling back
-    if args.delta:
-        import bench as benchmod
-
-        out["warmstart"] = benchmod.measure_warmstart(
-            pods_n=min(args.pods, 20_000))
-        out["consolidation_sweep"] = benchmod.measure_consolidation_sweep()
 
     print(json.dumps(out, indent=2))
     return 0

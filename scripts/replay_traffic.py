@@ -16,8 +16,7 @@ gRPC stack at programmable speedup:
     python scripts/replay_traffic.py --replay /tmp/burst.jsonl \
         --speedup 4 --target 127.0.0.1:50151
 
-Prints one JSON line: the replay report + fidelity verdict (the same
-numbers ``bench.py``'s ``measure_replay_fidelity`` gates).
+Prints one JSON line: the replay report + fidelity verdict.
 """
 
 from __future__ import annotations

@@ -196,7 +196,7 @@ def test_inventory_metrics_are_emitted(small_catalog):
     # replica; full-population zero-init is asserted by tests/
     # test_tuning.py::test_zero_init_registers_full_population and the
     # family is exercised end to end by the controller tests and
-    # bench.py measure_tuning
+    # scripts/tune_demo.py
     tuning_family = {m for m in INVENTORY
                      if m.startswith("karpenter_tuning_")}
 

@@ -623,11 +623,11 @@ class TestRepackConvergence:
         """The end-to-end repack (BASELINE config 4 at test scale): driving
         the full ladder to convergence with the device-screened loop must
         achieve >= 0.98x the savings of the oracle-driven loop, with every
-        evicted pod rebound.  The full-scale run is bench_all config 4."""
-        from bench_all import _repack_to_convergence
+        evicted pod rebound.  Nothing runs it at full scale (5k nodes)."""
+        from repack_fleet import repack_to_convergence
 
-        dev = _repack_to_convergence(small_catalog, 80, "auto", False)
-        orc = _repack_to_convergence(small_catalog, 80, "oracle", True)
+        dev = repack_to_convergence(small_catalog, 80, "auto", False)
+        orc = repack_to_convergence(small_catalog, 80, "oracle", True)
         assert dev["pending_end"] == 0 and orc["pending_end"] == 0
         assert orc["saved"] > 0
         assert dev["saved"] >= 0.98 * orc["saved"], (dev, orc)

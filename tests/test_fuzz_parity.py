@@ -42,7 +42,8 @@ from karpenter_tpu.solver.validate import validate_solution
 
 PARITY = 1.02
 #: random-adversarial-shape quality bounds.  The curated BASELINE configs
-#: are gated at PARITY (bench_all / tpu-solver suites); random fuzz shapes
+#: are held to PARITY (BENCHMARK.json's cost ceiling on the chip and the
+#: tpu-solver suites here); random fuzz shapes
 #: get a hard per-seed ceiling plus a tight MEAN gate (test_zz_fuzz_cost_mean)
 #: so a systematic regression fails even when each seed stays under the
 #: ceiling.
