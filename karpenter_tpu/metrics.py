@@ -185,6 +185,13 @@ REQUEST_DECODE_PODS = "karpenter_solver_request_decode_pods_total"
 #: field by field: the first pod of each shape, a pod whose bytes give no
 #: shape key, and every pod after the table gave up on its request)
 REQUEST_DECODE_HOW = ("templated", "plain")
+# ---- the client's side of the same wire (service/codec.py PodShapes) ----
+REQUEST_ENCODE_PODS = "karpenter_solver_request_encode_pods_total"
+#: how a pod of a request became a pb.Pod (KT003 zero-init source):
+#: 'templated' (its name plus the bytes of the first pod of its shape) vs
+#: 'plain' (built field by field: the first pod of each shape, a pod whose
+#: values give no shape key, and every pod after the table gave up)
+REQUEST_ENCODE_HOW = ("templated", "plain")
 # ---- the device scan's axes (solver/tpu.py TpuSolver._count_scan) --------
 SCAN_AXIS = "karpenter_solver_scan_axis_total"
 #: what a device scan ran at (KT003 zero-init source): 'groups' (serial
@@ -578,6 +585,20 @@ INVENTORY = {
         "up.  Pods of one deployment differ in name only, so a healthy "
         "provisioning batch reads almost all 'templated' (50,000 pods in "
         "20 deployments: 49,980).  No table outlives its request."),
+    REQUEST_ENCODE_PODS: (
+        "counter", ("how",),
+        "Pods a RemoteScheduler encoded onto Solve requests (pending pods, "
+        "daemonsets and the pods of existing nodes), on the CLIENT's "
+        "registry, by how: 'templated' — every field encode_pod reads but "
+        "the name equals an earlier pod's of the same request, so the pod "
+        "went out as its name plus that pod's serialized bytes; 'plain' — "
+        "built field by field by encode_pod: the first pod of each shape, "
+        "a pod whose values give no shape key, and every pod after the "
+        "request's table found fewer than half hits in its first 512 pods "
+        "and gave up.  The mirror of "
+        "karpenter_solver_request_decode_pods_total at the sidecar's door: "
+        "a healthy provisioning batch reads almost all 'templated' on both "
+        "sides.  No table outlives its request."),
     SCAN_AXIS: (
         "counter", ("axis",),
         "What the device scans ran at, summed over device solves (one "

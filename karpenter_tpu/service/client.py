@@ -30,6 +30,8 @@ from ..metrics import (
     FLEET_ENDPOINTS,
     FLEET_FAILOVER_REASONS,
     FLEET_FAILOVERS,
+    REQUEST_ENCODE_HOW,
+    REQUEST_ENCODE_PODS,
     Registry,
     registry as default_registry,
 )
@@ -626,6 +628,9 @@ class RemoteScheduler:
         # creates the sample; construction alone does not)
         self.registry.counter(REMOTE_FALLBACK_SOLVES).inc(value=0.0)
         self.registry.gauge(REMOTE_DEGRADED).set(0)
+        for how in REQUEST_ENCODE_HOW:
+            self.registry.counter(REQUEST_ENCODE_PODS).inc(
+                {"how": how}, value=0)
         faults_mod.zero_init_recovery(self.registry)
 
     #: RPC status codes that mean "the sidecar is not reachable right now".
@@ -719,7 +724,9 @@ class RemoteScheduler:
             # transports, the whole sidecar).
             with trace.span("remote", target=self.target) as span:
                 wire_tid, wire_parent = trace.wire_context()
-                with trace.span("encode", n_pods=len(pods)):
+                with trace.span("encode", n_pods=len(pods)) as encode:
+                    # one table of pod shapes per request, dropped with it
+                    shapes = codec.PodShapes()
                     req = codec.encode_request(
                         pods, provisioners, instance_types,
                         existing_nodes=existing_nodes, daemonsets=daemonsets,
@@ -730,7 +737,14 @@ class RemoteScheduler:
                         deadline_ms=(self.deadline_s * 1000.0
                                      if self.deadline_s else None),
                         trace_id=wire_tid, parent_span=wire_parent,
+                        shapes=shapes,
                     )
+                    encoded = self.registry.counter(REQUEST_ENCODE_PODS)
+                    encoded.inc({"how": "templated"},
+                                value=shapes.templated_pods)
+                    encoded.inc({"how": "plain"}, value=shapes.plain_pods)
+                    encode.annotate(shapes=shapes.shapes,
+                                    templated_pods=shapes.templated_pods)
                 # the wire deadline budget also bounds the RPC itself: a
                 # caller with 250ms left must not block 60s on the channel
                 rpc_timeout = (min(self.client.timeout, self.deadline_s)
@@ -803,14 +817,9 @@ class RemoteScheduler:
                     if served_by:
                         span.annotate(replica=served_by)
                     with trace.span("decode"):
-                        result = codec.decode_response(resp)
-                        # re-attach real PodSpecs to returned nodes (wire
-                        # carries names only)
-                        by_name = {p.name: p for p in pods}
-                        for node in result.nodes:
-                            node.pods = [by_name.get(p.name, p)
-                                         for p in node.pods]
-                    return result
+                        # the wire carries names only: the nodes come back
+                        # holding the caller's own PodSpecs
+                        return codec.decode_response(resp, pods)
         self.registry.counter(REMOTE_FALLBACK_SOLVES).inc()
         # recovery-outcome funnel (KT016): every local-fallback serve IS a
         # recovery from a transport-path failure, injected or organic

@@ -75,6 +75,127 @@ def encode_pod(p: PodSpec) -> pb.Pod:
     return out
 
 
+#: the one-byte varints (a pod's name is nearly always under 128 bytes)
+_VARINT1 = tuple(bytes((n,)) for n in range(0x80))
+
+
+def _varint(n: int) -> bytes:
+    if n < 0x80:
+        return _VARINT1[n]
+    out = bytearray()
+    while n > 0x7F:
+        out.append((n & 0x7F) | 0x80)
+        n >>= 7
+    out.append(n)
+    return bytes(out)
+
+
+def _name_field(name: str) -> bytes:
+    """``pb.Pod.name`` on the wire: field 1, length-delimited — and nothing
+    at all for an empty name (proto3 leaves a default off the wire)."""
+    if not name:
+        return b""
+    raw = name.encode()
+    return b"\n" + _varint(len(raw)) + raw
+
+
+def _shape_key(p: PodSpec) -> tuple:
+    """The value of every field :func:`encode_pod` reads except ``name``,
+    as far apart as the wire holds them: a dict in its own order (a map's
+    bytes follow it) and a zero by its ``repr`` (``-0.0 == 0.0`` in Python,
+    not in a ``pb.Pod``).  Hashing it raises ``TypeError`` where a value
+    does not hash."""
+    cost, requests = p.deletion_cost, p.requests
+    return (
+        p.namespace, tuple(p.labels.items()),
+        repr(requests) if 0 in requests.values() else tuple(requests.items()),
+        tuple(p.node_selector.items()),
+        tuple(map(tuple, p.required_affinity_terms)),
+        tuple(p.tolerations), tuple(p.topology_spread),
+        tuple(p.affinity_terms), p.priority, cost or repr(cost), p.owner_key,
+        tuple(p.volume_zone_requirements), p.gang_id, p.gang_size,
+    )
+
+
+class PodShapes:
+    """The pod shapes of ONE request on its way out: a ``pb.Pod`` is built
+    once per distinct shape, not once per pod — the client's half of what
+    :class:`PodTemplates` does at the sidecar's door.
+
+    The first pod of a :func:`_shape_key` goes through :func:`encode_pod`,
+    which stays the only code that turns a ``PodSpec``'s fields into a
+    ``pb.Pod``'s; its serialized bytes after the leading ``name`` field are
+    the key's template, and every later pod of the key is its own name
+    field plus those bytes.  The pods of one repeated field are parsed
+    into their message in one merge, in the order given: the message is
+    equal to the one ``encode_pod`` per pod builds, and each pod's bytes
+    still start with its name, which is what ``PodTemplates._key`` reads.
+
+    The table watches its own hit share as ``PodTemplates`` does: fewer
+    than half hits ``PROBE`` pods into the request and the rest encode
+    plainly.  One table serves one request — ``pods``, the pods of
+    ``existing_nodes``, ``daemonsets`` — and is dropped with it; nothing is
+    kept between requests and nothing is memoised on a pod."""
+
+    #: pods a request encodes before the table judges its hit share
+    PROBE = 512
+
+    def __init__(self) -> None:
+        self._tails: Dict[tuple, bytes] = {}
+        self._seen = 0
+        self._live = True
+        #: pods written from a template / pods that went through encode_pod
+        self.templated_pods = 0
+        self.plain_pods = 0
+
+    @property
+    def shapes(self) -> int:
+        """Distinct shapes the table holds (it stops adding to them once
+        it has given up on the request)."""
+        return len(self._tails)
+
+    def extend(self, msg, field: str, pods: Sequence[PodSpec]) -> None:
+        """``getattr(msg, field).extend(encode_pod(p) for p in pods)``,
+        by template."""
+        self.plain_pods += len(pods)
+        if not self._live:
+            getattr(msg, field).extend(encode_pod(p) for p in pods)
+            return
+        # every pod is one length-delimited (wire type 2) entry of `field`
+        tag = _varint(msg.DESCRIPTOR.fields_by_name[field].number << 3 | 2)
+        tails, key_of, head_of, varint = (
+            self._tails, _shape_key, _name_field, _varint)
+        seen, hits = self._seen, 0
+        parts: List[bytes] = []
+        it = iter(pods)
+        for p in it:
+            try:
+                key = key_of(p)
+                tail = tails.get(key)
+                head = head_of(p.name)
+            except (TypeError, AttributeError, UnicodeError):
+                # a value that does not hash, a name that is no str: the
+                # plain build says what the wire makes of them
+                key = tail = None
+            if tail is not None:
+                parts += (tag, varint(len(head) + len(tail)), head, tail)
+                hits += 1
+            else:
+                data = encode_pod(p).SerializeToString()
+                parts += (tag, varint(len(data)), data)
+                if key is not None and data.startswith(head):
+                    tails[key] = data[len(head):]
+            seen += 1
+            if seen == self.PROBE and 2 * (self.templated_pods + hits) < seen:
+                self._live = False
+                break
+        self._seen = seen
+        self.templated_pods += hits
+        self.plain_pods -= hits
+        msg.MergeFromString(b"".join(parts))
+        getattr(msg, field).extend(encode_pod(q) for q in it)
+
+
 def encode_instance_type(it: InstanceType) -> pb.InstanceType:
     out = pb.InstanceType(name=it.name)
     out.requirements.extend(_req(r) for r in it.requirements.to_list())
@@ -121,7 +242,8 @@ def encode_provisioner(p: Provisioner) -> pb.Provisioner:
     return out
 
 
-def encode_node(n: SimNode) -> pb.ExistingNode:
+def encode_node(n: SimNode,
+                shapes: Optional[PodShapes] = None) -> pb.ExistingNode:
     out = pb.ExistingNode(
         name=n.name, instance_type=n.instance_type, provisioner=n.provisioner,
         zone=n.zone, capacity_type=n.capacity_type, price=n.price,
@@ -130,7 +252,7 @@ def encode_node(n: SimNode) -> pb.ExistingNode:
     for k, v in n.labels.items():
         out.labels[k] = v
     out.taints.extend(pb.Taint(key=t.key, value=t.value, effect=t.effect) for t in n.taints)
-    out.pods.extend(encode_pod(p) for p in n.pods)
+    (PodShapes() if shapes is None else shapes).extend(out, "pods", n.pods)
     return out
 
 
@@ -155,7 +277,10 @@ def encode_request(
     trace_id: str = "",
     parent_span: str = "",
     session_nonce: str = "",
+    shapes: Optional[PodShapes] = None,
 ) -> pb.SolveRequest:
+    """``shapes``: the caller's table for THIS request, handed in only so
+    that its counts can be read afterwards (``RemoteScheduler.solve``)."""
     # admission fields (docs/ADMISSION.md): "" / 0 are the backward-
     # compatible wire defaults — the server folds them into its configured
     # default class / deadline, so an old client is indistinguishable from
@@ -176,11 +301,13 @@ def encode_request(
                           session_nonce=session_nonce or "")
     req.removed_pods.extend(removed_pods)
     req.reclaimed_nodes.extend(reclaimed_nodes)
-    req.pods.extend(encode_pod(p) for p in pods)
+    if shapes is None:
+        shapes = PodShapes()
+    shapes.extend(req, "pods", pods)
     req.provisioners.extend(encode_provisioner(p) for p in provisioners)
     req.instance_types.extend(encode_instance_type(t) for t in instance_types)
-    req.existing_nodes.extend(encode_node(n) for n in existing_nodes)
-    req.daemonsets.extend(encode_pod(p) for p in daemonsets)
+    req.existing_nodes.extend(encode_node(n, shapes) for n in existing_nodes)
+    shapes.extend(req, "daemonsets", daemonsets)
     for (t, z, c) in sorted(unavailable or ()):
         req.unavailable.append(pb.UnavailableOffering(instance_type=t, zone=z, capacity_type=c))
     if max_new_nodes is not None:
@@ -197,10 +324,11 @@ def encode_warm_request(
     backend: str = "",
 ) -> pb.WarmRequest:
     req = pb.WarmRequest(backend=backend)
+    shapes = PodShapes()
     req.provisioners.extend(encode_provisioner(p) for p in provisioners)
     req.instance_types.extend(encode_instance_type(t) for t in instance_types)
-    req.daemonsets.extend(encode_pod(p) for p in daemonsets)
-    req.existing_nodes.extend(encode_node(n) for n in existing_nodes)
+    shapes.extend(req, "daemonsets", daemonsets)
+    req.existing_nodes.extend(encode_node(n, shapes) for n in existing_nodes)
     return req
 
 
@@ -553,7 +681,14 @@ def decode_warm_request(req: pb.WarmRequest):
     )
 
 
-def decode_response(resp: pb.SolveResponse) -> SolveResult:
+def decode_response(resp: pb.SolveResponse,
+                    pods: Optional[Sequence[PodSpec]] = None) -> SolveResult:
+    """``pods``: the pods of the request this answers.  A node's ``pods``
+    are then the caller's own objects, looked up by name (of two pods with
+    one name, the later); a name the caller did not send, and every name
+    without ``pods``, gets a name-stub ``PodSpec`` — the wire carries
+    names only."""
+    seat = {p.name: p for p in pods or ()}.get
     nodes = []
     for n in resp.nodes:
         node = SimNode(
@@ -561,7 +696,7 @@ def decode_response(resp: pb.SolveResponse) -> SolveResult:
             capacity_type=n.capacity_type, price=n.price, allocatable={},
             name=n.name,
         )
-        node.pods = [PodSpec(name=pn) for pn in n.pod_names]
+        node.pods = [seat(pn) or PodSpec(name=pn) for pn in n.pod_names]
         nodes.append(node)
     return SolveResult(
         nodes=nodes,

@@ -13,7 +13,9 @@ request (outside the request's wall, inside the window: this is a probe, not
 a measurement of ``solve_ms``), which gives per request the door spans, the
 root and the collector's pauses on both sides — the position pattern of a
 pass, split by side — and how many of its pods the sidecar stamped from a
-template (``request_decode.hit_share``).
+template (``request_decode.hit_share``) and the client wrote from one
+(``encode.hit_share``, off the client's own registry: a client without the
+family reads nothing).
 
 Prints one JSON object (also written to
 ``chiprun_out/trace_probe.<cell>.<platform>.json``): the run's metrics as the benchmark read them, the sidecar's spans per request
@@ -36,11 +38,18 @@ M_COUNT = "karpenter_trace_span_duration_seconds_count"
 M_SELF = "karpenter_trace_span_self_seconds_total"
 M_GC = "karpenter_process_gc_pause_seconds_total"
 M_DECODED = "karpenter_solver_request_decode_pods_total"
+M_ENCODED = "karpenter_solver_request_encode_pods_total"
 
 
 def by_label(samples: list, name: str, label: str) -> dict:
     return {lab[label]: v for n, lab, v in samples
             if n == name and label in lab}
+
+
+def hit_share(by_how: dict):
+    """templated / all, or None where nothing was counted."""
+    total = sum(by_how.values())
+    return by_how.get("templated", 0.0) / total if total else None
 
 
 def main(argv=None) -> int:
@@ -77,20 +86,31 @@ def main(argv=None) -> int:
 
     def tamper(remote):
         inner = remote.solve
+        encoded = remote.registry.counter(M_ENCODED)
+
+        def client_encoded() -> dict:
+            return {how: encoded.get({"how": how})
+                    for how in ("templated", "plain")}
 
         def solve(pods, provisioners, catalog, **kw):
             if "before" not in state:
                 state["before"] = server_now()
-            gc0 = client_gc()
+            gc0, enc0 = client_gc(), client_encoded()
             with ctracer.start("provision", n_pods=len(pods)) as trace:
                 res = inner(pods, provisioners, catalog, trace=trace, **kw)
-            gc1, after = client_gc(), server_now()
+            gc1, enc1, after = client_gc(), client_encoded(), server_now()
             before, state["before"] = state["before"], after
             spans = {n: d for n, d, _s in trace.closed_spans()}
+            attrs = {sp.name: sp.attrs for sp in trace.spans()}
             rows.append({
                 "wall_ms": trace.duration_s * 1000.0,
                 "client_ms": {k: spans.get(k, 0.0) * 1000.0 for k in
                               ("remote", "encode", "rpc", "decode")},
+                "encode": {
+                    "duration_ms": spans.get("encode", 0.0) * 1000.0,
+                    "shapes": attrs.get("encode", {}).get("shapes"),
+                    "hit_share": hit_share(
+                        {how: enc1[how] - enc0[how] for how in enc1})},
                 "client_gc_ms": {g: (gc1[g] - gc0[g]) * 1000.0 for g in gc1},
                 "server_ms": {
                     k: (after["sum"].get(k, 0.0)
@@ -157,6 +177,10 @@ def main(argv=None) -> int:
     def mean(key, sub):
         return sum(r[key][sub] for r in timed) / n
 
+    def encode_mean(sub):
+        got = [r["encode"][sub] for r in timed if r["encode"][sub] is not None]
+        return sum(got) / len(got) if got else None
+
     out = {
         "workload": args.workload, "seed": args.seed, "requests": n,
         "correct": line["correct"], "device": line["device"],
@@ -167,9 +191,13 @@ def main(argv=None) -> int:
         "request_decode": {
             "duration_ms": spans.get("request_decode", {}).get("duration_ms"),
             "pods_per_request": decoded,
-            "hit_share": (decoded.get("templated", 0.0)
-                          / sum(decoded.values()) if any(decoded.values())
-                          else None)},
+            "hit_share": hit_share(decoded)},
+        # beside the client's encode span: the shapes its table held and the
+        # share of pods it wrote from one, means over the window's requests
+        "encode": {
+            "duration_ms": mean("client_ms", "encode"),
+            "shapes": encode_mean("shapes"),
+            "hit_share": encode_mean("hit_share")},
         "client_spans_ms": {k: mean("client_ms", k) for k in
                             ("remote", "encode", "rpc", "decode")},
         "client_gc_ms": {g: mean("client_gc_ms", g) for g in "012"},

@@ -140,6 +140,7 @@ def test_inventory_metrics_are_emitted(small_catalog):
         REMOTE_DEGRADED,
         REMOTE_FALLBACK_SOLVES,
         REQUEST_DECODE_PODS,
+        REQUEST_ENCODE_PODS,
     )
 
     # likewise the admission family: emitted by the solver SERVICE's
@@ -211,12 +212,14 @@ def test_inventory_metrics_are_emitted(small_catalog):
     # the door's pod counter (ISSUE 26) is service-side as well: zero-
     # inited where SolverService is constructed and moved by every Solve
     # RPC, both asserted by tests/test_codec_templates.py (the ``served``
-    # fixture and test_the_door_counts_what_it_stamped)
+    # fixture and test_the_door_counts_what_it_stamped); its client-side
+    # mirror (ISSUE 30) belongs to RemoteScheduler like the remote-solver
+    # pair (tests/test_codec_client_templates.py)
     missing = (set(INVENTORY) - emitted - admission_family - delta_family
                - resilience_family - fleet_family - multihost_shim
                - replay_family - slo_family - tuning_family
                - {REMOTE_DEGRADED, REMOTE_FALLBACK_SOLVES,
-                  REQUEST_DECODE_PODS})
+                  REQUEST_DECODE_PODS, REQUEST_ENCODE_PODS})
     assert not missing, (
         f"documented metrics never emitted: {sorted(missing)} "
         f"(warm debug: in_flight={auto_sched._tpu.compiles_in_flight()} "
