@@ -192,6 +192,18 @@ REQUEST_ENCODE_PODS = "karpenter_solver_request_encode_pods_total"
 #: 'plain' (built field by field: the first pod of each shape, a pod whose
 #: values give no shape key, and every pod after the table gave up)
 REQUEST_ENCODE_HOW = ("templated", "plain")
+# ---- the catalog by name (service/server.py SolverService.Solve) ---------
+REQUEST_CATALOG = "karpenter_solver_request_catalog_total"
+#: where a Solve's instance types came from (KT003 zero-init source):
+#: 'held' (the request named a list this sidecar kept), 'decoded' (built
+#: from the request's own instance_types) and 'unknown' (the request named
+#: a list this sidecar does not hold, and was refused)
+REQUEST_CATALOG_HOW = ("held", "decoded", "unknown")
+REQUEST_CATALOG_SENT = "karpenter_solver_request_catalog_sent_total"
+#: how a RemoteScheduler put the catalog on a Solve (KT003 zero-init
+#: source): 'digest' (by the sidecar's name for it), 'full' (the list) and
+#: 'resent' (the list again, after the sidecar did not know the name)
+REQUEST_CATALOG_SENT_HOW = ("digest", "full", "resent")
 # ---- the device scan's axes (solver/tpu.py TpuSolver._count_scan) --------
 SCAN_AXIS = "karpenter_solver_scan_axis_total"
 #: what a device scan ran at (KT003 zero-init source): 'groups' (serial
@@ -599,6 +611,32 @@ INVENTORY = {
         "karpenter_solver_request_decode_pods_total at the sidecar's door: "
         "a healthy provisioning batch reads almost all 'templated' on both "
         "sides.  No table outlives its request."),
+    REQUEST_CATALOG: (
+        "counter", ("how",),
+        "Solve RPCs by where their instance types came from, once per "
+        "request: 'held' — the request carried no instance_types and a "
+        "catalog_digest this sidecar handed out earlier, so it was solved "
+        "on the list the sidecar kept (the same InstanceType objects as "
+        "last time: nothing parsed, decoded or re-signed); 'decoded' — "
+        "built from the request's own instance_types (a sessionless one "
+        "is then digested, kept — the last 4 distinct lists — and named "
+        "in SolveResponse.catalog_digest); 'unknown' — the digest names "
+        "no list this sidecar holds (a restart, an eviction, another "
+        "replica): FAILED_PRECONDITION 'CATALOG_UNKNOWN', which costs the "
+        "client one resend.  A steady client reads 'held' on every "
+        "request after its first."),
+    REQUEST_CATALOG_SENT: (
+        "counter", ("how",),
+        "Solve requests a RemoteScheduler sent, on the CLIENT's registry, "
+        "by how the catalog went: 'digest' — instance_types is element "
+        "for element the same objects as the last list this sidecar "
+        "acknowledged, so its digest went in their place; 'full' — the "
+        "list itself (the first request, a refreshed catalog, a sidecar "
+        "that acknowledges nothing); 'resent' — the list again, inside "
+        "the same solve, after the sidecar answered CATALOG_UNKNOWN (or "
+        "answered without naming a catalog at all: a sidecar rolled back "
+        "under the client).  The mirror of "
+        "karpenter_solver_request_catalog_total at the sidecar's door."),
     SCAN_AXIS: (
         "counter", ("axis",),
         "What the device scans ran at, summed over device solves (one "

@@ -17,7 +17,7 @@ import logging
 import os
 import threading
 import time
-from typing import Dict, List, Optional, Sequence, Set
+from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import grpc
 
@@ -30,6 +30,8 @@ from ..metrics import (
     FLEET_ENDPOINTS,
     FLEET_FAILOVER_REASONS,
     FLEET_FAILOVERS,
+    REQUEST_CATALOG_SENT,
+    REQUEST_CATALOG_SENT_HOW,
     REQUEST_ENCODE_HOW,
     REQUEST_ENCODE_PODS,
     Registry,
@@ -631,6 +633,12 @@ class RemoteScheduler:
         for how in REQUEST_ENCODE_HOW:
             self.registry.counter(REQUEST_ENCODE_PODS).inc(
                 {"how": how}, value=0)
+        #: the last catalog sent in full that the sidecar acknowledged:
+        #: ``(its InstanceType objects, the sidecar's digest of them)``
+        self._catalog_acked: Optional[Tuple[tuple, str]] = None
+        for how in REQUEST_CATALOG_SENT_HOW:
+            self.registry.counter(REQUEST_CATALOG_SENT).inc(
+                {"how": how}, value=0)
         faults_mod.zero_init_recovery(self.registry)
 
     #: RPC status codes that mean "the sidecar is not reachable right now".
@@ -687,6 +695,53 @@ class RemoteScheduler:
             self.registry.gauge(REMOTE_DEGRADED).set(0)
         return ok
 
+    # ---- the catalog by name ---------------------------------------------
+    def _acked_digest(self, instance_types: Sequence[InstanceType]) -> str:
+        """The sidecar's digest for ``instance_types`` if they are, element
+        for element, the objects of the last list it acknowledged (a new
+        list of the same objects is), else ""."""
+        acked = self._catalog_acked
+        if (acked is not None and len(acked[0]) == len(instance_types)
+                and all(a is b for a, b in zip(acked[0], instance_types))):
+            return acked[1]
+        return ""
+
+    def _solve_rpc(self, req: pb.SolveRequest,
+                   instance_types: Sequence[InstanceType],
+                   timeout: Optional[float]) -> pb.SolveResponse:
+        """One Solve; a request that named its catalog and met a sidecar
+        that does not hold it goes once more with the list — nothing else
+        of it is encoded again.  A reply that names no catalog at all is
+        such a sidecar too: one rolled back under this client, which took
+        the request for one with no instance types."""
+        named = req.catalog_digest
+        resp = None
+        try:
+            resp = self.client.solve_raw(req, timeout=timeout)
+        except grpc.RpcError as err:
+            code = err.code() if callable(getattr(err, "code", None)) else None
+            detail = getattr(err, "details", lambda: "")() or ""
+            if not (named and code == grpc.StatusCode.FAILED_PRECONDITION
+                    and detail.startswith("CATALOG_UNKNOWN")):
+                raise
+        if named:
+            if resp is not None and resp.catalog_digest:
+                return resp  # solved on the list the sidecar kept
+            self._catalog_acked = None
+            req.catalog_digest = ""
+            req.instance_types.extend(
+                codec.encode_instance_type(t) for t in instance_types)
+            self.registry.counter(REQUEST_CATALOG_SENT).inc({"how": "resent"})
+            # a restart ridden through by one more send, in the funnel of
+            # every other (KT016)
+            faults_mod.count_recovery(self.registry, "transport", "retried")
+            resp = self.client.solve_raw(req, timeout=timeout)
+        # sent in full: what the sidecar calls this list from now on (""
+        # from one that keeps none: the next request goes in full again)
+        acked = getattr(resp, "catalog_digest", "")
+        self._catalog_acked = (tuple(instance_types), acked) if acked else None
+        return resp
+
     # ---- BatchScheduler surface -------------------------------------------
     def solve(
         self,
@@ -702,6 +757,14 @@ class RemoteScheduler:
         trace=None,
         relax: Optional[bool] = None,
     ) -> SolveResult:
+        """``instance_types`` cross the wire once: a request whose list is,
+        element for element, the objects of the last list this sidecar
+        acknowledged carries the sidecar's digest in their place, and the
+        sidecar solves on the list it kept.  That rests on the contract the
+        package already keeps (``_instance_type_sig``, models/tensorize.py):
+        an ``InstanceType`` is not mutated after construction — a changed
+        catalog is new objects.  What changes between refreshes, ICE'd
+        offerings, travels in ``unavailable``."""
         # ``relax`` mirrors BatchScheduler.solve for facade parity; the
         # rung is a server-side refinement governed by the sidecar's own
         # KT_RELAX policy (the wire carries no per-request override), so
@@ -727,6 +790,7 @@ class RemoteScheduler:
                 with trace.span("encode", n_pods=len(pods)) as encode:
                     # one table of pod shapes per request, dropped with it
                     shapes = codec.PodShapes()
+                    digest = self._acked_digest(instance_types)
                     req = codec.encode_request(
                         pods, provisioners, instance_types,
                         existing_nodes=existing_nodes, daemonsets=daemonsets,
@@ -737,21 +801,26 @@ class RemoteScheduler:
                         deadline_ms=(self.deadline_s * 1000.0
                                      if self.deadline_s else None),
                         trace_id=wire_tid, parent_span=wire_parent,
-                        shapes=shapes,
+                        shapes=shapes, catalog_digest=digest,
                     )
                     encoded = self.registry.counter(REQUEST_ENCODE_PODS)
                     encoded.inc({"how": "templated"},
                                 value=shapes.templated_pods)
                     encoded.inc({"how": "plain"}, value=shapes.plain_pods)
+                    catalog_how = "digest" if digest else "full"
+                    self.registry.counter(REQUEST_CATALOG_SENT).inc(
+                        {"how": catalog_how})
                     encode.annotate(shapes=shapes.shapes,
-                                    templated_pods=shapes.templated_pods)
+                                    templated_pods=shapes.templated_pods,
+                                    catalog=catalog_how)
                 # the wire deadline budget also bounds the RPC itself: a
                 # caller with 250ms left must not block 60s on the channel
                 rpc_timeout = (min(self.client.timeout, self.deadline_s)
                                if self.deadline_s else None)
                 try:
                     with trace.span("rpc"):
-                        resp = self.client.solve_raw(req, timeout=rpc_timeout)
+                        resp = self._solve_rpc(req, instance_types,
+                                               rpc_timeout)
                 except grpc.RpcError as err:
                     code = (err.code()
                             if callable(getattr(err, "code", None)) else None)

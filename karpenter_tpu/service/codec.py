@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import hashlib
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from ..models.instancetype import InstanceType, Offering, Overhead
@@ -278,9 +279,12 @@ def encode_request(
     parent_span: str = "",
     session_nonce: str = "",
     shapes: Optional[PodShapes] = None,
+    catalog_digest: str = "",
 ) -> pb.SolveRequest:
     """``shapes``: the caller's table for THIS request, handed in only so
-    that its counts can be read afterwards (``RemoteScheduler.solve``)."""
+    that its counts can be read afterwards (``RemoteScheduler.solve``).
+    ``catalog_digest``: the sidecar's own name for ``instance_types``
+    (``SolveResponse.catalog_digest``); it goes in the list's place."""
     # admission fields (docs/ADMISSION.md): "" / 0 are the backward-
     # compatible wire defaults — the server folds them into its configured
     # default class / deadline, so an old client is indistinguishable from
@@ -298,14 +302,17 @@ def encode_request(
                           catalog_epoch=int(catalog_epoch or 0),
                           trace_id=trace_id or "",
                           parent_span=parent_span or "",
-                          session_nonce=session_nonce or "")
+                          session_nonce=session_nonce or "",
+                          catalog_digest=catalog_digest or "")
     req.removed_pods.extend(removed_pods)
     req.reclaimed_nodes.extend(reclaimed_nodes)
     if shapes is None:
         shapes = PodShapes()
     shapes.extend(req, "pods", pods)
     req.provisioners.extend(encode_provisioner(p) for p in provisioners)
-    req.instance_types.extend(encode_instance_type(t) for t in instance_types)
+    if not catalog_digest:
+        req.instance_types.extend(
+            encode_instance_type(t) for t in instance_types)
     req.existing_nodes.extend(encode_node(n, shapes) for n in existing_nodes)
     shapes.extend(req, "daemonsets", daemonsets)
     for (t, z, c) in sorted(unavailable or ()):
@@ -560,16 +567,33 @@ def decode_node(n: pb.ExistingNode,
     )
 
 
+def catalog_digest(instance_types: Sequence[pb.InstanceType]) -> str:
+    """The sidecar's name for a list of wire instance types: equal lists
+    (same types, same order) get equal names.  Only the sidecar computes
+    one, so no two parties can disagree on what a digest means."""
+    h = hashlib.blake2b(digest_size=16)
+    for t in instance_types:
+        data = t.SerializeToString(deterministic=True)
+        h.update(len(data).to_bytes(4, "big"))
+        h.update(data)
+    return h.hexdigest()
+
+
 def decode_request(req: pb.SolveRequest,
-                   templates: Optional[PodTemplates] = None):
+                   templates: Optional[PodTemplates] = None,
+                   catalog: Optional[List[InstanceType]] = None):
     """``templates``: the caller's table for THIS request, handed in only
-    so that its counts can be read afterwards (``SolverService.Solve``)."""
+    so that its counts can be read afterwards (``SolverService.Solve``).
+    ``catalog``: the list the sidecar kept under the request's
+    ``catalog_digest``; it stands in for the ``instance_types`` the
+    request left out."""
     if templates is None:
         templates = PodTemplates()
     return dict(
         pods=templates.decode(req.pods),
         provisioners=[decode_provisioner(p) for p in req.provisioners],
-        instance_types=[decode_instance_type(t) for t in req.instance_types],
+        instance_types=(catalog if catalog is not None else
+                        [decode_instance_type(t) for t in req.instance_types]),
         existing_nodes=[decode_node(n, templates) for n in req.existing_nodes],
         daemonsets=templates.decode(req.daemonsets),
         unavailable={(u.instance_type, u.zone, u.capacity_type) for u in req.unavailable},

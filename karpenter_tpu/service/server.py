@@ -18,9 +18,10 @@ import sys
 import threading
 import time
 import uuid
+from collections import OrderedDict
 from concurrent import futures
 from concurrent.futures import Future
-from typing import Optional
+from typing import Optional, Sequence, Tuple
 
 import grpc
 
@@ -49,6 +50,8 @@ from ..metrics import (
     OCCUPANCY_DELTA_INLINE,
     OCCUPANCY_DEVICE_BUSY,
     OCCUPANCY_SLOT_FILL,
+    REQUEST_CATALOG,
+    REQUEST_CATALOG_HOW,
     REQUEST_DECODE_HOW,
     REQUEST_DECODE_PODS,
     Registry,
@@ -1369,6 +1372,46 @@ class SolvePipeline:
             self._drain(self._inflight.pop_to(0))
 
 
+#: instance-type lists a sidecar keeps by its own digest: room for the
+#: operator's catalog, a refresh of it and a second caller's, no more
+CATALOGS_KEPT = 4
+
+
+class CatalogUnknown(LookupError):
+    """A Solve named a catalog this sidecar does not hold (direct callers;
+    over gRPC it is FAILED_PRECONDITION with the same text)."""
+
+
+class KeptCatalogs:
+    """``digest -> the InstanceTypes decoded under it``: the
+    ``CATALOGS_KEPT`` lists used last.  What it hands out are the SAME
+    objects every time, which is the point: ``_instance_type_sig``'s
+    identity memo hits on them (models/tensorize.py)."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._by_digest: "OrderedDict[str, Tuple]" = OrderedDict()
+
+    def get(self, digest: str) -> Optional[Tuple]:
+        with self._lock:
+            held = self._by_digest.get(digest)
+            if held is not None:
+                self._by_digest.move_to_end(digest)
+            return held
+
+    def keep(self, digest: str, instance_types: Sequence) -> None:
+        """A digest already held keeps its first objects: requests that
+        name it go on solving on the objects they solved on before."""
+        with self._lock:
+            self._by_digest.setdefault(digest, tuple(instance_types))
+            self._by_digest.move_to_end(digest)
+            while len(self._by_digest) > CATALOGS_KEPT:
+                self._by_digest.popitem(last=False)
+
+    def __len__(self) -> int:
+        return len(self._by_digest)
+
+
 class SolverService:
     def __init__(self, scheduler: Optional[BatchScheduler] = None,
                  registry: Optional[Registry] = None,
@@ -1392,6 +1435,10 @@ class SolverService:
         for how in REQUEST_DECODE_HOW:
             self.registry.counter(REQUEST_DECODE_PODS).inc(
                 {"how": how}, value=0)
+        #: the catalogs of sessionless Solves, by this sidecar's digest
+        self.catalogs = KeptCatalogs()
+        for how in REQUEST_CATALOG_HOW:
+            self.registry.counter(REQUEST_CATALOG).inc({"how": how}, value=0)
         self._schedulers = {"": self.scheduler}  # guarded-by: _direct_lock
         # KT_SOLVE_PIPELINE=0 falls back to direct, lock-serialized solves
         self._pipelined = os.environ.get("KT_SOLVE_PIPELINE", "1") != "0"
@@ -1597,9 +1644,37 @@ class SolverService:
         # context, which is IN the request), so it is timed as a phase and
         # recorded into the tree once the root exists
         with self.tracer.phase("request_decode") as door:
+            # the catalog by name, before anything of the request is
+            # decoded: a sessionless request that left its instance types
+            # out and names a list this sidecar kept is solved on that list
+            sessionless = not getattr(request, "session_id", "")
+            digest = getattr(request, "catalog_digest", "")
+            catalogs = self.registry.counter(REQUEST_CATALOG)
+            held = None
+            if digest and sessionless and not request.instance_types:
+                held = self.catalogs.get(digest)
+                if held is None:
+                    # a restart, an eviction, another replica: typed, and
+                    # the client sends the list once more
+                    catalogs.inc({"how": "unknown"})
+                    msg = (f"CATALOG_UNKNOWN: no instance types held under "
+                           f"catalog_digest {digest!r}; send them in full")
+                    if context is None:
+                        raise CatalogUnknown(msg)
+                    context.abort(grpc.StatusCode.FAILED_PRECONDITION, msg)
             # one table of pod shapes per request, dropped with it
             shapes = codec.PodTemplates()
-            kwargs = codec.decode_request(request, shapes)
+            kwargs = codec.decode_request(
+                request, shapes, None if held is None else list(held))
+            if held is None:
+                # a list on the request wins over a digest beside it; this
+                # sidecar names what it decoded and keeps it under the name
+                digest = ""
+                if sessionless and request.instance_types:
+                    digest = codec.catalog_digest(request.instance_types)
+                    self.catalogs.keep(digest, kwargs["instance_types"])
+            catalog_how = "decoded" if held is None else "held"
+            catalogs.inc({"how": catalog_how})
             decoded = self.registry.counter(REQUEST_DECODE_PODS)
             decoded.inc({"how": "templated"}, value=shapes.templated_pods)
             decoded.inc({"how": "plain"}, value=shapes.plain_pods)
@@ -1659,7 +1734,8 @@ class SolverService:
                 trace.record("request_decode", door.t0, door.t1,
                              n_pods=len(kwargs.get("pods", ())),
                              templates=shapes.templates,
-                             templated_pods=shapes.templated_pods)
+                             templated_pods=shapes.templated_pods,
+                             catalog=catalog_how)
                 kwargs["trace"] = trace
                 if self._pipelined:
                     pipe = self._pipeline_for(sched)
@@ -1697,6 +1773,9 @@ class SolverService:
                         resp = codec.encode_delta_reply(result)
                     else:
                         resp = codec.encode_response(result)
+                        # this sidecar's name for the catalog it solved on:
+                        # the client may send the name alone next time
+                        resp.catalog_digest = digest
                     # which replica served: failover-aware clients stamp
                     # this on their "remote" span, and offline dump
                     # correlation keys on it

@@ -57,6 +57,11 @@ NEW_FIELDS = {
         # either side is the legacy wildcard — mixed-version fleets
         # simply keep today's epoch-only check.
         (21, "session_nonce", F.TYPE_STRING, F.LABEL_OPTIONAL),
+        # the catalog by name (ISSUE 33): the sidecar's digest of an
+        # instance-type list it already holds, in place of the list.  ""
+        # (old clients) decodes in full; an old sidecar never hands a
+        # digest out (SolveResponse 11), so it is never sent one.
+        (22, "catalog_digest", F.TYPE_STRING, F.LABEL_OPTIONAL),
     ],
     # session ack + delta-shaped responses: `assignments`/`nodes` carry only
     # the step's changes when `delta_mode` is an incremental tier;
@@ -72,6 +77,9 @@ NEW_FIELDS = {
         (9, "replica_id", F.TYPE_STRING, F.LABEL_OPTIONAL),
         # chain-identity nonce echo (ISSUE 17, see SolveRequest 21)
         (10, "session_nonce", F.TYPE_STRING, F.LABEL_OPTIONAL),
+        # the sidecar's name for the catalog it solved on (ISSUE 33, see
+        # SolveRequest 22)
+        (11, "catalog_digest", F.TYPE_STRING, F.LABEL_OPTIONAL),
     ],
     # gang scheduling (ISSUE 20, docs/GANGS.md): members of one gang share
     # a gang_id and declare the gang's total size.  Old bytes decode to

@@ -15,7 +15,10 @@ root and the collector's pauses on both sides — the position pattern of a
 pass, split by side — and how many of its pods the sidecar stamped from a
 template (``request_decode.hit_share``) and the client wrote from one
 (``encode.hit_share``, off the client's own registry: a client without the
-family reads nothing).
+family reads nothing), and beside it how the client put the catalog on the
+wire: the ``encode`` span's ``catalog`` attribute and the client's
+``karpenter_solver_request_catalog_sent_total`` (``encode.catalog``,
+``encode.catalog_sent``).
 
 Prints one JSON object (also written to
 ``chiprun_out/trace_probe.<cell>.<platform>.json``): the run's metrics as the benchmark read them, the sidecar's spans per request
@@ -26,6 +29,7 @@ rows.  Needs a TPU exactly as the benchmark does.
 from __future__ import annotations
 
 import argparse
+import collections
 import json
 import os
 import sys
@@ -39,6 +43,8 @@ M_SELF = "karpenter_trace_span_self_seconds_total"
 M_GC = "karpenter_process_gc_pause_seconds_total"
 M_DECODED = "karpenter_solver_request_decode_pods_total"
 M_ENCODED = "karpenter_solver_request_encode_pods_total"
+M_CATALOG_SENT = "karpenter_solver_request_catalog_sent_total"
+CATALOG_SENT_HOW = ("digest", "full", "resent")
 
 
 def by_label(samples: list, name: str, label: str) -> dict:
@@ -87,18 +93,24 @@ def main(argv=None) -> int:
     def tamper(remote):
         inner = remote.solve
         encoded = remote.registry.counter(M_ENCODED)
+        catalog_sent = remote.registry.counter(M_CATALOG_SENT)
 
         def client_encoded() -> dict:
             return {how: encoded.get({"how": how})
                     for how in ("templated", "plain")}
 
+        def client_catalog() -> dict:
+            return {how: catalog_sent.get({"how": how})
+                    for how in CATALOG_SENT_HOW}
+
         def solve(pods, provisioners, catalog, **kw):
             if "before" not in state:
                 state["before"] = server_now()
-            gc0, enc0 = client_gc(), client_encoded()
+            gc0, enc0, cat0 = client_gc(), client_encoded(), client_catalog()
             with ctracer.start("provision", n_pods=len(pods)) as trace:
                 res = inner(pods, provisioners, catalog, trace=trace, **kw)
             gc1, enc1, after = client_gc(), client_encoded(), server_now()
+            cat1 = client_catalog()
             before, state["before"] = state["before"], after
             spans = {n: d for n, d, _s in trace.closed_spans()}
             attrs = {sp.name: sp.attrs for sp in trace.spans()}
@@ -110,7 +122,13 @@ def main(argv=None) -> int:
                     "duration_ms": spans.get("encode", 0.0) * 1000.0,
                     "shapes": attrs.get("encode", {}).get("shapes"),
                     "hit_share": hit_share(
-                        {how: enc1[how] - enc0[how] for how in enc1})},
+                        {how: enc1[how] - enc0[how] for how in enc1}),
+                    # how the catalog went: the span's word for it, and
+                    # the client's counter (a client without either reads
+                    # nothing / zeros)
+                    "catalog": attrs.get("encode", {}).get("catalog"),
+                    "catalog_sent": {how: cat1[how] - cat0[how]
+                                     for how in cat1}},
                 "client_gc_ms": {g: (gc1[g] - gc0[g]) * 1000.0 for g in gc1},
                 "server_ms": {
                     k: (after["sum"].get(k, 0.0)
@@ -197,7 +215,14 @@ def main(argv=None) -> int:
         "encode": {
             "duration_ms": mean("client_ms", "encode"),
             "shapes": encode_mean("shapes"),
-            "hit_share": encode_mean("hit_share")},
+            "hit_share": encode_mean("hit_share"),
+            # the window's requests by the span's ``catalog`` attribute,
+            # and the client's counter over the same requests
+            "catalog": dict(collections.Counter(
+                str(r["encode"]["catalog"]) for r in timed)),
+            "catalog_sent": {how: sum(r["encode"]["catalog_sent"][how]
+                                      for r in timed)
+                             for how in CATALOG_SENT_HOW}},
         "client_spans_ms": {k: mean("client_ms", k) for k in
                             ("remote", "encode", "rpc", "decode")},
         "client_gc_ms": {g: mean("client_gc_ms", g) for g in "012"},

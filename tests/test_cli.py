@@ -139,6 +139,8 @@ def test_inventory_metrics_are_emitted(small_catalog):
     from karpenter_tpu.metrics import (
         REMOTE_DEGRADED,
         REMOTE_FALLBACK_SOLVES,
+        REQUEST_CATALOG,
+        REQUEST_CATALOG_SENT,
         REQUEST_DECODE_PODS,
         REQUEST_ENCODE_PODS,
     )
@@ -214,12 +216,14 @@ def test_inventory_metrics_are_emitted(small_catalog):
     # RPC, both asserted by tests/test_codec_templates.py (the ``served``
     # fixture and test_the_door_counts_what_it_stamped); its client-side
     # mirror (ISSUE 30) belongs to RemoteScheduler like the remote-solver
-    # pair (tests/test_codec_client_templates.py)
+    # pair (tests/test_codec_client_templates.py); the catalog's pair
+    # (ISSUE 33) sits at the same two places: tests/test_catalog_digest.py
     missing = (set(INVENTORY) - emitted - admission_family - delta_family
                - resilience_family - fleet_family - multihost_shim
                - replay_family - slo_family - tuning_family
                - {REMOTE_DEGRADED, REMOTE_FALLBACK_SOLVES,
-                  REQUEST_DECODE_PODS, REQUEST_ENCODE_PODS})
+                  REQUEST_DECODE_PODS, REQUEST_ENCODE_PODS,
+                  REQUEST_CATALOG, REQUEST_CATALOG_SENT})
     assert not missing, (
         f"documented metrics never emitted: {sorted(missing)} "
         f"(warm debug: in_flight={auto_sched._tpu.compiles_in_flight()} "

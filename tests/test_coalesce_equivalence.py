@@ -287,7 +287,11 @@ def test_each_new_metric_is_declared_as_its_file_says(name):
         bench = json.load(f)
     with open(os.path.join(BENCH, "metrics", f"{name}.json")) as f:
         spec = json.load(f)
-    decl = bench["per_layer"][-2:][NEW_METRICS.index(name)]  # appended
+    names = [m["name"] for m in bench["per_layer"]]
+    # appended after what PR 27 left, in this order (later PRs append on)
+    assert names.index(name) == names.index(
+        "scan_slot_retries") + 1 + NEW_METRICS.index(name)
+    decl = bench["per_layer"][names.index(name)]
     assert decl["name"] == spec["name"] == name
     assert "workloads" not in decl  # every cell reports it
     for key in ("unit", "better", "source", "layer", "moves"):

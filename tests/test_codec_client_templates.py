@@ -537,7 +537,8 @@ def test_a_solve_over_grpc_returns_what_the_parent_returned(
         "templated": templated, "plain": len(pods) - templated}
     spans = {sp.name: sp for sp in trace.spans()}
     assert spans["encode"].attrs == {
-        "n_pods": len(pods), "shapes": n_shapes, "templated_pods": templated}
+        "n_pods": len(pods), "shapes": n_shapes, "templated_pods": templated,
+        "catalog": "full"}
     assert {"remote", "encode", "rpc", "decode"} <= set(spans)
     text = creg.expose()
     assert f'{REQUEST_ENCODE_PODS}{{how="templated"}}' in text

@@ -504,7 +504,7 @@ def test_the_door_counts_what_it_stamped(served, small_catalog, pods,
     tree = served["flight"].traces()[-1].to_dict()
     door = {c["name"]: c for c in tree["spans"]}["request_decode"]["attrs"]
     assert door == {"n_pods": len(pods), "templates": templates,
-                    "templated_pods": templated}
+                    "templated_pods": templated, "catalog": "decoded"}
     text = served["reg"].expose()
     assert f'{REQUEST_DECODE_PODS}{{how="templated"}}' in text
     assert f'{REQUEST_DECODE_PODS}{{how="plain"}}' in text
