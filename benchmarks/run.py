@@ -9,7 +9,10 @@ imported, it never touches the chip.  It starts one child, the solver sidecar
 from ``--seed`` (``gen.py``); warms the cell's own shapes with untimed
 requests; drives a closed loop of one client with zero think time for
 ``--seconds`` seconds and on to the end of the pass it is in (every window
-holds whole passes of the cell's pool, so every run does the same work);
+holds whole passes of the cell's pool or deck, every pass the same work;
+``solve_ms`` and ``pods_per_s`` are taken over the whole window, and every
+pass's wall, the median pass and the stalled passes are in the run's ``info``
+lines: ``whole_passes``);
 reads ``/metrics`` deltas, the device and — traced — the profiler trace from
 the sidecar; stops the sidecar; compares every answer the client decoded with
 the plain reference (``plainref.py``); checks its own last line against the
@@ -33,6 +36,7 @@ import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import signal  # noqa: E402
+import statistics  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -45,6 +49,8 @@ SIDECAR_READY_S = 300.0
 SIDECAR_EXIT_S = 120.0
 #: a traced run's window closes at the first pass boundary after this long
 TRACE_MAX_S = 12.0
+#: a pass this many times its window's median pass is reported as stalled
+STALL_OVER = 1.25
 
 
 class RunFailed(Exception):
@@ -181,6 +187,54 @@ def p95(xs: list) -> float:
 
 
 # ---------------------------------------------------------------------------
+# the window: whole passes
+# ---------------------------------------------------------------------------
+
+
+def drive(kind, limit: float, clock=time.perf_counter) -> dict:
+    """The closed loop: requests of ``kind`` one after another until the
+    first pass boundary (``kind.whole()``) at or after ``limit`` seconds.
+    ``walls`` holds every request's wall, a failed one's too; ``cuts`` the
+    number of requests sent when each pass ended."""
+    walls, cuts, failed, pods_offered = [], [], 0, 0
+    t_open = clock()
+    while True:
+        t0 = clock()
+        try:
+            pods_offered += kind.request()
+        except Exception as err:  # noqa: BLE001 — a failed request counts
+            failed += 1
+            log(f"request failed: {err!r}")
+        now = clock()
+        walls.append(now - t0)
+        if kind.whole():
+            cuts.append(len(walls))
+            if now - t_open >= limit:
+                break
+    return {"walls": walls, "cuts": cuts, "failed": failed,
+            "pods_offered": pods_offered, "window_s": now - t_open}
+
+
+def whole_passes(window: dict) -> dict:
+    """What a window :func:`drive` closed reads.  ``solve_ms`` and
+    ``pods_per_s`` are taken over ALL of it: its wall over its requests, the
+    pods it offered over its wall.  Beside them, for the run's ``info``
+    lines and for no metric: the wall of every pass (the sum of its
+    requests' walls; every pass is the same work), what the MEDIAN pass
+    would read as ``solve_ms``, and how many passes stood more than
+    ``STALL_OVER`` x over that median — a stall inside one request moves
+    the mean and has to show in the run's output."""
+    walls, cuts = window["walls"], window["cuts"]
+    pass_s = [sum(walls[a:b]) for a, b in zip([0] + cuts, cuts)]
+    mid = statistics.median(pass_s)
+    return {"passes": len(pass_s), "pass_s": pass_s,
+            "solve_ms": window["window_s"] / len(walls) * 1000.0,
+            "pods_per_s": window["pods_offered"] / window["window_s"],
+            "median_solve_ms": mid * len(pass_s) / len(walls) * 1000.0,
+            "stalled_passes": sum(p > STALL_OVER * mid for p in pass_s)}
+
+
+# ---------------------------------------------------------------------------
 # the two traffic kinds
 # ---------------------------------------------------------------------------
 
@@ -266,8 +320,7 @@ class Burst:
 
     def report(self, walls: list) -> None:
         n = len(self.pool)
-        info("pool", sent=self.sent, pool=n, passes=self.sent / n,
-             pass_s=[sum(walls[k:k + n]) for k in range(0, len(walls), n)],
+        info("pool", sent=self.sent, pool=n,
              request_ms=[w * 1000.0 for w in walls[:n]])
 
 
@@ -482,21 +535,10 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
         built_before = sidecar.command("device")["jit_programs_built"]
         if trace:
             sidecar.command(f"trace_start {trace_dir}")
-        walls, failed, pods_offered = [], 0, 0
         setup_s = time.perf_counter() - T_START
-        t_open = time.perf_counter()
-        while True:
-            t0 = time.perf_counter()
-            try:
-                pods_offered += kind.request()
-            except Exception as err:  # noqa: BLE001 — a failed request counts
-                failed += 1
-                log(f"request failed: {err!r}")
-            now = time.perf_counter()
-            walls.append(now - t0)
-            if now - t_open >= limit and kind.whole():
-                break
-        window_s = now - t_open
+        window = drive(kind, limit)
+        walls, failed, window_s = (window["walls"], window["failed"],
+                                   window["window_s"])
         trace_window_s = (sidecar.command("trace_stop")["window_s"]
                           if trace else None)
         time.sleep(0.2)  # the last request's spans land when its trace ends
@@ -535,6 +577,8 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
              compared=verdict["compared"],
              first_violations=verdict["first_violations"])
         kind.report(walls)
+        passes = whole_passes(window)
+        info("window", requests=len(walls), **passes)
         info("tiers", window=S.serving_tiers(before, after),
              window_s=window_s, target=sidecar.target,
              compile_cache=hello.get("compile_cache"),
@@ -542,9 +586,9 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
 
         # ---- the metrics ----
         n = len(walls)
-        e2e = {"setup_s": setup_s, "solve_ms": window_s / n * 1000.0,
+        e2e = {"setup_s": setup_s, "solve_ms": passes["solve_ms"],
                "solve_p95_ms": p95(walls) * 1000.0,
-               "pods_per_s": pods_offered / window_s,
+               "pods_per_s": passes["pods_per_s"],
                "cost_ratio": verdict["cost_ratio"]}
         metrics = {}
         for decl in bench["end_to_end"]:

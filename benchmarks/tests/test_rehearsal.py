@@ -9,6 +9,7 @@ the router serves it from the host oracle by policy."""
 
 import json
 import os
+import statistics
 import subprocess
 import sys
 
@@ -53,11 +54,27 @@ def test_cell_runs_and_its_last_line_meets_the_contract(bench, workload,
     earlier = [json.loads(e) for e in run.EARLIER]
     tiers = [e for e in earlier if e["info"] == "tiers"][-1]
     assert tiers["window_s"] >= 2.0
+    # its passes are in the run's output, every one, beside the median pass;
+    # solve_ms and pods_per_s are the whole window's
+    window = [e for e in earlier if e["info"] == "window"][-1]
+    assert len(window["pass_s"]) == window["passes"] >= 1
+    assert window["requests"] == line["attempted"]
+    assert sum(window["pass_s"]) <= tiers["window_s"]
+    assert line["metrics"]["solve_ms"]["value"] == pytest.approx(
+        tiers["window_s"] / line["attempted"] * 1000.0)
+    assert window["median_solve_ms"] == pytest.approx(
+        statistics.median(window["pass_s"]) * window["passes"]
+        / line["attempted"] * 1000.0)
+    assert 0 <= window["stalled_passes"] < window["passes"]
     if workload.endswith(".burst"):
         pool = gen.load_traffic("burst")["pool"]
-        assert line["attempted"] % pool == 0
+        assert line["attempted"] == pool * window["passes"]
+        assert line["metrics"]["pods_per_s"]["value"] == window["pods_per_s"]
         compared = [e for e in earlier if e["info"] == "comparison"][-1]
         assert compared["compared"] == line["attempted"]
+    else:
+        deck = sum(e["copies"] for e in gen.load_traffic("reconcile")["deck"])
+        assert line["attempted"] == deck * window["passes"]
 
 
 # ---- the timed path broken underneath: correct has to come out false ----
