@@ -14,11 +14,11 @@ plain reference (``plainref.py``) never sees an object of the program.
 :class:`ProgramInputs` turns plain clusters into the program's input types
 for the client — the one place here that imports ``karpenter_tpu``.
 
-Every seed gets the same work in another order: a burst pool and the standing
-cluster of a session are fixed by the configuration; the seed salts the names,
-orders the deployments inside a request and the requests of a pass, orders
-the step deck, draws the deployment a step scales and the pods it removes (a
-seed that changes the amount of work shows up as run-to-run spread).  A
+Every seed gets the same work: a burst pool, the standing cluster of a
+session and the step stream over it are fixed by the configuration (the
+stream by the traffic file too); the seed salts the names, orders the
+deployments inside a request and the requests of a burst pass, and nothing
+else (a seed that changes the work shows up as run-to-run spread).  A
 constraint kind this file lacks arrives as ``constraints/<kind>.py`` with ``plain(template) -> dict`` (what the plain
 reference and validator enforce) and ``program(pod_kwargs, group) -> None``
 (what the client sends).
@@ -192,34 +192,59 @@ def salted(cluster: Cluster, seed: int) -> Cluster:
 
 
 class Steps:
-    """The seeded step stream of a ``reconcile`` cell over one standing
-    cluster.  A deck holds the traffic file's steps (``deck`` entries
-    ``{kind, n, copies}``); the seed shuffles it, and shuffles it again each
-    time it is exhausted — every seed sees the same multiset of steps.
+    """The step stream of a ``reconcile`` cell over one standing cluster.
+    Cluster and stream are the CONFIGURATION's: the deck's order (a deck holds
+    the traffic file's ``deck`` entries ``{kind, n, copies}``, shuffled anew
+    each time it is exhausted), the deployment every ``scale_up`` grows and
+    the pods every ``scale_down`` removes are drawn from a generator keyed by
+    the configuration's and the traffic file's names, which runs on from pass
+    to pass.  So the k-th pass of every run is the same steps, and the cluster
+    at the k-th pass boundary the same problem.  ``seed`` does what it does
+    in a burst cell (:func:`salted`): it names the deployments and orders
+    them inside the cluster.
 
     ``scale_up`` adds ``n`` new pods to one deployment drawn uniformly;
     ``scale_down`` removes, uniformly among the live pods, as many as were
     added since the last ``scale_down``.  The live set is the generator's own
-    ledger (``cluster``), never read back from the client."""
+    ledger, never read back from the client: ``(deployment, ordinal)`` pairs,
+    the deployment by its place in the configuration, in an order no seed
+    touches.  ``stream`` names another stream over a ledger of its own (the
+    warm-up's)."""
 
-    def __init__(self, cluster: Cluster, traffic: dict, seed: int) -> None:
-        self.cluster = cluster
-        self.rng = random.Random(seed ^ 0x5EED5)
+    def __init__(self, cfg: dict, traffic: dict, seed: int,
+                 scale: float = 1.0, stream: str = "steps") -> None:
+        plain = make_cluster(
+            cfg, random.Random(f"{cfg['name']}/standing"), 0, scale)
+        for d, g in enumerate(plain.groups):
+            g["deployment"] = d
+        #: the standing cluster as this seed names and orders it
+        self.cluster = salted(plain, seed)
+        #: deployment -> its group's index in this seed's order
+        self.at = sorted(range(len(plain.groups)), key=lambda i:
+                         self.cluster.groups[i]["deployment"])
+        self.rng = random.Random(
+            f"{cfg['name']}/{traffic['name']}/{stream}")
         self.deck_def = [(e["kind"], int(e.get("n", 0)))
                          for e in traffic["deck"]
                          for _ in range(int(e.get("copies", 1)))]
         self.deck: List[Tuple[str, int]] = []
         self.decks_dealt = 0
         self.added_since_down = 0
-        self.live: List[Tuple[int, str]] = [
-            (gi, name) for gi, g in enumerate(cluster.groups)
-            for name in g["pods"]]
+        #: steps taken since the last ``scale_down``
+        self.since_down = 0
+        self.live: List[Tuple[int, int]] = [
+            (d, i) for d, g in enumerate(plain.groups)
+            for i in range(len(g["pods"]))]
         self.kinds: Dict[str, int] = {}
 
+    def _name(self, d: int, i: int) -> str:
+        return f"{self.cluster.groups[self.at[d]]['name']}-{i}"
+
     def next(self, forced: Optional[Tuple[str, int]] = None) -> dict:
-        """``{kind, group, added: [names], removed: [names]}`` — and the
-        ledger already holds the step.  ``forced`` is a ``(kind, n)`` taken
-        in place of the deck's next card (the warm-up's steps)."""
+        """``{kind, group, added: [names], removed: [names]}`` (``group``
+        indexes ``cluster.groups``) — and the ledger already holds the step.
+        ``forced`` is a ``(kind, n)`` taken in place of the deck's next card
+        (the warm-up's steps)."""
         if forced is None and not self.deck:
             self.deck = list(self.deck_def)
             self.rng.shuffle(self.deck)
@@ -227,34 +252,56 @@ class Steps:
         kind, n = forced or self.deck.pop()
         self.kinds[kind] = self.kinds.get(kind, 0) + 1
         if kind == "scale_up":
-            gi = self.rng.randrange(len(self.cluster.groups))
-            g = self.cluster.groups[gi]
-            names = [f"{g['name']}-{g['next'] + i}" for i in range(n)]
+            d = self.rng.randrange(len(self.at))
+            g = self.cluster.groups[self.at[d]]
+            new = [(d, g["next"] + i) for i in range(n)]
             g["next"] += n
-            self.live.extend((gi, nm) for nm in names)
+            self.live.extend(new)
             self.added_since_down += n
-            return {"kind": kind, "group": gi, "added": names, "removed": []}
+            self.since_down += 1
+            return {"kind": kind, "group": self.at[d],
+                    "added": [self._name(*pod) for pod in new],
+                    "removed": []}
         if kind == "scale_down":
             gone = []
             for _ in range(min(self.added_since_down, len(self.live) - 1)):
                 i = self.rng.randrange(len(self.live))
                 self.live[i], self.live[-1] = self.live[-1], self.live[i]
                 gone.append(self.live.pop())
-            self.added_since_down = 0
+            self.added_since_down = self.since_down = 0
             return {"kind": kind, "group": -1, "added": [],
-                    "removed": [nm for _, nm in gone]}
+                    "removed": [self._name(*pod) for pod in gone]}
         raise ValueError(f"unknown step kind {kind!r}")
 
-    def settle(self) -> Cluster:
-        """The standing cluster as the ledger has it now (pods of every
-        group = the live set), for the comparison after the window."""
+    def settle(self, live: Optional[List[Tuple[int, int]]] = None) -> Cluster:
+        """The standing cluster as the ledger has it now, or had it when
+        ``live`` was copied off it (a pass boundary): the pods of every group
+        are its live ones, for the comparison after the window."""
         by_group: Dict[int, List[str]] = {}
-        for gi, nm in self.live:
-            by_group.setdefault(gi, []).append(nm)
-        groups = []
-        for gi, g in enumerate(self.cluster.groups):
-            groups.append({**g, "pods": by_group.get(gi, [])})
-        return Cluster(groups, ("settled",))
+        for d, i in self.live if live is None else live:
+            by_group.setdefault(self.at[d], []).append(self._name(d, i))
+        return Cluster([{**g, "pods": by_group.get(gi, [])}
+                        for gi, g in enumerate(self.cluster.groups)],
+                       ("settled",))
+
+
+def boundary_costs(boundaries: int, cost_passes: int) -> List[Optional[str]]:
+    """What the $ of a session's view at each of a window's pass boundaries
+    are compared for (``plainref.compare``'s third field of a case).  The
+    FIRST ``cost_passes`` make ``cost_ratio``, however many more a faster
+    program's window holds: the metric reads the same problems in every run.
+    A window that held fewer makes none (a traced run's; an untraced one's
+    runs on to the ``cost_passes``-th).  The configuration's cost ceiling is
+    held on those and on the LAST boundary, where what incremental steps
+    cost in $ has had the longest to grow; the boundaries between are the
+    validator's alone, so the reference is packed ``cost_passes`` + 1 times
+    a run at most."""
+    what: List[Optional[str]] = [None] * boundaries
+    for k in range(min(boundaries, cost_passes)):
+        what[k] = "metric" if boundaries >= cost_passes else "ceiling"
+    if boundaries and what[-1] is None:
+        what[-1] = "ceiling"
+    return what
 
 
 # ---------------------------------------------------------------------------

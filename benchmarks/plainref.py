@@ -339,29 +339,49 @@ def cost(ans: Answer, rows: Sequence[dict]) -> float:
 # ---------------------------------------------------------------------------
 
 
+#: what an answer's $ are compared for (a case's optional third field):
+#: ``METRIC`` they enter ``cost_ratio`` and are held to the ceiling,
+#: ``CEILING`` they are held to the ceiling alone, ``None`` they are not
+#: compared and the reference is not packed (the validator judges the answer)
+METRIC, CEILING = "metric", "ceiling"
+
+
 def compare(cases: list, provisioners: Sequence[dict], rows: Sequence[dict],
-            zones: Sequence[str], ceiling: float, unanswered: int) -> dict:
-    """``cases`` is ``[(groups, Answer)]`` — the answers compared, each with
-    the plain cluster it answers (the reference packs a cluster once, however
-    many answers to it there are).  Returns ``{correct, numbers, cost_ratio,
-    ...}``; ``numbers`` holds each number compared beside its limit."""
-    unplaced = violations = 0
+            zones: Sequence[str], ceiling: float, unanswered: int,
+            unplaced_on_the_way: int = 0) -> dict:
+    """``cases`` is ``[(groups, Answer)]`` or ``[(groups, Answer, cost)]`` —
+    the answers compared, each with the plain cluster it answers and what its
+    $ are compared for (``METRIC`` where it is not said; the reference packs
+    a cluster once, however many answers to it there are).
+    ``unplaced_on_the_way`` counts pods that answers NOT among the cases left
+    without a node; they are ``unplaced`` like the cases' own.  Returns
+    ``{correct, numbers, cost_ratio, per_case, ...}``; ``numbers`` holds each
+    number compared beside its limit, ``per_case`` every case's own
+    ``{unplaced, violations, cost, ffd}`` (``ffd`` where it was packed)."""
+    unplaced, violations = unplaced_on_the_way, 0
     got = base = 0.0
     worst = 0.0
     first_errs: List[str] = []
     refs: dict = {}
-    for groups, ans in cases:
+    per_case: List[dict] = []
+    for groups, ans, *what in cases:
+        what = what[0] if what else METRIC
         u, errs = validate(groups, provisioners, rows, zones, ans)
         unplaced += u
         violations += len(errs)
         first_errs.extend(errs[:3])
+        c = cost(ans, rows)
+        per_case.append({"unplaced": u, "violations": len(errs), "cost": c})
+        if what is None:
+            continue
         if id(groups) not in refs:
             refs[id(groups)] = cost(ffd(groups, provisioners, rows, zones),
                                     rows)
-        c, b = cost(ans, rows), refs[id(groups)]
-        got += c
-        base += b
+        b = per_case[-1]["ffd"] = refs[id(groups)]
         worst = max(worst, c / b if b else float("inf"))
+        if what == METRIC:
+            got += c
+            base += b
     numbers = {
         "unanswered": [unanswered, LIMITS["unanswered"]],
         "unplaced": [unplaced, LIMITS["unplaced"]],
@@ -371,4 +391,5 @@ def compare(cases: list, provisioners: Sequence[dict], rows: Sequence[dict],
     correct = bool(cases) and all(v <= lim for v, lim in numbers.values())
     return {"correct": correct, "numbers": numbers,
             "cost_ratio": got / base if base else None,
-            "compared": len(cases), "first_violations": first_errs[:6]}
+            "compared": len(cases), "per_case": per_case,
+            "first_violations": first_errs[:6]}

@@ -191,9 +191,11 @@ def p95(xs: list) -> float:
 # ---------------------------------------------------------------------------
 
 
-def drive(kind, limit: float, clock=time.perf_counter) -> dict:
+def drive(kind, limit: float, clock=time.perf_counter,
+          passes: int = 1) -> dict:
     """The closed loop: requests of ``kind`` one after another until the
-    first pass boundary (``kind.whole()``) at or after ``limit`` seconds.
+    first pass boundary (``kind.whole()``) at or after ``limit`` seconds
+    that is the ``passes``-th or a later one.
     ``walls`` holds every request's wall, a failed one's too; ``cuts`` the
     number of requests sent when each pass ended."""
     walls, cuts, failed, pods_offered = [], [], 0, 0
@@ -209,7 +211,7 @@ def drive(kind, limit: float, clock=time.perf_counter) -> dict:
         walls.append(now - t0)
         if kind.whole():
             cuts.append(len(walls))
-            if now - t_open >= limit:
+            if now - t_open >= limit and len(cuts) >= passes:
                 break
     return {"walls": walls, "cuts": cuts, "failed": failed,
             "pods_offered": pods_offered, "window_s": now - t_open}
@@ -326,24 +328,49 @@ class Burst:
 
 class Reconcile:
     """One session; each request one ``solve_delta`` step of the deck.  A
-    pass is one whole deck."""
+    pass is one whole deck, and every run's k-th pass is the same steps on
+    the same cluster (``gen.Steps``): the configuration's, under this seed's
+    names.
+
+    What is compared is every PASS BOUNDARY: the client's merged view when a
+    deck ends, copied as plain data, beside the generator's ledger at that
+    moment.  The validator judges each.  ``cost_ratio`` is taken over the
+    first ``cost_passes`` of them (the traffic file's), however many more a
+    faster program's window holds; an untraced window runs on until it holds
+    that many, so every run that reports the metric read it off the same
+    boundaries.  The configuration's cost ceiling (``cost_ratio_max``) is
+    held on those and on the LAST boundary, where drift has had the longest
+    to grow (``gen.boundary_costs``).  After EVERY step the pods its answer
+    holds infeasible are counted, and each such pod-step is ``unplaced``:
+    the operator acts on every answer, not on the one the window closes
+    on."""
 
     def __init__(self, inputs, cfg, traffic, seed, scale) -> None:
-        import random
-
         import gen
 
         self.traffic, self.inputs = traffic, inputs
-        # the standing cluster is the configuration's; the seed shuffles the
-        # deck, draws the deployment a step scales and the pods it removes
-        self.cluster = gen.make_cluster(
-            cfg, random.Random(f"{cfg['name']}/standing"), 0, scale)
-        self.steps = gen.Steps(self.cluster, traffic, seed)
+        self.steps = gen.Steps(cfg, traffic, seed, scale)
+        #: the warm-up's steps run over a ledger of their own
+        self.warm = gen.Steps(cfg, traffic, seed, scale, stream="warm")
+        self.cluster = self.steps.cluster
         self.first = inputs.pods(self.cluster.groups)
         self.modes: dict = {}
         self.warm_max = int(traffic["warm_max"])
+        self.cost_passes = int(traffic["cost_passes"])
+        #: per boundary ``(ledger, view, steps since a scale_down)``
+        self.boundaries: list = []
+        #: per pass, the pod-steps its answers (the boundary's own apart:
+        #: the validator counts those) held infeasible
+        self.on_the_way = [0]
+        #: the pods out now, and where the first of them were left out: the
+        #: pass and step, the step's kind and the mode it was answered in
+        self.out: set = set()
+        self.left_out_at: list = []
+        self.step_no = 0
+        self.last = ("", "")
+        self.between_s = 0.0
         info("generator", kind="reconcile", standing=self.cluster.n_pods,
-             deck=len(self.steps.deck_def))
+             deck=len(self.steps.deck_def), cost_passes=self.cost_passes)
 
     def connect(self, run) -> None:
         from karpenter_tpu.service.client import DeltaSession
@@ -371,35 +398,62 @@ class Reconcile:
         wall = time.perf_counter() - t0
         return wall, self.sess.last_mode or "?"
 
-    def warm_up(self) -> None:
-        """Establish the session, then untimed steps of each kind until a
-        whole pass shows no cold tier and no compile."""
+    @staticmethod
+    def _steady(seen: dict) -> bool:
+        """Nothing compiled and no cold tier served (``Run.since``)."""
+        return seen["quiet"] and "native" not in seen["tiers"]
+
+    def _establish(self) -> dict:
+        """The session over the standing cluster, by one full solve; returns
+        what the sidecar did for it (``Run.since``)."""
+        mark = self.run.mark()
         t0 = time.perf_counter()
         self.sess.solve(self.first, self.inputs.provisioners,
                         self.inputs.catalog)
+        seen = self.run.since(mark)
         log(f"session established: {(time.perf_counter() - t0) * 1000:.0f} "
-            f"ms, {len(self.first)} pods")
-        self.first = None
+            f"ms, {len(self.first)} pods, {seen}")
+        return seen
+
+    def warm_up(self) -> None:
+        """Establish the session, take untimed steps of each kind until a
+        whole pass of them shows no cold tier and no compile, then establish
+        it AGAIN: the window starts from the standing cluster as the warm
+        device tier packs it, however many warm passes this run needed and
+        whichever tier served the first establishment."""
+        self._establish()
         done = 0
         while done < self.warm_max:
             mark = self.run.mark()
             steps = []
             for spec in self.traffic["warm_steps"]:
-                wall, mode = self._step(self.steps.next(
+                wall, mode = self._step(self.warm.next(
                     (spec["kind"], int(spec.get("n", 0)))))
                 steps.append(f"{spec['kind']}:{mode}:{wall * 1000:.0f}")
                 done += 1
             seen = self.run.since(mark)
             log(f"warm pass ({done} steps): {' '.join(steps)}; {seen}")
-            if seen["quiet"] and "native" not in seen["tiers"]:
-                self.steps.kinds.clear()
+            if self._steady(seen):
+                break
+            if seen["compiling"]:
+                time.sleep(0.5)
+        else:
+            raise RunFailed(f"delta steps still compiled or went to a cold "
+                            f"tier after {self.warm_max} warm steps")
+        # (more than once where the warm steps never reached the sidecar,
+        # so that this is its second request and records a compile behind
+        # it: the rehearsal's ``state_unchanged`` fault; no chip run has)
+        for _ in range(3):
+            seen = self._establish()
+            if self._steady(seen):
+                self.first = None
                 info("warm_up", steps=done,
                      full_resends=self.sess.full_resends)
                 return
             if seen["compiling"]:
                 time.sleep(0.5)
-        raise RunFailed(f"delta steps still compiled or went to a cold tier "
-                        f"after {self.warm_max} warm steps")
+        raise RunFailed("establishing the session still compiled or went to "
+                        "a cold tier after the warm-up")
 
     def request(self) -> int:
         step = self.steps.next()
@@ -407,28 +461,79 @@ class Reconcile:
         m = self.modes.setdefault(mode, [0, 0.0])
         m[0] += 1
         m[1] += wall
+        self.step_no += 1
+        self.last = (step["kind"], mode)
         return len(step["added"])
 
+    @property
+    def unplaced_on_the_way(self) -> int:
+        return sum(self.on_the_way)
+
     def whole(self) -> bool:
-        return not self.steps.deck
+        """``drive`` asks once after each request, outside its wall: the pods
+        that the step's answer holds infeasible are counted here, and at the
+        end of a deck the boundary is kept (a copy: the session's containers
+        are shared).  ``between_s`` is all the time spent here."""
+        from plainref import Answer
+
+        t0 = time.perf_counter()
+        view = self.sess.result()
+        out = set(view.infeasible)
+        for pod in sorted(out - self.out)[:24 - len(self.left_out_at)]:
+            self.left_out_at.append({
+                "pass": len(self.boundaries) + 1, "step": self.step_no,
+                "kind": self.last[0], "mode": self.last[1], "pod": pod,
+                "why": str(view.infeasible[pod])[:120]})
+        self.out = out
+        boundary = not self.steps.deck
+        if boundary:
+            self.boundaries.append((list(self.steps.live),
+                                    Answer.of_result(view),
+                                    self.steps.since_down))
+            self.on_the_way.append(0)
+            self.step_no = 0
+        else:
+            self.on_the_way[-1] += len(out)
+        self.between_s += time.perf_counter() - t0
+        return boundary
 
     def close(self) -> None:
-        self.view = self.sess.result()
         self.full_resends = self.sess.full_resends
         self.sess.close()
 
     def cases(self) -> list:
-        from plainref import Answer
+        import gen
 
-        return [(self.steps.settle().groups, Answer.of_result(self.view))]
+        costs = gen.boundary_costs(len(self.boundaries), self.cost_passes)
+        return [(self.steps.settle(live).groups, ans, what)
+                for (live, ans, _), what in zip(self.boundaries, costs)]
 
     def report(self, walls: list) -> None:
+        import gen
+
         total = sum(n for n, _ in self.modes.values()) or 1
         info("delta_modes", full_resends=self.full_resends,
              decks=self.steps.decks_dealt, kinds=self.steps.kinds,
              modes={m: {"steps": n, "share": n / total,
                         "mean_ms": w / n * 1000.0}
                     for m, (n, w) in sorted(self.modes.items())})
+        # every boundary in the order of the ``comparison`` line's
+        # ``per_case`` (its view's cost, drifting from pass to pass, is what
+        # incremental steps cost in $), the steps that left a pod without a
+        # node, and what the look after every step added to the window
+        costs = gen.boundary_costs(len(self.boundaries), self.cost_passes)
+        info("boundaries", cost_passes=self.cost_passes,
+             in_cost_ratio=costs.count("metric"),
+             between_s=self.between_s,
+             between_share=self.between_s / sum(walls),
+             unplaced_on_the_way=self.unplaced_on_the_way,
+             left_out_at=self.left_out_at,
+             passes=[{"pass": k + 1, "pods": len(live),
+                      "steps_since_scale_down": since,
+                      "cost_compared_for": what,
+                      "unplaced_on_the_way": self.on_the_way[k]}
+                     for k, ((live, _, since), what)
+                     in enumerate(zip(self.boundaries, costs))])
 
 
 KINDS = {"burst": Burst, "reconcile": Reconcile}
@@ -536,7 +641,11 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
         if trace:
             sidecar.command(f"trace_start {trace_dir}")
         setup_s = time.perf_counter() - T_START
-        window = drive(kind, limit)
+        # a traffic mix whose cost_ratio reads fixed passes gets them all,
+        # whatever the time: the metric is never read off fewer (a traced
+        # window stays short and reports none)
+        window = drive(kind, limit, passes=1 if trace else int(
+            traffic.get("cost_passes", 1)))
         walls, failed, window_s = (window["walls"], window["failed"],
                                    window["window_s"])
         trace_window_s = (sidecar.command("trace_stop")["window_s"]
@@ -572,9 +681,10 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
         verdict = plainref.compare(
             kind.cases(), gen.provisioners_plain(cfg), rows["types"],
             rows["zones"], float(cfg["guarantees"]["cost_ceiling"]),
-            unanswered=failed)
+            unanswered=failed,
+            unplaced_on_the_way=getattr(kind, "unplaced_on_the_way", 0))
         info("comparison", seconds=time.perf_counter() - t_cmp,
-             compared=verdict["compared"],
+             compared=verdict["compared"], per_case=verdict["per_case"],
              first_violations=verdict["first_violations"])
         kind.report(walls)
         passes = whole_passes(window)

@@ -45,13 +45,18 @@ def test_cell_runs_and_its_last_line_meets_the_contract(bench, workload,
     declared = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]
                 if "workloads" not in m or workload in m["workloads"]}
     missing = declared - set(line["metrics"])
-    # device busy time exists only in a traced run
-    assert missing == (set() if trace else {"device_busy_ms"}), missing
+    earlier = [json.loads(e) for e in run.EARLIER]
+    # device busy time exists only in a traced run; cost_ratio only where
+    # the window held the passes it is read off (an untraced one always did)
+    marks = [e for e in earlier if e["info"] == "boundaries"]
+    short = bool(marks) and not marks[-1]["in_cost_ratio"]
+    assert not (short and not trace)
+    assert missing == ({"cost_ratio"} if short else
+                       set() if trace else {"device_busy_ms"}), missing
     assert line["device"]["platform"] == "cpu"
     if trace:
         assert 0 < line["device"]["busy_s"] <= line["device"]["window_s"]
     # the window is whole passes, and every answer in it was compared
-    earlier = [json.loads(e) for e in run.EARLIER]
     tiers = [e for e in earlier if e["info"] == "tiers"][-1]
     assert tiers["window_s"] >= 2.0
     # its passes are in the run's output, every one, beside the median pass;
@@ -73,8 +78,57 @@ def test_cell_runs_and_its_last_line_meets_the_contract(bench, workload,
         compared = [e for e in earlier if e["info"] == "comparison"][-1]
         assert compared["compared"] == line["attempted"]
     else:
-        deck = sum(e["copies"] for e in gen.load_traffic("reconcile")["deck"])
+        traffic = gen.load_traffic("reconcile")
+        deck = sum(e["copies"] for e in traffic["deck"])
         assert line["attempted"] == deck * window["passes"]
+        # every pass boundary was compared: the first cost_passes for the
+        # metric, the last for the ceiling, no other priced
+        compared = [e for e in earlier if e["info"] == "comparison"][-1]
+        costs = gen.boundary_costs(window["passes"], traffic["cost_passes"])
+        assert compared["compared"] == window["passes"] == len(
+            marks[-1]["passes"])
+        assert [p["cost_compared_for"] for p in marks[-1]["passes"]] == costs
+        assert ["ffd" in c for c in compared["per_case"]] == [
+            c is not None for c in costs]
+        assert trace or window["passes"] >= traffic["cost_passes"]
+        assert marks[-1]["unplaced_on_the_way"] == 0
+        assert marks[-1]["between_share"] < 0.005
+
+
+def test_a_reconcile_cell_reads_one_cost_ratio_whatever_seed_and_window(
+        bench):
+    """The step stream is the configuration's and ``cost_ratio`` is taken at
+    the first ``cost_passes`` pass boundaries: two seeds read it equal, and
+    so does a window of twice the passes.  (The long window is not held to
+    ``correct``: from the third pass on, the program under test leaves a pod
+    without a node in some ``scan`` steps; PERF.md section 7.)"""
+    n = gen.load_traffic("reconcile")["cost_passes"]
+
+    def read(seed, seconds):
+        line = rehearse(bench, "c2.reconcile", 0, seconds=seconds, seed=seed)
+        earlier = [json.loads(e) for e in run.EARLIER]
+        marks = [e for e in earlier if e["info"] == "boundaries"][-1]
+        cases = [e for e in earlier if e["info"] == "comparison"][-1][
+            "per_case"]
+        assert marks["in_cost_ratio"] == n and line["failed"] == 0
+        return (line["metrics"]["cost_ratio"]["value"], len(cases),
+                [(p["pods"], c["cost"], c["ffd"])
+                 for p, c in zip(marks["passes"][:n], cases)], line)
+
+    # no time at all: the window runs on to the cost_passes-th boundary
+    ratio, passes, priced, line = read(SEED, 0.1)
+    assert passes == n
+    assert line["correct"] is True, line["compared"]
+    assert sum(c for _, c, _ in priced) / sum(
+        f for _, _, f in priced) == pytest.approx(ratio, rel=1e-12)
+    other = read(977, 0.1)
+    assert other[3]["correct"] is True, other[3]["compared"]
+    longer = read(SEED, 2.0 * line["attempted"]
+                  * line["metrics"]["solve_ms"]["value"] / 1000.0)
+    assert longer[1] > passes
+    for got in (other, longer):
+        assert got[2] == priced
+        assert got[0] == pytest.approx(ratio, abs=0.0025)
 
 
 # ---- the timed path broken underneath: correct has to come out false ----
@@ -136,6 +190,64 @@ def view_altered(sess):
     return sess
 
 
+def numbered(sess, fault):
+    """``fault(k, added)`` after the k-th step since the session was last
+    established, which is where the window starts."""
+    establish, step = sess.solve, sess.solve_delta
+    steps = [0]
+
+    def solve(*args, **kw):
+        steps[0] = 0
+        return establish(*args, **kw)
+
+    def solve_delta(added=(), removed=(), **kw):
+        res = step(added=added, removed=removed, **kw)
+        steps[0] += 1
+        fault(steps[0], added)
+        return res
+
+    sess.solve, sess.solve_delta = solve, solve_delta
+    return sess
+
+
+DECK = sum(e["copies"] for e in gen.load_traffic("reconcile")["deck"])
+
+
+def view_altered_at_the_first_boundary(sess):
+    """Only the step that ends the first pass leaves a pod assigned to a node
+    that does not list it; the steps after it mend the view."""
+    def fault(k, added):
+        name = next(iter(sess._assignments))
+        if k == DECK:
+            sess.was = (name, sess._assignments[name])
+            sess._assignments[name] = next(
+                n.name for n in sess._nodes.values()
+                if all(p.name != name for p in n.pods))
+        elif k == DECK + 1 and sess._assignments.get(sess.was[0]) not in (
+                None, sess.was[1]):
+            sess._assignments[sess.was[0]] = sess.was[1]
+
+    return numbered(sess, fault)
+
+
+def pod_left_out_for_one_step(sess):
+    """The second step after an establishment (the deck's first four cards
+    add pods, and so do the warm-up's) leaves one of its pods without a node;
+    the third seats it."""
+    held = []
+
+    def fault(k, added):
+        if k == 2:
+            name = added[0].name
+            held[:] = [name, sess._assignments.pop(name)]
+            sess._infeasible[name] = "left out"
+        elif k == 3:
+            del sess._infeasible[held[0]]
+            sess._assignments[held[0]] = held[1]
+
+    return numbered(sess, fault)
+
+
 FAULTS = [
     ("c2.burst", half_of_the_batch, "unplaced"),
     ("c3.burst", half_of_the_batch, "unplaced"),
@@ -143,15 +255,35 @@ FAULTS = [
     ("c3.burst", answer_altered, "violations"),
     ("c2.reconcile", state_unchanged, "unplaced"),
     ("c2.reconcile", view_altered, "violations"),
+    ("c2.reconcile", view_altered_at_the_first_boundary, "violations"),
+    ("c2.reconcile", pod_left_out_for_one_step, "unplaced"),
 ]
 
 
 @pytest.mark.parametrize("workload,fault,number", FAULTS)
 def test_a_broken_timed_path_is_not_correct(bench, workload, fault, number):
+    # (a reconcile window runs on to its second boundary, so the fault at
+    # the first has a pass behind it)
     line = rehearse(bench, workload, 0, tamper=fault, seconds=1.5)
     assert line["correct"] is False
     value, limit = line["compared"][number]
     assert value > limit, line["compared"]
+    if fault in (view_altered_at_the_first_boundary,
+                 pod_left_out_for_one_step):
+        # no other number fails, and the view the window closed on is sound:
+        # judged on that alone, as before, the run would have read correct
+        assert all(v <= lim for name, (v, lim) in line["compared"].items()
+                   if name != number), line["compared"]
+        earlier = [json.loads(e) for e in run.EARLIER]
+        marks = [e for e in earlier if e["info"] == "boundaries"][-1]
+        cases = [e for e in earlier if e["info"] == "comparison"][-1][
+            "per_case"]
+        assert cases[-1]["unplaced"] == cases[-1]["violations"] == 0
+        if fault is pod_left_out_for_one_step:
+            assert marks["unplaced_on_the_way"] == 1 == line["compared"][
+                number][0]
+        else:
+            assert cases[0]["violations"] > 0 and len(cases) > 1
 
 
 def test_the_command_refuses_a_cpu(tmp_path):

@@ -10,6 +10,7 @@ import pytest
 
 import gen
 import run
+from karpenter_tpu.solver.types import SolveResult
 
 
 class Clock:
@@ -144,11 +145,19 @@ def the_deck_boundary():
         return 0.0, "scan"
 
     kind._step = step
+
+    class NoSession:  # what ``whole`` reads after every step: an empty view
+        @staticmethod
+        def result():
+            return SolveResult(nodes=[], assignments={}, infeasible={})
+
+    kind.sess = NoSession
     # a deck takes 3.567 s: the window runs on to the end of the second
     out = run.drive(kind, 5.0, clock)
     est = run.whole_passes(out)
     assert out["cuts"] == [deck, 2 * deck] and len(out["walls"]) == 2 * deck
     assert kind.steps.decks_dealt == 2 and not kind.steps.deck
+    assert len(kind.cases()) == 2  # a case a pass boundary
     up = sum(int(e["n"]) * int(e["copies"]) for e in traffic["deck"]
              if e["kind"] == "scale_up")
     assert out["pods_offered"] == 2 * up
