@@ -215,8 +215,9 @@ SCAN_SLOT_RETRIES = "karpenter_solver_scan_slot_retries_total"
 # ---- the host's merge pass over a scan's new nodes (solver/coalesce.py) --
 COALESCE = "karpenter_solver_coalesce_total"
 #: what a pass saw and did (KT003 zero-init source): 'nodes_in' (new nodes
-#: the scan opened) and 'merges' (each takes one node off the answer)
-COALESCE_WHAT = ("nodes_in", "merges")
+#: the scan opened), 'merges' (each takes one node off the answer) and
+#: 'pairs' (pair verdicts computed to find them)
+COALESCE_WHAT = ("nodes_in", "merges", "pairs")
 TRACE_RING_EVICTIONS = "karpenter_trace_ring_evictions_total"
 FLIGHT_DUMPS = "karpenter_trace_flight_recorder_dumps_total"
 # ---- fleet-wide tracing (ISSUE 15: wire-propagated trace context) -------
@@ -669,10 +670,13 @@ INVENTORY = {
         "each megabatch slot): 'nodes_in' — new nodes the scan opened; "
         "'merges' — pairs of them replaced by one node of a larger type "
         "at no higher price, so nodes_in less merges is what the answer "
-        "keeps.  A work count: for one input it reads the same whatever "
+        "keeps; 'pairs' — pairs of nodes whose verdict (the cheapest type "
+        "that holds both, or none) the pass computed on its way to those "
+        "merges.  Work counts: for one input they read the same whatever "
         "the pass costs (the `coalesce` span times it); merges close to "
         "nodes_in is a scan that opened a node per tiny group and left "
-        "the packing to the host."),
+        "the packing to the host, and pairs far above merges is a pass "
+        "whose windows hold few pairs that can merge."),
     TRACE_RING_EVICTIONS: (
         "counter", (),
         "Traces evicted from the flight recorder's bounded ring to admit "

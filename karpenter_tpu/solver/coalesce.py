@@ -28,32 +28,43 @@ the FRAG_WINDOW first of them, take the first pair (smallest i, then
 smallest j > i) that some candidate can hold, replace both by the cheapest
 such candidate, put it back in order, look again; stop when no pair of the
 window merges.  Greedy and deterministic.  How it is held: a bucket's nodes
-are rows 0..n-1 of dense arrays (used resources, price, candidate
+are rows 0..n-1 of dense arrays (used resources and price, candidate
 feasibility, raw capacity, hostname counts and caps) and every merge writes
-ONE new row (sum / AND / min of two); a pair's verdict — its cheapest
-candidate, or none — is computed once, when the later of the two first
-enters the window, for all pairs new to the window in one broadcast over
-the candidates in price order (less those an earlier one beats on every
-count), and lives in a matrix addressed by the two nodes' window slots, so
-the first hit is an ``argmax`` over its upper triangle (a node the capped
-order pushes out of the window keeps its slot, and on its way back is not
-asked again about nodes that came in meanwhile: the rule of the loop this
-replaced, kept so that the answers are its answers); the order is a sorted list of ``(rank, size, name)`` keys kept by
-bisection, where a merge re-ranks only the later members of the
-combinations it takes from and adds to.  Merged nodes are rows and a name
-until the bucket is done: only the survivors become ``SimNode`` objects.
+ONE new row (sum / AND / min of two).  A pair's verdict — its cheapest
+candidate, or none — does not change with other merges, so it is computed
+when the rule needs it and kept: each merge walks the window as the rule
+reads it, row by row and partners in order, past the pairs known to hold
+nothing, and asks the pairs it meets that have no verdict yet in one
+broadcast over the candidates in price order (less those an earlier one
+beats on every count) — as many in a round as finding the last hit took,
+twice that if none of them holds, never the whole window because it is
+there.  The pairs that hold nothing are one whole number per row, bit y for
+row y — the rows it is dead to; the hits are a dict.  A pair may be asked only
+if the later of the two first entered the window while the other was in it: a
+row the capped order pushes out is, on its way back, dead to the rows that
+came in meanwhile (the rule of the loop this replaced, kept so that the
+answers are its answers).  The order is a sorted list of ``(rank, size,
+name)`` keys kept by bisection, where a merge re-ranks only the later
+members of the combinations it takes from and adds to.  Merged nodes are
+rows and a name until the bucket is done: only the survivors become
+``SimNode`` objects.  The per-node state is read off the scan's take matrix:
+the callers hand over how many pods of which group each node took and what
+it has in use, and a bucket's hostname counts and caps are one product and
+one minimum over those entries.
 
 ``TpuSolver._extract`` wraps the pass in a ``coalesce`` span (``nodes_in``,
-``nodes_out``, ``merges``, ``buckets``) and counts it in
-``karpenter_solver_coalesce_total{what="nodes_in"|"merges"}``.
+``nodes_out``, ``merges``, ``buckets``, ``pairs``) and counts it in
+``karpenter_solver_coalesce_total{what="nodes_in"|"merges"|"pairs"}``
+(``pairs``: verdicts computed, the walk's work count).
 """
 
 from __future__ import annotations
 
 from bisect import bisect_left, insort
-from collections import Counter
+from functools import reduce
 from itertools import chain
-from typing import Dict, List, Optional, Tuple
+from operator import or_
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -83,12 +94,21 @@ def label_feasibility(st) -> np.ndarray:
     kc = np.asarray(st.key_check)             # [K]
     G, K, _W = pm.shape
     C = vw.shape[0]
-    lab = np.ones((G, C), dtype=bool)
+    # groups that ask the same of a node's labels share a row (a long tail
+    # of deployments is a handful of requirement sets): the test runs once
+    # for each group that is the first to ask what it asks
+    seen: Dict[bytes, int] = {}
+    same = np.fromiter((seen.setdefault(row.tobytes(), g)
+                        for g, row in enumerate(pm.reshape(G, -1))),
+                       np.intp, G)
+    first = np.flatnonzero(same == np.arange(G))
+    lab = np.ones((first.size, C), dtype=bool)
     for k in range(K):
         if not kc[k]:
             continue
-        words = pm[:, k, :][:, vw[:, k]]      # [G, C]
+        words = pm[first, k, :][:, vw[:, k]]  # [distinct, C]
         lab &= ((words >> vb[None, :, k]) & 1).astype(bool)
+    lab = lab[np.searchsorted(first, same)]   # [G, C]
     gp_ok = np.asarray(st.gp_ok)
     lab &= gp_ok[np.arange(G)[:, None], np.asarray(st.cand_prov)[None, :]]
     st._host_F = lab
@@ -106,6 +126,14 @@ def hostname_constrained(st) -> bool:
     )
 
 
+def _rows(mask: int):
+    """The rows whose bits are set in ``mask``, lowest first."""
+    while mask:
+        low = mask & -mask
+        yield low.bit_length() - 1
+        mask ^= low
+
+
 def _domain_index(st, zone: str, ct: str) -> Optional[int]:
     try:
         zi = st.zone_names.index(zone)
@@ -115,62 +143,68 @@ def _domain_index(st, zone: str, ct: str) -> Optional[int]:
     return zi * max(1, len(st.ct_names)) + ci
 
 
+class Coalesced(tuple):
+    """``(nodes, renames, buckets)``, what the pass has always returned, and
+    beside them ``pairs``: how many pair verdicts it computed."""
+
+    pairs = 0
+
+
 def apply_coalesce(st, nodes, used_rows, node_groups, assignments, span=None):
-    """Shared tier epilogue: run the merge pass and repoint assignments of
+    """Shared tier epilogue: run the merge pass and repoint the pods of
     absorbed nodes at their replacements.  Both the device tier
     (tpu._extract) and the native tier (native.solve_tensors_native) call
     this so the cold-start answer and the warm answer stay the same
-    coalescing contract.  ``span``, where given, is told what the pass did."""
-    n_in, buckets = len(nodes), 0
+    coalescing contract.  Returns ``(nodes, pairs)``; ``span``, where given,
+    is told what the pass did."""
+    n_in, buckets, pairs = len(nodes), 0, 0
     if n_in >= 2:
-        nodes, renames, buckets = coalesce_new_nodes(
-            st, nodes, used_rows, node_groups=node_groups)
-        if renames:
-            for pod_name, node_name in list(assignments.items()):
-                if node_name in renames:
-                    assignments[pod_name] = renames[node_name]
+        done = coalesce_new_nodes(st, nodes, used_rows,
+                                  node_groups=node_groups)
+        (nodes, renames, buckets), pairs = done, done.pairs
+        # an absorbed node's pods are on the node its name now leads to
+        holders = set(renames.values())
+        for node in nodes:
+            if node.name in holders:
+                for pod in node.pods:
+                    assignments[pod.name] = node.name
     if span is not None:
         span.annotate(nodes_in=n_in, nodes_out=len(nodes),
-                      merges=n_in - len(nodes), buckets=buckets)
-    return nodes
+                      merges=n_in - len(nodes), buckets=buckets, pairs=pairs)
+    return nodes, pairs
 
 
 def coalesce_new_nodes(
     st,
     nodes: List[SimNode],
     used_rows: Dict[int, np.ndarray],  # id(node) -> used resource row [R]
-    node_groups: Optional[Dict[int, set]] = None,  # id(node) -> {group idx}
-) -> Tuple[List[SimNode], Dict[str, str], int]:
+    # id(node) -> {group idx: pods of it on the node}, off the take matrix
+    node_groups: Optional[Dict[int, Dict[int, int]]] = None,
+) -> Coalesced:
     """Merge mergeable new nodes; returns (new node list, renames, buckets)
     where ``renames`` maps absorbed old node names -> their replacement's
     name and ``buckets`` counts the (provisioner, zone, capacity-type)
     buckets the nodes fell in.  Every merge takes one node off the list.
-    Pods are moved onto the replacement nodes; callers fix assignments via
-    the rename map.  ``node_groups`` scopes the label-feasibility check to
-    the groups actually placed on each node; without it (untracked solves)
-    the merge target must be feasible for EVERY group in the solve."""
+    Pods are moved onto the replacement nodes.  ``node_groups`` scopes the
+    label-feasibility check to the groups actually placed on each node, and
+    its counts are what the hostname caps are held against; without it
+    (untracked solves) the merge target must be feasible for EVERY group in
+    the solve."""
     # untracked solves can't scope the check per node: all-or-nothing
     if node_groups is None and hostname_constrained(st):
-        return nodes, {}, 0
+        return Coalesced((nodes, {}, 0))
     # per-node hostname bookkeeping for capped solves: a merge is legal when,
     # for every hostname slot either node's groups cap, the COMBINED count of
     # slot-matching pods stays within the stricter cap (anti-affinity
     # cap 1/0, spread maxSkew).  Group labels are uniform, so counts come
-    # from g_sel_match at group granularity — no per-pod selector matching.
-    # This is what lets bench config 3 (every pod hostname-anti) coalesce its
-    # 1-pod-per-service fragments into shared nodes at equal-or-lower price.
-    # Positive hostname affinity (g_host_paff) needs no cap: it wants
-    # matching pods together, and merging only ever ADDS co-residents (fuzz
-    # seed 23: one paff group used to disable coalescing for the whole solve).
+    # from g_sel_match at group granularity.  This is what lets bench config
+    # 3 (every pod hostname-anti) coalesce its 1-pod-per-service fragments
+    # into shared nodes at equal-or-lower price.  Positive hostname affinity
+    # (g_host_paff) needs no cap: merging only ever ADDS co-residents.
     g_hs = np.asarray(st.g_host_spread)
     g_hc = np.asarray(st.g_host_cap, dtype=np.float64)
     sel = np.asarray(st.g_sel_match)
     host_active = node_groups is not None and bool((g_hs >= 0).any())
-    pod_group: Dict[str, int] = {}
-    if host_active:
-        for gi, g in enumerate(st.groups):
-            for p in g.pods:
-                pod_group[p.name] = gi
     F = label_feasibility(st)                             # [G, C]
     G = F.shape[0]
     F_distinct = np.array(list({row.tobytes(): row for row in F}.values()))
@@ -188,6 +222,7 @@ def coalesce_new_nodes(
 
     out: List[SimNode] = []
     renames: Dict[str, str] = {}
+    pairs = 0
     for (prov, zone, ct), group in buckets.items():
         di = _domain_index(st, zone, ct)
         pi = prov_index.get(prov)
@@ -199,8 +234,7 @@ def coalesce_new_nodes(
         # bucket-local candidate table (spot pricing is NOT linear in size —
         # zonal discounts vary per type — so the cheapest feasible
         # replacement can come from any family), in price order: the
-        # cheapest feasible candidate is the FIRST feasible one, and a pair
-        # only has to look at the candidates its two prices can pay for
+        # cheapest feasible candidate is the FIRST feasible one
         cand_ix = np.asarray([ci for ci in cands if st.cand_avail[ci, di]],
                              dtype=np.int64)
         if cand_ix.size == 0:
@@ -232,74 +266,89 @@ def coalesce_new_nodes(
             c_room, c_cap = c_room[:, keep], c_cap[:, keep]
 
         # the bucket's dense state: rows 0..n-1 are the scan's nodes as they
-        # arrive, every merge appends one (2n-1 at most)
+        # arrive, every merge appends one (2n-1 at most).  A row holds its
+        # used resources and, last, its price with the sign turned: "the
+        # candidate has the room and is no dearer" is one comparison with
+        # c_tab, whose last row is the candidates' prices, turned too
         K, N = cand_ix.size, 2 * n - 1
         names = [x.name for x in group]
-        used = np.empty((N, R))
-        used[:n] = [used_rows[id(x)] for x in group]
-        size = used[:n].sum(axis=1).tolist()              # the order's key
-        price = np.empty(N)
-        price[:n] = [x.price for x in group]
+        tab = np.empty((N, R + 1))
+        tab[:n, :R] = [used_rows[id(x)] for x in group]
+        tab[:n, R] = [-x.price for x in group]
+        size = tab[:n, :R].sum(axis=1).tolist()           # the order's key
+        # (column K is no candidate: it holds every pair, after all others)
+        c_tab = np.full((R + 1, K + 1), np.inf)
+        c_tab[:R, :K], c_tab[R, :K] = c_room, -c_price
         # candidate feasibility: AND over the node's groups (c_F[union].all
         # == c_F[a].all & c_F[b].all, so a merged row is an AND of two)
-        feas = np.empty((N, K), dtype=bool)
+        feas = np.ones((N, K + 1), dtype=bool)
         if node_groups is None:
-            feas[:n] = c_F.all(axis=0)
+            feas[:n, :K] = c_F.all(axis=0)
         else:
             # one reduceat over every node's rows; row G (all true) closes
             # each segment so none is empty, row G+1 is a node the caller
             # did not track: every group
             rows = np.vstack([c_F, np.ones((1, K), dtype=bool),
                               c_F.all(axis=0)[None]])
-            segs = [(*gs, G) if gs is not None else (G + 1,)
-                    for gs in (node_groups.get(id(x)) for x in group)]
+            took = [node_groups.get(id(x)) for x in group]
+            segs = [(*gs, G) if gs is not None else (G + 1,) for gs in took]
             starts = np.cumsum([0] + [len(s) for s in segs[:-1]])
-            feas[:n] = np.logical_and.reduceat(
+            feas[:n, :K] = np.logical_and.reduceat(
                 rows[np.fromiter(chain.from_iterable(segs), np.intp)],
                 starts, axis=0)
+        selective = not feas[:n].all()  # some group does not admit some type
         if limited:
             cap = np.empty((N, R), dtype=np.float32)
             cap[:n] = [st.capacity_row(x.instance_type, x.allocatable)
                        for x in group]
         if host_active:
-            # (counts[S], caps[S]) per node; caps inf where unconstrained
-            hcnt = np.zeros((N, sel.shape[0]), dtype=np.int64)
-            hcap = np.full((N, sel.shape[0]), np.inf)
-            for x, node in enumerate(group):
-                took = Counter(pod_group.get(p.name) for p in node.pods)
-                if None in took:
-                    # a pod outside this solve (shouldn't happen for new
-                    # nodes): be conservative, forbid merging this node
+            # (counts[S], caps[S]) per node, caps inf where unconstrained,
+            # from the bucket's (node, group, pods) entries: the counts as
+            # one product with the selector matrix, entry by matching slot
+            # (whole numbers: exact), the caps as one minimum
+            held = [gs or {} for gs in took]
+            at = np.repeat(np.arange(n), [len(gs) for gs in held])
+            gs = np.fromiter(chain.from_iterable(held), np.intp, at.size)
+            pods = np.fromiter(chain.from_iterable(h.values() for h in held),
+                               np.float64, at.size)
+            S = sel.shape[0]
+            entry, slot = np.nonzero(sel.T[gs])
+            hcnt = np.zeros((N, S))
+            hcnt[:n] = np.bincount(at[entry] * S + slot, weights=pods[entry],
+                                   minlength=n * S).reshape(n, S)
+            hcap = np.full((N, S), np.inf)
+            capping = g_hs[gs] >= 0
+            np.minimum.at(hcap, (at[capping], g_hs[gs[capping]]),
+                          g_hc[gs[capping]])
+            for x in range(n):  # a node the caller did not track: no merge
+                if took[x] is None:
                     hcap[x] = -1.0
-                    continue
-                gs = np.fromiter(took, np.intp, len(took))
-                hcnt[x] = sel[:, gs] @ np.fromiter(took.values(), np.int64,
-                                                   len(took))
-                capping = gs[g_hs[gs] >= 0]
-                np.minimum.at(hcap[x], g_hs[capping], g_hc[capping])
 
         def verdicts(x: np.ndarray, y: np.ndarray) -> np.ndarray:
             """The cheapest candidate that can replace nodes x[p] and y[p]
-            together, -1 where none can.  Symmetric, and unaffected by other
+            together, K where none can.  Symmetric, and unaffected by other
             merges: asked once per pair."""
-            pay = price[x] + price[y]
-            top = max(1, int(np.searchsorted(c_price, pay.max() + 1e-9,
-                                             side="right")))
-            need = used[x] + used[y]                              # [P, R]
-            ok = feas[x, :top] & feas[y, :top]                    # [P, top]
-            for r in range(R):
-                ok &= c_room[r, :top] >= need[:, r, None]
-            ok &= c_price[:top] <= pay[:, None] + 1e-9
+            if host_active:
+                # hostname caps: combined slot-matching counts must respect
+                # the stricter of the two nodes' caps on every slot; only
+                # the pairs that do need a candidate
+                got = np.full(x.size, K)
+                fit = np.flatnonzero((hcnt[x] + hcnt[y] <= np.minimum(
+                    hcap[x], hcap[y])).all(axis=1))
+                x, y = x[fit], y[fit]
+            need = tab[x] + tab[y]                                # [P, R+1]
+            need[:, R] -= 1e-9  # c_price <= pay + 1e-9, both sides turned
+            ok = (c_tab >= need[:, :, None]).all(axis=1)          # [P, K+1]
+            if selective:
+                ok &= feas[x] & feas[y]
             if limited:
                 capb = cap[x] + cap[y]
                 for r in range(R):
-                    ok &= c_cap[r, :top] <= capb[:, r, None] + 1e-6
-            if host_active:
-                # hostname caps: combined slot-matching counts must respect
-                # the stricter of the two nodes' caps on every slot
-                ok &= (hcnt[x] + hcnt[y] <= np.minimum(hcap[x], hcap[y])
-                       ).all(axis=1)[:, None]
-            return np.where(ok.any(axis=1), ok.argmax(axis=1), -1)
+                    ok[:, :K] &= c_cap[r] <= capb[:, r, None] + 1e-6
+            if not host_active:
+                return ok.argmax(axis=1)
+            got[fit] = ok.argmax(axis=1)
+            return got
 
         # Scan order.  Plain solves: smallest-first.  Hostname-capped
         # solves: same, but round-robin across group combinations — the
@@ -307,9 +356,8 @@ def coalesce_new_nodes(
         # smallest-first window would fill with ONE service's nodes, whose
         # pairs all violate the per-node cap; rotating group combos puts
         # mergeable cross-service partners inside the window.  A key is
-        # (rank inside its combination, size, name, row); names are unique,
-        # so the order is total and a bisection keeps the list what a full
-        # sort would give.
+        # (rank inside its combination, size, name, row): names are unique,
+        # so a bisection keeps the list what a full sort would give.
         keys = sorted((0, size[x], names[x], x) for x in range(n))
         if host_active:
             combo = [frozenset(node_groups.get(id(x), all_groups))
@@ -334,54 +382,97 @@ def coalesce_new_nodes(
         # The scan is windowed to the FRAG_WINDOW first nodes — fragments
         # live at the small end, and an unwindowed pair scan over a 50k-pod
         # solve's hundreds of nodes would cost more host time than the solve.
-        # A node takes a slot of the verdict matrix when it first enters the
-        # window and keeps it until it is absorbed (the capped order can
-        # push a node out of the window and let it back in).
-        verdict = np.full((FRAG_WINDOW, FRAG_WINDOW), -1, np.int32)
-        later = np.triu(np.ones((FRAG_WINDOW, FRAG_WINDOW), dtype=bool), 1)
-        slot = np.full(N, -1, dtype=np.intp)
-        free = list(range(len(verdict)))
+        # A row has a bit from its first entry to the window until absorbed
+        bit: Dict[int, int] = {}
+        dead: Dict[int, int] = {}  # row -> rows it cannot, or may not, take
+        best: Dict[tuple, int] = {}  # (row, row) -> their cheapest candidate
+        left: Dict[int, int] = {}  # row pushed out -> `entered` as it went
+        window = entered = absent = 0
         merged: Dict[int, tuple] = {}  # row -> (row a, row b, candidate)
+        ask = 1  # pairs asked in a round: what the last hit took to find
         while len(keys) >= 2:
-            wid = np.array([key[3] for key in keys[:FRAG_WINDOW]])
-            fresh = slot[wid] < 0
-            if fresh.any():
-                for row in wid[fresh].tolist():
-                    if not free:  # nodes pushed out of the window hold slots
-                        had = len(verdict)
-                        verdict = np.pad(verdict, (0, had), constant_values=-1)
-                        free.extend(range(had, 2 * had))
-                    slot[row] = s = free.pop()
-                    verdict[s] = verdict[:, s] = -1
-                at = np.flatnonzero(fresh)
-                # pairs that touch a fresh node, each once
-                p, q = np.nonzero((np.arange(wid.size) > at[:, None]) | ~fresh)
-                x, y = wid[at[p]], wid[q]
-                verdict[slot[x], slot[y]] = verdict[slot[y], slot[x]] = (
-                    verdicts(x, y))
-            ws = slot[wid]
-            hits = (verdict[ws[:, None], ws] >= 0) & later[:ws.size, :ws.size]
-            i, j = divmod(int(hits.argmax()), wid.size)
-            if not hits[i, j]:
+            wid = [key[3] for key in keys[:FRAG_WINDOW]]
+            fresh = [x for x in wid if x not in bit]
+            for x in fresh:
+                bit[x] = 1 << x
+            was, window = window, reduce(or_, map(bit.__getitem__, wid))
+            # a pair may be asked if the later of the two first entered the
+            # window while the other was in it: a row the capped order pushes
+            # out and lets back in is dead to the rows that entered meanwhile
+            if was & ~window:
+                for z in _rows(was & ~window):
+                    left[z] = entered
+                absent |= was & ~window
+            if absent & window:
+                for z in _rows(absent & window):
+                    dead[z] |= entered & ~left.pop(z)
+                absent &= ~window
+            for x in fresh:
+                dead[x] = absent
+                entered |= bit[x]
+            # the walk: the window's pairs row by row, partners in order, up
+            # to the first that a candidate holds.  The pairs not asked yet
+            # that it meets on the way are asked, `ask` of them in one round,
+            # and the walk goes on from the top only if none of them is held
+            asked = 0
+            while True:
+                seen, todo, found = 0, [], None
+                for i, x in enumerate(wid):
+                    seen |= bit[x]
+                    live = window & ~(seen | dead[x])
+                    if not live:
+                        continue
+                    if live.bit_count() ** 2 > len(wid) - i:
+                        # many: meet them on the way down the window
+                        after = (y for y in wid[i + 1:] if live & bit[y])
+                    else:  # few: read them off, put them in order
+                        after = sorted(_rows(live), key=wid.index)
+                    for y in after:
+                        if (x, y) in best:
+                            found = (x, y)
+                            break
+                        todo.append((x, y))
+                        if len(todo) == ask:
+                            break
+                    else:
+                        continue
+                    break
+                if todo:
+                    got = verdicts(*np.array(todo).T).tolist()
+                    for (x, y), k in zip(todo, got):
+                        if k == K:
+                            dead[x] |= bit[y]
+                            dead[y] |= bit[x]
+                        else:
+                            best[x, y] = best[y, x] = k
+                    if min(got) < K:  # the first held in order: what it took
+                        at = next(i for i, k in enumerate(got) if k < K)
+                        found, ask = todo[at], asked + at + 1
+                    asked += len(todo)
+                if found is not None or len(todo) < ask:
+                    break  # a pair to merge, or no pair left to ask
+                ask *= 2
+            pairs += asked
+            if found is None:
                 break
-            a, b = int(wid[i]), int(wid[j])
-            k = int(verdict[ws[i], ws[j]])
+            a, b = found
+            k = best[found]
             m = len(names)
             merged[m] = (a, b, k)
-            # names are a tie-break of the order and part of the answer:
-            # one a merge, drawn at the merge
+            # names break ties of the order: one a merge, drawn at the merge
             names.append(next_node_name())
-            used[m] = used[a] + used[b]
-            size.append(float(used[m].sum()))
-            price[m] = c_price[k]
-            feas[m] = feas[a] & feas[b]
+            tab[m] = tab[a] + tab[b]
+            tab[m, R] = -c_price[k]
+            size.append(float(tab[m, :R].sum()))
+            if selective:
+                feas[m] = feas[a] & feas[b]
             if limited:
                 cap[m] = st.capacity_row(st.cand_names[cand_ix[k]][1], None)
-            # one hop each; an absorbed node may itself be a prior
-            # replacement, so the chains are followed once, at the end
+            # one hop each: the chains are followed once, at the end
             renames[names[a]] = renames[names[b]] = names[m]
-            free += (int(slot[a]), int(slot[b]))
+            window &= ~(bit.pop(a) | bit.pop(b))
             if not host_active:
+                i, j = wid.index(a), wid.index(b)
                 del keys[j], keys[i]  # i < j, both inside the window
                 insort(keys, (0, size[m], names[m], m))
                 continue
@@ -416,18 +507,11 @@ def coalesce_new_nodes(
                 continue
             ci = int(cand_ix[merged[x][2]])
             node = SimNode(
-                instance_type=st.cand_names[ci][1],
-                provisioner=prov,
-                zone=zone,
-                capacity_type=ct,
-                price=float(price[x]),
-                allocatable={
-                    st.vocab.resources[r]: float(st.cand_alloc[ci, r])
-                    for r in range(R)
-                },
-                existing=False,
-                name=name,
-            )
+                instance_type=st.cand_names[ci][1], provisioner=prov,
+                zone=zone, capacity_type=ct, price=float(-tab[x, R]),
+                allocatable={st.vocab.resources[r]: float(st.cand_alloc[ci, r])
+                             for r in range(R)},
+                existing=False, name=name)
             node.stamp_labels()
             node.pods = pods_of(x)
             out.append(node)
@@ -436,4 +520,6 @@ def coalesce_new_nodes(
     # backwards finds each target already resolved
     for old in reversed(renames):
         renames[old] = renames.get(renames[old], renames[old])
-    return out, renames, len(buckets)
+    done = Coalesced((out, renames, len(buckets)))
+    done.pairs = pairs
+    return done

@@ -327,7 +327,8 @@ def solve_tensors_native(
 
     assignments: Dict[str, str] = {}
     infeasible_map: Dict[str, str] = {}
-    node_groups: Dict[int, set] = {}
+    # id(node) -> {group: pods of it on the node}, off the take matrix
+    node_groups: Dict[int, Dict[int, int]] = {}
     for gi, g in enumerate(st.groups):
         gp = g.pods
         base = 0
@@ -337,7 +338,7 @@ def solve_tensors_native(
             base += len(chunk)
             node = slot_to_node.get(int(s))
             if node is not None:
-                node_groups.setdefault(id(node), set()).add(gi)
+                node_groups.setdefault(id(node), {})[gi] = take
                 node.pods.extend(chunk)
                 nn = node.name
                 for pod in chunk:
@@ -354,15 +355,14 @@ def solve_tensors_native(
     # bench config 1)
     from .coalesce import apply_coalesce
 
-    used_rows = {}
-    for s, node in slot_to_node.items():
-        if s >= NE:  # slots >= NE are exactly the new nodes
-            ci = int(slot_cand[s])
-            used_rows[id(node)] = (
-                np.asarray(st.cand_alloc[ci], dtype=np.float64)
-                - np.asarray(slot_res[s], dtype=np.float64)
-            )
-    nodes = apply_coalesce(st, nodes, used_rows, node_groups, assignments)
+    # slots >= NE are exactly the new nodes: what each has in use is what its
+    # type allocates less what the solve left of it
+    slots = [s for s in slot_to_node if s >= NE]
+    used = (np.asarray(st.cand_alloc, dtype=np.float64)[slot_cand[slots]]
+            - slot_res[slots].astype(np.float64))
+    used_rows = {id(slot_to_node[s]): row for s, row in zip(slots, used)}
+    nodes, _pairs = apply_coalesce(st, nodes, used_rows, node_groups,
+                                   assignments)
 
     return SolveResult(
         nodes=nodes,

@@ -2415,7 +2415,8 @@ class TpuSolver:
 
         assignments: Dict[str, str] = {}
         infeasible_map: Dict[str, str] = {}
-        node_groups: Optional[Dict[int, set]] = None
+        # id(node) -> {group: pods of it on the node}, off the take matrix
+        node_groups: Optional[Dict[int, Dict[int, int]]] = None
         if ys is not None:
             takes = np.asarray(ys)  # [G, NR]
             node_groups = {}
@@ -2425,7 +2426,8 @@ class TpuSolver:
                 for si in placed_slots:
                     node = slot_to_node.get(int(si))
                     if node is not None:
-                        node_groups.setdefault(id(node), set()).add(gi)
+                        node_groups.setdefault(id(node), {})[gi] = int(
+                            takes[gi, si])
                     for _ in range(int(takes[gi, si])):
                         try:
                             pod = next(pod_iter)
@@ -2449,21 +2451,20 @@ class TpuSolver:
         # node count is operational load even when the $ match)
         from .coalesce import apply_coalesce
 
-        used_rows = {}
-        for si, node in slot_to_node.items():
-            if si >= NE:  # slots >= NE are exactly the new_nodes entries
-                ci = int(row_cand[si])
-                used_rows[id(node)] = (
-                    np.asarray(st.cand_alloc[ci], dtype=np.float64)
-                    - np.asarray(res[si], dtype=np.float64)
-                )
+        # slots >= NE are exactly the new_nodes entries: what each has in
+        # use is what its type allocates less what the scan left of it
+        slots = [si for si in slot_to_node if si >= NE]
+        used = (np.asarray(st.cand_alloc, dtype=np.float64)[row_cand[slots]]
+                - np.asarray(res, dtype=np.float64)[slots])
+        used_rows = {id(slot_to_node[si]): row for si, row in zip(slots, used)}
         n_in = len(new_nodes)
         with trace.span("coalesce") as span:
-            new_nodes = apply_coalesce(st, new_nodes, used_rows, node_groups,
-                                       assignments, span)
+            new_nodes, pairs = apply_coalesce(
+                st, new_nodes, used_rows, node_groups, assignments, span)
         counter = self.registry.counter(COALESCE)
         counter.inc({"what": "nodes_in"}, value=float(n_in))
         counter.inc({"what": "merges"}, value=float(n_in - len(new_nodes)))
+        counter.inc({"what": "pairs"}, value=float(pairs))
 
         result = SolveResult(
             nodes=new_nodes,

@@ -11,15 +11,20 @@ them and the nodes that come out have to be those of the old loop
     batch (hostname anti-affinity, two provisioners: the capped order), a
     provisioner with limits (the capacity term), an untracked solve, buckets
     of one node, and both orders again through a window of 8 nodes, where
-    nodes slide in and out of it at every merge;
+    nodes slide in and out of it at every merge; a capped bucket built by
+    hand in which a node is pushed out of the window and let back in among
+    nodes that entered meanwhile, and a bucket where nothing merges (PR 36:
+    the pass computes a pair's verdict when the walk reaches it);
 (b) the ``coalesce`` span and ``karpenter_solver_coalesce_total``, and the
-    benchmark's two metric files read over real scrapes.
+    benchmark's metric files read over real scrapes;
+(c) the two callers of ``apply_coalesce`` hand it the same kind of input.
 """
 
 import copy
 import json
 import os
 
+import numpy as np
 import pytest
 
 import coalesce_reference
@@ -27,12 +32,23 @@ from coalesce_reference import reference_coalesce, twin
 from karpenter_tpu.metrics import COALESCE, COALESCE_WHAT, Registry
 from karpenter_tpu.models.provisioner import Provisioner
 from karpenter_tpu.obs.trace import Tracer
-from karpenter_tpu.solver import coalesce, types
+from karpenter_tpu.solver import coalesce, native, types
 from karpenter_tpu.solver.scheduler import BatchScheduler
 from test_longtail_config import BENCH, ROOT, _load, harness  # noqa: F401
 
-#: this PR's per-layer metrics
+#: PR 28's per-layer metrics
 NEW_METRICS = ("coalesce_ms", "coalesce_merges")
+#: PR 36's: the pair verdicts the pass computed
+PAIRS = "coalesce_pairs"
+
+
+def _twin(st, nodes, used_rows, node_groups):
+    """``twin`` for the pass under test: the reference reads which groups a
+    node holds, the pass how many pods of each (``twin`` keeps the groups)."""
+    st, twins, rows, _groups = twin(st, nodes, used_rows, node_groups)
+    return (st, twins, rows, None if node_groups is None else {
+        id(t): dict(node_groups[id(n)])
+        for n, t in zip(nodes, twins) if id(n) in node_groups})
 
 
 def _solve_and_capture(monkeypatch_ctx, pods, provisioners, catalog):
@@ -42,7 +58,7 @@ def _solve_and_capture(monkeypatch_ctx, pods, provisioners, catalog):
     real = coalesce.coalesce_new_nodes
 
     def capture(st, nodes, used_rows, node_groups=None):
-        seen.append(twin(st, nodes, used_rows, node_groups))
+        seen.append(_twin(st, nodes, used_rows, node_groups))
         seen.append(real(st, nodes, used_rows, node_groups=node_groups))
         return seen[-1]
 
@@ -134,6 +150,43 @@ def selective(longtail):
                                   inputs.catalog)
 
 
+@pytest.fixture(scope="module")
+def pushed_out(c3_shaped):
+    """Five nodes of one bucket on the c3-shaped tensors, one pod each of
+    services A, B and C (hostname anti-affinity: a node holds one pod of a
+    service), sized so that through a window of THREE the capped order reads
+
+        [a1 b1 c1] a2 b2   ->  a1 + b1 = m; a2 and b2 move up a rank:
+        [a2 b2 m] c1       ->  c1 is pushed out, m enters; a2 + b2 = m2:
+        [m c1 m2]          ->  c1 is back, beside an m it was never asked about
+
+    (m, c1) fits one node and (m, m2) shares services, so a pass that asked
+    every pair of the window would merge m + c1; the rule asks a pair only
+    when the later of the two ENTERS beside the other, so it is c1 + m2."""
+    st, nodes, _rows, _groups = c3_shaped["args"]
+    like = next(n for n in nodes if n.provisioner == "default")
+    whole = np.array([like.allocatable[r] for r in st.vocab.resources])
+    picked, rows, held = [], {}, {}
+    for name, g, share in (("a1", 0, .10), ("b1", 1, .15), ("c1", 2, .30),
+                           ("a2", 0, .20), ("b2", 1, .22)):
+        node = copy.copy(like)  # its type, its zone, its price
+        node.name = f"built-{name}"
+        node.pods = [st.groups[g].pods[int(name[1]) - 1]]
+        picked.append(node)
+        rows[id(node)] = whole * share
+        held[id(node)] = {g: 1}
+    return {"args": (st, picked, rows, held)}
+
+
+@pytest.fixture(scope="module")
+def full_nodes(longtail):
+    """The long tail's nodes, every one of them as full as the largest type
+    there is: no two fit one node."""
+    st, nodes, rows, groups = longtail["args"]
+    full = np.asarray(st.cand_alloc, dtype=np.float64).max(axis=0)
+    return {"args": (st, nodes, {key: full.copy() for key in rows}, groups)}
+
+
 def _untracked(args):
     st, nodes, rows, _groups = args
     return st, nodes, rows, None
@@ -162,6 +215,9 @@ CASES = {
     "longtail-window-of-8": ("longtail", None, 8, (300, 600)),
     "c3-shaped-window-of-8": ("c3_shaped", None, 8, (20, 200)),
     "c3-shaped-window-of-2": ("c3_shaped", None, 2, (1, 600)),
+    "capped-pushed-out-and-back": ("pushed_out", None, 3, (3, 3)),
+    "capped-never-pushed-out": ("pushed_out", None, None, (3, 3)),
+    "nothing-merges": ("full_nodes", None, None, (0, 0)),
 }
 
 
@@ -186,7 +242,7 @@ def test_the_new_loop_makes_the_old_loops_merges(case, request, monkeypatch):
                                             node_groups=groups)
     drawn = types._node_next - start
     monkeypatch.setattr(types, "_node_next", start)
-    st, nodes, rows, groups = twin(*args)
+    st, nodes, rows, groups = _twin(*args)
     got, got_renames, buckets = coalesce.coalesce_new_nodes(
         st, nodes, rows, node_groups=groups)
     merges = len(nodes) - len(got)
@@ -223,6 +279,47 @@ def test_the_capped_order_had_something_to_hold(c3_shaped):
     for node in c3_shaped["res"].nodes:
         apps = [p.labels["app"] for p in node.pods]
         assert len(apps) == len(set(apps))
+
+
+def _pods_together(args, window, monkeypatch):
+    monkeypatch.setattr(coalesce, "FRAG_WINDOW", window)
+    st, nodes, rows, groups = _twin(*args)
+    got = coalesce.coalesce_new_nodes(st, nodes, rows, node_groups=groups)
+    return got, sorted(sorted(p.name for p in n.pods) for n in got[0])
+
+
+def test_a_node_let_back_in_is_not_asked_about_who_came_meanwhile(
+        pushed_out, monkeypatch):
+    """The trap of a pass that computes verdicts on demand: the window of 3
+    holds (m, c1) ahead of (c1, m2), and both can merge — a window of 64,
+    which pushes nothing out, merges m + c1.  Through the window of 3 the
+    pair was never askable, and the merge is c1 + m2."""
+    args = pushed_out["args"]
+    a1, b1, c1, a2, b2 = ([p.name for p in n.pods] for n in args[1])
+    _got, wide = _pods_together(args, 64, monkeypatch)
+    assert wide == sorted([sorted(a1 + b1 + c1), sorted(a2 + b2)])
+    got, narrow = _pods_together(args, 3, monkeypatch)
+    assert narrow == sorted([sorted(a1 + b1), sorted(c1 + a2 + b2)])
+    # (a1, b1), (a2, b2), (m, m2) and (c1, m2) at the least; never all ten
+    assert 4 <= got.pairs < 10
+
+
+def test_a_bucket_where_nothing_merges_asks_each_pair_of_its_window_once(
+        full_nodes):
+    """What the eager rule asked of such a bucket — every pair of its first
+    window, once — is what the walk comes to, and the pass hands back the
+    nodes it was given."""
+    st, nodes, rows, groups = full_nodes["args"]
+    sizes = {}
+    for n in nodes:
+        key = (n.provisioner, n.zone, n.capacity_type)
+        sizes[key] = sizes.get(key, 0) + 1
+    assert max(sizes.values()) > coalesce.FRAG_WINDOW
+    got = coalesce.coalesce_new_nodes(st, nodes, rows, node_groups=groups)
+    assert got[1] == {} and got[2] == len(sizes)
+    assert sorted(map(id, got[0])) == sorted(map(id, nodes))
+    assert got.pairs == sum(w * (w - 1) // 2 for w in (
+        min(n, coalesce.FRAG_WINDOW) for n in sizes.values()))
 
 
 def test_the_limited_bucket_took_the_capacity_term(limited):
@@ -275,10 +372,69 @@ def test_a_solve_raises_the_family_by_what_the_pass_returned(fixture, request):
     # the span sits inside `extract` and says the same
     spans = {s.name: s for s in solve["trace"].spans()}
     assert "coalesce" in [c.name for c in spans["extract"].children]
+    pairs = solve["returned"].pairs
     assert dict(spans["coalesce"].attrs) == {
         "nodes_in": nodes_in, "nodes_out": len(out),
-        "merges": nodes_in - len(out), "buckets": buckets}
+        "merges": nodes_in - len(out), "buckets": buckets, "pairs": pairs}
+    # the work count: raised by what the span says, and a pass that merges
+    # asked at least one pair a merge
+    assert counter.get({"what": "pairs"}) == pairs >= nodes_in - len(out)
     assert 0 < spans["coalesce"].duration_s <= spans["extract"].duration_s
+
+
+def test_the_long_tail_asks_a_handful_of_pairs_a_merge(longtail, c3_shaped):
+    """The work-count guard (no clock in it): the eager rule asked every pair
+    a node new to the window formed, ~126 a merge; the walk asks the pairs
+    it reaches.  Where nine pairs in ten cannot merge (the capped batch) it
+    reaches more of them, and still not the window."""
+    for solve, most in ((longtail, 16), (c3_shaped, 64)):
+        merges = len(solve["args"][1]) - len(solve["returned"][0])
+        assert merges <= solve["returned"].pairs < most * merges
+
+
+# ---- (c) one signature, both callers ---------------------------------------
+
+
+@pytest.mark.skipif(not native.available(), reason="native lib unavailable")
+def test_both_tiers_hand_the_pass_the_same_kind_of_input(c3_shaped,
+                                                         monkeypatch):
+    """``TpuSolver._extract`` and ``native.solve_tensors_native`` feed one
+    ``apply_coalesce``: on one set of tensors (hostname caps: the counts
+    matter) each hands it, per node, what the node has in use and how many
+    pods of which group it holds, and each answer keeps the caps and says
+    where every pod ended up."""
+    st = c3_shaped["args"][0]
+    seen = []
+    real = coalesce.coalesce_new_nodes
+
+    def capture(st, nodes, used_rows, node_groups=None):
+        seen.append(_twin(st, nodes, used_rows, node_groups))
+        return real(st, nodes, used_rows, node_groups=node_groups)
+
+    monkeypatch.setattr(coalesce, "coalesce_new_nodes", capture)
+    cold = native.solve_tensors_native(st)
+    assert not cold.infeasible
+    requests = np.asarray(st.requests, dtype=np.float64)
+    group_of = {p.name: g for g, grp in enumerate(st.groups) for p in grp.pods}
+    for _st, nodes, rows, groups in (seen[0], c3_shaped["args"]):
+        assert len(nodes) > 100
+        for node in nodes:
+            held = groups[id(node)]
+            assert held == {g: sum(group_of[p.name] == g for p in node.pods)
+                            for g in held}
+            assert sum(held.values()) == len(node.pods) > 0
+            np.testing.assert_allclose(
+                rows[id(node)],
+                sum(n * requests[g] for g, n in held.items()), rtol=1e-5)
+    for res in (cold, c3_shaped["res"]):
+        assert len(res.nodes) < len(seen[0][1])  # the pass merged
+        by_name = {n.name: n for n in res.nodes}
+        assert len(res.assignments) == 720
+        for pod_name, node_name in res.assignments.items():
+            assert pod_name in {p.name for p in by_name[node_name].pods}
+        for node in res.nodes:
+            apps = [p.labels["app"] for p in node.pods]
+            assert len(apps) == len(set(apps))
 
 
 @pytest.mark.parametrize("name", NEW_METRICS)
@@ -332,4 +488,46 @@ def test_the_benchmarks_metric_file_reads_a_real_scrape(harness, longtail,
     assert got > 0
     without = [s for s in after if s[0] != family]
     assert reader.read({**ctx, "before": without, "after": without},
+                       **spec["args"]) == 0.0
+
+
+# ---- PR 36: the pair verdicts the pass computed -----------------------------
+
+
+def test_coalesce_pairs_is_declared_as_its_file_says():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    with open(os.path.join(BENCH, "metrics", f"{PAIRS}.json")) as f:
+        spec = json.load(f)
+    decl = bench["per_layer"][-1]  # appended: nothing that was there moved
+    assert decl == {k: spec[k] for k in ("name", "unit", "better", "source",
+                                         "layer", "moves")}
+    assert decl == {"name": PAIRS, "unit": "pairs", "better": "lower",
+                    "source": "program_counter", "layer": "host epilogues",
+                    "moves": "solve_ms"}  # no `workloads`: every cell
+    assert [m["name"] for m in bench["per_layer"]].count(PAIRS) == 1
+    assert spec["reader"] == "counter_per_request"
+    assert spec["args"] == {"metric": COALESCE, "labels": [{"what": "pairs"}]}
+    assert "pairs" in COALESCE_WHAT
+
+
+def test_coalesce_pairs_reads_a_real_scrape(harness, longtail):
+    """Through the benchmark's own reader the file reads what the span says;
+    a program without the label (the parent, under this file) reads 0.0."""
+    scrape = harness["scrape"]
+    with open(os.path.join(BENCH, "metrics", f"{PAIRS}.json")) as f:
+        spec = json.load(f)
+    reader = _load(os.path.join(BENCH, "readers", f"{spec['reader']}.py"),
+                   f"reader_{PAIRS}")
+    after = scrape.parse_metrics(longtail["reg"].expose())
+    before = scrape.parse_metrics(longtail["zero"]["text"])
+    ctx = {"before": before, "after": after, "requests": 1}
+    span = {s.name: s for s in longtail["trace"].spans()}["coalesce"]
+    got = reader.read(ctx, **spec["args"])
+    assert got == dict(span.attrs)["pairs"] == longtail["returned"].pairs > 0
+    without = [s for s in after if s[1].get("what") != "pairs"]
+    assert len(without) == len(after) - 1  # the parent: family, no label
+    assert reader.read({**ctx, "before": without, "after": without},
+                       **spec["args"]) == 0.0
+    assert reader.read({**ctx, "before": [], "after": []},
                        **spec["args"]) == 0.0
