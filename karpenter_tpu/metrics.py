@@ -79,6 +79,15 @@ class Registry:
         self.counters: Dict[str, Counter] = {}
         self.gauges: Dict[str, Gauge] = {}
         self.histograms: Dict[str, Histogram] = {}
+        #: gauge name -> reading taken when the registry is exposed
+        self._at_scrape: Dict[str, object] = {}
+
+    def at_scrape(self, name: str, read) -> None:
+        """The unlabelled gauge ``name`` is ``read()`` at every
+        :meth:`expose`: for a reading too dear to take per request (a walk
+        over the heap) and only ever wanted by a scraper."""
+        self.gauge(name).set(0)
+        self._at_scrape[name] = read
 
     def counter(self, name: str) -> Counter:
         return self.counters.setdefault(name, Counter())
@@ -104,6 +113,8 @@ class Registry:
         ``+Inf``), ``_sum`` and ``_count`` — so quantile queries
         (``histogram_quantile``) work against the scrape, not just counts."""
         lines: List[str] = []
+        for name, read in self._at_scrape.items():
+            self.gauge(name).set(read())
 
         def header(name: str, kind: str) -> None:
             inv = INVENTORY.get(name)
@@ -178,6 +189,14 @@ TRACE_SPAN_SELF = "karpenter_trace_span_self_seconds_total"
 GC_PAUSE_SECONDS = "karpenter_process_gc_pause_seconds_total"
 #: the collector's generations (KT003 zero-init source)
 GC_GENERATIONS = ("0", "1", "2")
+GC_SPAN_PAUSE_SECONDS = "karpenter_trace_span_gc_pause_seconds_total"
+#: the spans whose share of the collector a dashboard or a benchmark metric
+#: selects by name, and ``none`` (KT003 zero-init source; any other span a
+#: pause lands in appears with its first pause)
+GC_SPANS_ZEROED = ("none", "await_request", "request_parse", "request_decode",
+                   "response_serialize", "extract", "readback", "nodes",
+                   "assign", "coalesce", "reseat", "relax", "gang")
+ALLOCATED_BLOCKS = "karpenter_process_allocated_blocks"
 # ---- the sidecar's door: pods decoded by template (service/codec.py) ----
 REQUEST_DECODE_PODS = "karpenter_solver_request_decode_pods_total"
 #: how a pod of a request became a PodSpec (KT003 zero-init source):
@@ -585,6 +604,24 @@ INVENTORY = {
         "enabled tracer (KT_TRACE=0: never registered, the family stays "
         "absent).  Generation-2 pauses are also mirrored onto the "
         "profiler's host plane as gc_gen2."),
+    GC_SPAN_PAUSE_SECONDS: (
+        "counter", ("span",),
+        "Seconds this process spent inside the Python collector, by the "
+        "innermost trace span or door phase open on the thread the "
+        "collection ran on ('none' outside any; the root counts as a span "
+        "on the thread that opened it): which layer's time the collector "
+        "is hiding in.  Written when a trace finishes, so between two "
+        "such moments its sum over span lags "
+        "karpenter_process_gc_pause_seconds_total, which it otherwise "
+        "equals.  KT_TRACE=0: absent."),
+    ALLOCATED_BLOCKS: (
+        "gauge", (),
+        "sys.getallocatedblocks() of this process, read when /metrics is "
+        "scraped (it walks the heap's pools: milliseconds on a heap of "
+        "gigabytes, so not per request).  A sidecar that keeps something "
+        "of every request (a leak) shows it rising scrape after scrape "
+        "under steady traffic; one that does not shows it level once "
+        "warm.  Registered by an enabled tracer: KT_TRACE=0, absent."),
     REQUEST_DECODE_PODS: (
         "counter", ("how",),
         "Pods the sidecar decoded off Solve requests (pending pods, "

@@ -17,9 +17,10 @@ Design constraints, in order:
   it is a constant no-op, so the hot path pays one attribute check.
 - **Thread-crossing solves.**  A pipelined solve opens its root on the RPC
   thread, its dispatch/fence spans on the dispatcher thread, and may fence
-  on the hang guard's expendable thread.  Nesting is tracked with a
-  per-thread open-span stack: a span opened on a thread with no open parent
-  attaches to the root.  Already-elapsed cross-thread phases (the pipeline
+  on the hang guard's expendable thread.  Nesting is tracked with one
+  per-thread stack of open spans and phases, every trace's: a span's parent
+  is the innermost open span of ITS trace on the thread, the root when
+  there is none.  Already-elapsed cross-thread phases (the pipeline
   queue wait) are attached with :meth:`Trace.record`, which never leaves a
   span open.
 - **Lock discipline.**  The span tree is mutated from multiple threads and
@@ -45,7 +46,13 @@ Design constraints, in order:
   one ``gc.callbacks`` entry; pauses are counted into
   ``karpenter_process_gc_pause_seconds_total{generation}`` of every
   registry that has an enabled tracer, and generation-2 pauses are mirrored
-  as ``gc_gen2``.  No span per collection.
+  as ``gc_gen2``.  No span per collection.  Each pause is also put down to
+  the innermost span or phase open on the thread it ran on
+  (``karpenter_trace_span_gc_pause_seconds_total{span}``, ``none`` outside
+  any): the callback reads this thread's stack of open spans and adds to a
+  plain dict, which reaches the registries when a trace finishes.  An
+  enabled tracer also registers ``karpenter_process_allocated_blocks``
+  (what the heap holds), read when the registry is scraped.
 - **Context-manager lifecycle (KT007).**  ``with tracer.start(...) as
   trace:`` / ``with trace.span(...):`` are the only blessed forms — a bare
   ``Tracer.start()`` leaks an open trace on any exception path, and ktlint
@@ -65,8 +72,11 @@ import weakref
 from typing import Dict, List, Optional, Tuple
 
 from ..metrics import (
+    ALLOCATED_BLOCKS,
     GC_GENERATIONS,
     GC_PAUSE_SECONDS,
+    GC_SPAN_PAUSE_SECONDS,
+    GC_SPANS_ZEROED,
     TRACE_REMOTE_OUTCOMES,
     TRACE_REMOTE_SPANS,
     TRACE_SPAN_DURATION,
@@ -104,9 +114,44 @@ def _end(ann) -> None:
         ann.__exit__(None, None, None)
 
 
+#: the spans and phases open on this thread, innermost last, whatever trace
+#: each belongs to: a span's parent is found here (Trace.span) and the
+#: collector's callback reads the innermost one.  An entry that ended on
+#: another thread than it began on (the door's ``await_request``) stays
+#: until the next push prunes it; readers skip what has ended (``t1`` set)
+_HERE = threading.local()
+
+
+def _open_here() -> list:
+    st = getattr(_HERE, "open", None)
+    if st is None:
+        st = _HERE.open = []
+    return st
+
+
+def _entered(what) -> None:
+    """``what`` (a span or a phase) is open on this thread from now on."""
+    st = _open_here()
+    while st and st[-1].t1 is not None:
+        st.pop()
+    st.append(what)
+
+
+def _left(what) -> None:
+    """``what`` is no longer open on this thread.  Two traces' spans may
+    interleave on one thread (the dispatcher's), so only ``what`` goes."""
+    st = _open_here()
+    if st and st[-1] is what:
+        st.pop()
+    elif what in st:
+        st.remove(what)
+
+
 class _GcWatch:
     """The process's one ``gc.callbacks`` entry.  Pause seconds go to every
-    registry added (weakly held: a test's private registry dies with it)."""
+    registry added (weakly held: a test's private registry dies with it):
+    by generation at once, by the span they stopped at the next
+    :meth:`flush` (a trace's finish)."""
 
     def __init__(self) -> None:
         self._registries: "weakref.WeakSet[Registry]" = weakref.WeakSet()
@@ -114,15 +159,44 @@ class _GcWatch:
         self._t0 = 0.0
         self._ann = None
         self._labels = [{"generation": g} for g in GC_GENERATIONS]
+        #: span -> pause seconds since the process began; the callback is
+        #: its only writer, under no lock (collections do not nest)
+        self._by_span: Dict[str, float] = {}
+        self._flushed: Dict[str, float] = {}  # guarded-by: _lock
 
     def add(self, registry: Registry) -> None:
         counter = registry.counter(GC_PAUSE_SECONDS)
         for labels in self._labels:
             counter.inc(labels, value=0.0)
+        by_span = registry.counter(GC_SPAN_PAUSE_SECONDS)
+        for span in GC_SPANS_ZEROED:
+            by_span.inc({"span": span}, value=0.0)
+        # what was paused before belongs to the registries of before
+        self.flush()
         with self._lock:
             self._registries.add(registry)
             if self._callback not in gc.callbacks:
                 gc.callbacks.append(self._callback)
+
+    def flush(self) -> None:
+        """Hand the registries what the callback has put down to each span
+        since the last flush.  Totals are kept and their rise handed on, so
+        a pause that lands while this runs is in the next one."""
+        with self._lock:
+            # copy(): one allocation before the copying starts, so a
+            # collection (and with it the callback) cannot land in the
+            # middle of the walk
+            for span, total in self._by_span.copy().items():
+                moved = total - self._flushed.get(span, 0.0)
+                if moved <= 0.0:
+                    continue
+                self._flushed[span] = total
+                labels = {"span": span}
+                for registry in list(self._registries):
+                    # span names are runtime data: add() zero-initialises
+                    # the ones a metric selects by name
+                    registry.counter(GC_SPAN_PAUSE_SECONDS).inc(
+                        labels, value=moved)
 
     def _callback(self, phase: str, info: dict) -> None:
         # collections do not nest and a start/stop pair runs on one thread
@@ -138,6 +212,12 @@ class _GcWatch:
         labels = self._labels[min(gen, len(self._labels) - 1)]
         for registry in list(self._registries):
             registry.counter(GC_PAUSE_SECONDS).inc(labels, value=pause)
+        span = "none"
+        for entry in reversed(getattr(_HERE, "open", ())):
+            if entry.t1 is None:
+                span = entry.name
+                break
+        self._by_span[span] = self._by_span.get(span, 0.0) + pause
 
 
 _GC_WATCH = _GcWatch()
@@ -306,7 +386,8 @@ class _Phase:
 
     def __init__(self, tracer: "Tracer", name: str, detached: bool) -> None:
         self.name = name
-        self.t0 = self.t1 = 0.0
+        self.t0 = 0.0
+        self.t1: Optional[float] = None  # while it is open, like a span's
         self._tracer = tracer
         self._detached = detached
         self._ann = None
@@ -319,11 +400,13 @@ class _Phase:
 
     def __enter__(self) -> "_Phase":
         self._ann = _annotate(self.name)
+        _entered(self)
         self.t0 = self._tracer.clock.now()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.t1 = self._tracer.clock.now()
+        _left(self)
         _end(self._ann)
         self._ann = None
         if self._detached:
@@ -372,7 +455,6 @@ class Trace:
         self._n_spans = 1           # guarded-by: _lock
         self._n_dropped = 0         # guarded-by: _lock
         self.root = Span(self, name, self._clock.now(), attrs, span_id="s1")
-        self._open = threading.local()  # per-thread open-span stack
 
     # ---- time -----------------------------------------------------------
     def now(self) -> float:
@@ -385,17 +467,18 @@ class Trace:
         return self.root.duration_s
 
     # ---- span lifecycle -------------------------------------------------
-    def _stack(self) -> list:
-        st = getattr(self._open, "stack", None)
-        if st is None:
-            st = self._open.stack = []
-        return st
+    def _innermost(self) -> Span:
+        """This thread's innermost open span of this trace (the root when
+        it has none)."""
+        for entry in reversed(_open_here()):
+            if getattr(entry, "_trace", None) is self and entry.t1 is None:
+                return entry
+        return self.root
 
     def span(self, name: str, **attrs):
         """Open a child span under this thread's innermost open span (the
         root when none).  Use as ``with trace.span("tensorize") as sp:``."""
-        stack = self._stack()
-        parent = stack[-1] if stack else self.root
+        parent = self._innermost()
         with self._lock:
             if self._n_spans >= MAX_SPANS_PER_TRACE:
                 self._n_dropped += 1
@@ -405,7 +488,7 @@ class Trace:
             sp = Span(self, name, self._clock.now(), attrs,
                       span_id=f"s{self._n_spans}")
             parent.children.append(sp)
-        stack.append(sp)
+        _entered(sp)
         sp._ann = _annotate(name)
         return sp
 
@@ -431,9 +514,7 @@ class Trace:
         with self._lock:
             if span.t1 is None:
                 span.t1 = self._clock.now()
-        stack = self._stack()
-        if stack and stack[-1] is span:
-            stack.pop()
+        _left(span)
 
     def _annotate_span(self, span: Span, attrs: dict) -> None:
         with self._lock:
@@ -450,9 +531,7 @@ class Trace:
         remote side opens its child trace under this thread's innermost
         OPEN span (the root when none), so the hop lands exactly where
         the RPC happened in the tree."""
-        stack = self._stack()
-        return (self.trace_id,
-                stack[-1].span_id if stack else self.root.span_id)
+        return (self.trace_id, self._innermost().span_id)
 
     # ---- completion / introspection -------------------------------------
     def finish(self) -> "Trace":
@@ -507,11 +586,17 @@ class Trace:
             return {"trace_id": self.trace_id, **self.root._to_dict_locked()}
 
     def __enter__(self) -> "Trace":
+        # the root is open on the thread that runs the ``with``
+        _entered(self.root)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         if exc is not None:
             self.annotate(error=repr(exc))
+        # the root goes, and with it whatever of this trace was opened on
+        # this thread and never closed
+        here = _open_here()
+        here[:] = [e for e in here if getattr(e, "_trace", None) is not self]
         self._tracer._finish(self)
         return False
 
@@ -566,6 +651,9 @@ class Tracer:
         self.registry.counter(TRACE_SPAN_SELF).inc(value=0.0)
         if self.enabled:
             _GC_WATCH.add(self.registry)
+            # a walk over the heap's pools (4 ms at 24M blocks): read when
+            # a scraper asks, never on a request's path
+            self.registry.at_scrape(ALLOCATED_BLOCKS, sys.getallocatedblocks)
 
     def phase(self, name: str, detached: bool = False):
         """Time a phase that no open span can hold — the RPC's door, before
@@ -648,6 +736,7 @@ class Tracer:
         self.registry.counter(TRACE_TRACES).inc()
         for name, duration_s, self_s in trace.closed_spans():
             self._observe(name, duration_s, self_s)
+        _GC_WATCH.flush()
         for sink in self._sinks:
             try:
                 sink(trace)

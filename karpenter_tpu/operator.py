@@ -477,6 +477,7 @@ class Operator:
     def stop_http(self) -> None:
         if self._http:
             self._http.shutdown()
+            self._http.server_close()  # the listening socket, not at some later collection
             self._http = None
 
     # ---- loop -----------------------------------------------------------

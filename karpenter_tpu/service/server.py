@@ -630,7 +630,9 @@ class SolvePipeline:
             return None
         # the probe hardens, routes and TENSORIZES the batch (the real
         # solve's tensorize is then a cache hit): for a fresh 50k-pod batch
-        # it is the host build itself, so it gets a span of its own
+        # it is the host build itself.  Each of those stretches is a span
+        # of bucket_key's own under this one (harden, carve, tensorize,
+        # signature), which keeps next to nothing for itself
         trace = kwargs.get("trace") or NULL_TRACE
         # the probe itself never fails a request (bucket_key boxes its own
         # errors and returns None), but a facade without that contract must
@@ -1412,6 +1414,54 @@ class KeptCatalogs:
         return len(self._by_digest)
 
 
+class _Door:
+    """How many requests the sidecar's handlers hold, and the stretches in
+    which it holds none, each timed as the detached phase
+    ``await_request``: from the moment the last ``response_serialize``
+    closed with nothing in hand to the next ``request_parse``.  It is the
+    sidecar's own account of the client's turn (its codec, both
+    transports, whatever the caller does between two requests); with
+    several clients it measures "no request in the sidecar", not a sum per
+    client.  A request is counted where its handler enters and leaves, so
+    one that gRPC parses and never hands to a handler (cancelled in
+    between) ends the wait it arrived in and leaves no count behind: that
+    one stretch goes unrecorded and the next answered request opens the
+    wait again.  A disabled tracer hands out ``NULL_PHASE`` and nothing is
+    recorded."""
+
+    def __init__(self, tracer: Tracer) -> None:
+        self._tracer = tracer
+        self._lock = threading.Lock()
+        self._held = 0      # guarded-by: _lock
+        self._idle = None   # guarded-by: _lock — the open await_request
+
+    def arrive(self) -> None:
+        """Bytes of a request are here: the wait for them, if one is open,
+        ends (on gRPC's parsing thread, not the one it began on)."""
+        if not self._tracer.enabled:
+            return
+        with self._lock:
+            idle, self._idle = self._idle, None
+        if idle is not None:
+            idle.__exit__(None, None, None)
+
+    def enter(self) -> None:
+        """A handler took a request."""
+        with self._lock:
+            self._held += 1
+
+    def leave(self) -> None:
+        """A request is out of the sidecar (answered or failed); with none
+        left in hand the wait begins."""
+        if not self._tracer.enabled:
+            return
+        with self._lock:
+            self._held = max(0, self._held - 1)
+            if self._held == 0 and self._idle is None:
+                self._idle = self._tracer.phase(
+                    "await_request", detached=True).__enter__()
+
+
 class SolverService:
     def __init__(self, scheduler: Optional[BatchScheduler] = None,
                  registry: Optional[Registry] = None,
@@ -1432,6 +1482,7 @@ class SolverService:
         #: request_parse timestamps on their way from gRPC's deserialiser
         #: to the handler, by id(request) (parse_request)
         self._parse_times: dict = {}
+        self._door = _Door(self.tracer)
         for how in REQUEST_DECODE_HOW:
             self.registry.counter(REQUEST_DECODE_PODS).inc(
                 {"how": how}, value=0)
@@ -1619,6 +1670,7 @@ class SolverService:
         queue thread, not on the pool thread that then runs the handler,
         so the timestamps cross to :meth:`Solve` by the request object's
         id (the object lives from here to the handler's return)."""
+        self._door.arrive()
         with self.tracer.phase("request_parse") as ph:
             request = pb.SolveRequest.FromString(data)
         if ph is not NULL_PHASE:
@@ -1632,12 +1684,32 @@ class SolverService:
         """Solve's ``response_serializer``, as the detached
         ``response_serialize`` phase: gRPC calls it on the handler's pool
         thread after the handler has returned and the trace has finished."""
-        with self.tracer.phase("response_serialize", detached=True) as ph:
-            data = resp.SerializeToString()
-            ph.annotate(bytes=len(data))
+        try:
+            with self.tracer.phase("response_serialize",
+                                   detached=True) as ph:
+                data = resp.SerializeToString()
+                ph.annotate(bytes=len(data))
+        finally:
+            self._door.leave()
         return data
 
     def Solve(self, request: pb.SolveRequest, context) -> pb.SolveResponse:
+        # what gRPC's deserialiser timed for this request; None for a direct
+        # caller's, which never came through the door
+        parsed = self._parse_times.pop(id(request), None)
+        if parsed is None:
+            return self._solve(request, context, parsed)
+        # through the door: in hand from here to response_serialize
+        self._door.enter()
+        try:
+            return self._solve(request, context, parsed)
+        except BaseException:
+            # no response_serialize will end this request's stay
+            self._door.leave()
+            raise
+
+    def _solve(self, request: pb.SolveRequest, context,
+               parsed: Optional[tuple]) -> pb.SolveResponse:
         t_door = self.tracer.clock.now()
         # the door (docs/OBSERVABILITY.md): decoding the request runs
         # before the root span can open (the root adopts the wire trace
@@ -1728,7 +1800,6 @@ class SolverService:
                    if gangmod.gang_enabled()
                    and gangmod.has_gangs(kwargs.get("pods", ())) else {}),
             ) as trace:
-                parsed = self._parse_times.pop(id(request), None)
                 if parsed is not None:
                     trace.record("request_parse", *parsed)
                 trace.record("request_decode", door.t0, door.t1,

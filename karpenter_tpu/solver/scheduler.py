@@ -857,7 +857,13 @@ class BatchScheduler:
         megabatch round — the key carries the mesh signature, so requests
         against different meshes can never coalesce.
         Pipeline-dispatcher-only, like submit: the tensorize it performs
-        lands in the cache, so the real solve's tensorize is a hit."""
+        lands in the cache, so the real solve's tensorize is a hit.
+        With the request's ``trace`` in ``kwargs`` each stretch of the
+        probe is a span under the caller's ``bucket``, ``where="probe"`` on
+        each: ``harden`` and ``carve`` (the passes over the batch that
+        ``_submit`` and ``_solve_tpu`` make again), ``tensorize`` (the
+        fresh batch's build: the ``miss`` of the request) and
+        ``signature``."""
         if self.backend not in ("auto", "tpu"):
             return None
         if self.mega_unshardable:
@@ -871,31 +877,36 @@ class BatchScheduler:
             return None
         if self._route_small(len(pods)):
             return None
+        trace = kwargs.get("trace") or NULL_TRACE
         try:
-            hardened = [_harden_preferences(p) for p in pods]
-            if batch_needs_oracle(hardened):
+            with trace.span("harden", where="probe"):
+                hardened = [_harden_preferences(p) for p in pods]
+            with trace.span("carve", where="probe"):
+                serial = (batch_needs_oracle(hardened)
+                          # oracle carve-outs couple waves; keep serial
+                          or any(device_inexpressible(p) for p in hardened))
+            if serial:
                 return None
-            if any(device_inexpressible(p) for p in hardened):
-                return None  # oracle carve-outs couple waves; keep serial
             if (self.backend == "auto" and self._guard.enabled
                     and not self._guard.healthy):
                 return None
             tpu_pods = hardened
-            st, _tier = self._tensorize_cache.tensorize(
+            st, _dt = self._tensorize(
                 tpu_pods, kwargs["provisioners"], kwargs["instance_types"],
-                daemonsets=kwargs.get("daemonsets") or (),
-                unavailable=kwargs.get("unavailable"),
+                kwargs.get("daemonsets") or (), kwargs.get("unavailable"),
+                trace=trace, where="probe",
             )
             existing = list(kwargs.get("existing_nodes") or ())
             max_new = kwargs.get("max_new_nodes")
             new_budget = len(tpu_pods) if max_new is None else max_new
             max_slots = len(existing) + new_budget
-            if not self._device_ready(st, existing, max_slots):
-                return None  # cold shapes keep the compile-behind path
-            return self._tpu.mega_signature(
-                st, existing_nodes=existing, max_nodes=max_slots, slots=1,
-                mesh=self.mesh,
-            )
+            with trace.span("signature", where="probe"):
+                if not self._device_ready(st, existing, max_slots):
+                    return None  # cold shapes keep the compile-behind path
+                return self._tpu.mega_signature(
+                    st, existing_nodes=existing, max_nodes=max_slots,
+                    slots=1, mesh=self.mesh,
+                )
         # ktlint: allow[KT005] the bucket probe must never fail a request —
         # an unbucketable request just solves on the classic single path,
         # where a real error surfaces with full context
@@ -1450,23 +1461,27 @@ class BatchScheduler:
         # cap the ladder depth like the reference caps its long axes
         # (SURVEY §5 long-context analog: 60-type truncation, batching):
         # a pod with absurdly many preferences drops straight to its last
-        # MAX_RELAXATION_WAVES instead of funding one solve per preference
-        max_pref = min(
-            max((_n_preferences(p) for p in pods), default=0),
-            MAX_RELAXATION_WAVES,
-        )
-        for keep in range(max_pref - 1, -1, -1):
-            retry = [p for p in pods if p.name in result.infeasible
-                     and _n_preferences(p) > keep]
-            if not retry:
-                continue
-            _merge(result, self._solve_once(
-                [_harden_preferences(p, keep) for p in retry],
-                provisioners, instance_types,
-                list(result.existing_nodes) + result.nodes, daemonsets,
-                unavailable, allow_new_nodes,
-                _budget_left(result, max_new_nodes), trace=trace,
-            ))
+        # MAX_RELAXATION_WAVES instead of funding one solve per preference.
+        # Finding the depth is a pass over the batch even when no pod has a
+        # preference: the `ladder` span holds it and the rungs it funds
+        with (trace or NULL_TRACE).span("ladder") as span:
+            max_pref = min(
+                max((_n_preferences(p) for p in pods), default=0),
+                MAX_RELAXATION_WAVES,
+            )
+            span.annotate(depth=max_pref)
+            for keep in range(max_pref - 1, -1, -1):
+                retry = [p for p in pods if p.name in result.infeasible
+                         and _n_preferences(p) > keep]
+                if not retry:
+                    continue
+                _merge(result, self._solve_once(
+                    [_harden_preferences(p, keep) for p in retry],
+                    provisioners, instance_types,
+                    list(result.existing_nodes) + result.nodes, daemonsets,
+                    unavailable, allow_new_nodes,
+                    _budget_left(result, max_new_nodes), trace=trace,
+                ))
         return result
 
     def _solve_once(
@@ -1476,9 +1491,13 @@ class BatchScheduler:
     ):
         # a hard capacity-type spread couples the whole batch to the
         # sequential engine (batch_needs_oracle) — exact interleaved
-        # semantics, every backend
-        if (self.backend == "oracle" or self._route_small(len(pods))
-                or batch_needs_oracle(pods)):
+        # semantics, every backend.  Asking is a pass over the batch, the
+        # same routing question `_solve_tpu` goes on with: one name
+        host = self.backend == "oracle" or self._route_small(len(pods))
+        if not host:
+            with (trace or NULL_TRACE).span("carve"):
+                host = batch_needs_oracle(pods)
+        if host:
             t0 = time.perf_counter()
             try:
                 return oracle_solve(
@@ -1806,12 +1825,16 @@ class BatchScheduler:
         return self.backend == "native"
 
     def _tensorize(self, pods, provisioners, instance_types, daemonsets,
-                   unavailable, trace=NULL_TRACE) -> Tuple["object", float]:
+                   unavailable, trace=NULL_TRACE,
+                   **attrs) -> Tuple["object", float]:
         """Host tensorize through the incremental cache (steady-state: a
         lookup plus a counts vector — models/tensorize.TensorizeCache).
+        The bucket probe's build goes through here like the solve's hit,
+        so the span, the histogram and the hit / miss counters see a
+        request's tensors where they are built.  ``attrs`` go on the span.
         Returns (tensors, seconds spent)."""
         t0 = time.perf_counter()
-        with trace.span("tensorize") as span:
+        with trace.span("tensorize", **attrs) as span:
             st, tier = self._tensorize_cache.tensorize(
                 pods, provisioners, instance_types,
                 daemonsets=daemonsets, unavailable=unavailable,

@@ -2377,73 +2377,82 @@ class TpuSolver:
         self, st, carry, ys, existing_nodes, NE, solve_ms, compile_ms,
         trace=NULL_TRACE,
     ) -> TpuSolveOutput:
-        (res, row_zone, row_dom, row_cand, row_price, selcnt, active,
-         n_used, zc, tot, prov_used, infeasible) = [np.asarray(x) for x in carry]
-        n_used = int(n_used)
+        # four leaves: the carry and the take matrix come back whole
+        # (``readback``: 41 MB of takes for 1,640 groups on 4,608 slots), then
+        # what the host makes of them (``nodes``, ``assign``, ``coalesce``)
+        with trace.span("readback"):
+            (res, row_zone, row_dom, row_cand, row_price, selcnt, active,
+             n_used, zc, tot, prov_used, infeasible) = [
+                np.asarray(x) for x in carry]
+            n_used = int(n_used)
+            takes = None if ys is None else np.asarray(ys)  # [G, NR]
 
         new_nodes: List[SimNode] = []
         slot_to_node: Dict[int, SimNode] = {}
-        NE_pad = max(1, NE)
-        for si in range(NE, n_used):
-            ci = int(row_cand[si])
-            if ci < 0 or not active[si]:
-                continue
-            prov_name, type_name = st.cand_names[ci]
-            zone = st.zone_names[int(row_zone[si])] if st.zone_names else ""
-            node = SimNode(
-                instance_type=type_name,
-                provisioner=prov_name,
-                zone=zone,
-                capacity_type=self._ct_of_dom(st, int(row_dom[si])),
-                price=float(row_price[si]),
-                allocatable={
-                    st.vocab.resources[r]: float(st.cand_alloc[ci, r])
-                    for r in range(st.cand_alloc.shape[1])
-                },
-                existing=False,
-            )
-            node.stamp_labels()
-            new_nodes.append(node)
-            slot_to_node[si] = node
+        with trace.span("nodes"):
+            for si in range(NE, n_used):
+                ci = int(row_cand[si])
+                if ci < 0 or not active[si]:
+                    continue
+                prov_name, type_name = st.cand_names[ci]
+                zone = (st.zone_names[int(row_zone[si])]
+                        if st.zone_names else "")
+                node = SimNode(
+                    instance_type=type_name,
+                    provisioner=prov_name,
+                    zone=zone,
+                    capacity_type=self._ct_of_dom(st, int(row_dom[si])),
+                    price=float(row_price[si]),
+                    allocatable={
+                        st.vocab.resources[r]: float(st.cand_alloc[ci, r])
+                        for r in range(st.cand_alloc.shape[1])
+                    },
+                    existing=False,
+                )
+                node.stamp_labels()
+                new_nodes.append(node)
+                slot_to_node[si] = node
 
-        # snapshots: placements must not leak into the caller's node objects;
-        # the placed snapshots are returned (existing_nodes) so retry waves
-        # can chain on them without double-booking capacity
-        snap_existing = [n.snapshot() for n in existing_nodes]
-        for ni, node in enumerate(snap_existing):
-            slot_to_node[ni] = node
+            # snapshots: placements must not leak into the caller's node
+            # objects; the placed snapshots are returned (existing_nodes) so
+            # retry waves can chain on them without double-booking capacity
+            snap_existing = [n.snapshot() for n in existing_nodes]
+            for ni, node in enumerate(snap_existing):
+                slot_to_node[ni] = node
 
         assignments: Dict[str, str] = {}
         infeasible_map: Dict[str, str] = {}
         # id(node) -> {group: pods of it on the node}, off the take matrix
         node_groups: Optional[Dict[int, Dict[int, int]]] = None
-        if ys is not None:
-            takes = np.asarray(ys)  # [G, NR]
-            node_groups = {}
-            for gi, g in enumerate(st.groups):
-                placed_slots = np.nonzero(takes[gi])[0]
-                pod_iter = iter(g.pods)
-                for si in placed_slots:
-                    node = slot_to_node.get(int(si))
-                    if node is not None:
-                        node_groups.setdefault(id(node), {})[gi] = int(
-                            takes[gi, si])
-                    for _ in range(int(takes[gi, si])):
-                        try:
-                            pod = next(pod_iter)
-                        except StopIteration:
-                            break
-                        assignments[pod.name] = node.name if node else f"slot-{si}"
+        with trace.span("assign"):
+            if takes is not None:
+                node_groups = {}
+                for gi, g in enumerate(st.groups):
+                    placed_slots = np.nonzero(takes[gi])[0]
+                    pod_iter = iter(g.pods)
+                    for si in placed_slots:
+                        node = slot_to_node.get(int(si))
                         if node is not None:
-                            node.pods.append(pod)
-                for pod in pod_iter:
-                    infeasible_map[pod.name] = "solver: no feasible placement"
-        else:
-            takes = None
-            for gi, g in enumerate(st.groups):
-                k = int(infeasible[gi])
-                for pod in g.pods[len(g.pods) - k:]:
-                    infeasible_map[pod.name] = "solver: no feasible placement"
+                            node_groups.setdefault(id(node), {})[gi] = int(
+                                takes[gi, si])
+                        for _ in range(int(takes[gi, si])):
+                            try:
+                                pod = next(pod_iter)
+                            except StopIteration:
+                                break
+                            assignments[pod.name] = (node.name if node
+                                                     else f"slot-{si}")
+                            if node is not None:
+                                node.pods.append(pod)
+                    for pod in pod_iter:
+                        infeasible_map[pod.name] = (
+                            "solver: no feasible placement")
+            else:
+                for gi, g in enumerate(st.groups):
+                    k = int(infeasible[gi])
+                    for pod in g.pods[len(g.pods) - k:]:
+                        infeasible_map[pod.name] = (
+                            "solver: no feasible placement")
 
         # cost-neutral coalescing: merge small new nodes into larger types at
         # <= the same price (solver/coalesce.py — the scan buys each group's

@@ -12,13 +12,19 @@ spans can be set beside that number.  It also scrapes the sidecar after each
 request (outside the request's wall, inside the window: this is a probe, not
 a measurement of ``solve_ms``), which gives per request the door spans, the
 root and the collector's pauses on both sides — the position pattern of a
-pass, split by side — and how many of its pods the sidecar stamped from a
-template (``request_decode.hit_share``) and the client wrote from one
-(``encode.hit_share``, off the client's own registry: a client without the
-family reads nothing), and beside it how the client put the catalog on the
-wire: the ``encode`` span's ``catalog`` attribute and the client's
-``karpenter_solver_request_catalog_sent_total`` (``encode.catalog``,
-``encode.catalog_sent``).
+pass, split by side — and how many of its pods the client wrote from a
+template (``encode.hit_share``, off the client's own registry: a client
+without the family reads nothing), and beside it how the client put the
+catalog on the wire: the ``encode`` span's ``catalog`` attribute and the
+client's ``karpenter_solver_request_catalog_sent_total`` (``encode.catalog``,
+``encode.catalog_sent``).  Beside the client's spans stand the sidecar's
+leaves (ISSUE 37): what each of ``bucket``, ``extract``, the scheduler
+outside them and the door keeps under which name (``leaves_ms``; ``own`` is
+a parent's self time, the stretch no leaf names yet), the collector's pauses by the span they stopped
+(``gc_by_span_ms``), and the identities the ledger's metrics rest on
+(``identities``: each ``[left, right]`` pair has to agree).  What the
+sidecar's side of the wire costs is in the ledger (``decode_ms``,
+``decode_templated_pods``, ``gc_gen2_ms``) and is not computed here again.
 
 Prints one JSON object (also written to
 ``chiprun_out/trace_probe.<cell>.<platform>.json``): the run's metrics as the benchmark read them, the sidecar's spans per request
@@ -41,7 +47,8 @@ M_SUM = "karpenter_trace_span_duration_seconds_sum"
 M_COUNT = "karpenter_trace_span_duration_seconds_count"
 M_SELF = "karpenter_trace_span_self_seconds_total"
 M_GC = "karpenter_process_gc_pause_seconds_total"
-M_DECODED = "karpenter_solver_request_decode_pods_total"
+M_GC_SPAN = "karpenter_trace_span_gc_pause_seconds_total"
+M_TENSORIZE_HITS = "karpenter_solver_tensorize_cache_hits_total"
 M_ENCODED = "karpenter_solver_request_encode_pods_total"
 M_CATALOG_SENT = "karpenter_solver_request_catalog_sent_total"
 CATALOG_SENT_HOW = ("digest", "full", "resent")
@@ -84,8 +91,7 @@ def main(argv=None) -> int:
     def server_now() -> dict:
         samples = S.scrape(state["run"].sidecar.metrics_url)
         return {"sum": by_label(samples, M_SUM, "span"),
-                "gc": by_label(samples, M_GC, "generation"),
-                "decoded": by_label(samples, M_DECODED, "how")}
+                "gc": by_label(samples, M_GC, "generation")}
 
     def client_gc() -> dict:
         return {g: creg.counter(M_GC).get({"generation": g}) for g in "012"}
@@ -139,10 +145,6 @@ def main(argv=None) -> int:
                     g: (after["gc"].get(g, 0.0)
                         - before["gc"].get(g, 0.0)) * 1000.0
                     for g in sorted(after["gc"])},
-                "server_decoded_pods": {
-                    how: after["decoded"].get(how, 0.0)
-                    - before["decoded"].get(how, 0.0)
-                    for how in sorted(after["decoded"])},
             })
             return res
 
@@ -189,8 +191,56 @@ def main(argv=None) -> int:
                 "self_ms": S.delta(before, after, M_SELF,
                                    span=name) / n * 1000.0}
 
-    decoded = {how: S.delta(before, after, M_DECODED, how=how) / n
-               for how in by_label(after, M_DECODED, "how")}
+    def dur(name):
+        return spans.get(name, {}).get("duration_ms", 0.0)
+
+    def own(name):
+        return spans.get(name, {}).get("self_ms", 0.0)
+
+    # a parent's leaves sum over EVERY span of the name (``harden``,
+    # ``carve`` and ``tensorize`` also run outside ``bucket``): the split is
+    # of the request, as the ledger's metrics are
+    leaves = {
+        "bucket": {"own": own("bucket"), **{k: dur(k) for k in (
+            "harden", "carve", "tensorize", "signature")}},
+        "extract": {"own": own("extract"), **{k: dur(k) for k in (
+            "readback", "nodes", "assign", "coalesce")}},
+        # what no leaf names: the own time of the spans that only hold
+        # others, and beside it `ladder`'s (its rungs are solves)
+        "scheduler": {"ladder": own("ladder"), **{
+            f"{k}.own": own(k) for k in ("solve", "dispatch", "fence")}},
+        "epilogue_beside_extract": {k: dur(k) for k in (
+            "reseat", "relax", "gang")},
+        "door": {k: dur(k) for k in (
+            "await_request", "request_parse", "request_decode",
+            "response_serialize")}}
+    # a request's `tensorize` spans by what the cache answered: the builds
+    # are the ledger's `tensorize_builds`, the hits stand beside them
+    leaves["tensorize_hits"] = {
+        tier: S.delta(before, after, M_TENSORIZE_HITS, tier=tier) / n
+        for tier in sorted(by_label(after, M_TENSORIZE_HITS, "tier"))}
+    gc_by_span = {span: S.delta(before, after, M_GC_SPAN, span=span)
+                  / n * 1000.0
+                  for span in sorted(by_label(after, M_GC_SPAN, "span"))}
+    m = {k: v["value"] for k, v in line["metrics"].items()}
+    inside = [k for k in spans if k not in leaves["door"]]
+    identities = {
+        # inside one root the self times sum to the root (plus what
+        # `admission` spends side by side with `window`)
+        "root = self times inside it": [
+            dur("solve"), sum(own(k) for k in inside) - dur("admission")],
+        # `signature` and `ladder` are spans route_ms.json never listed
+        "root = leaf metrics + route_ms + signature + ladder": [
+            dur("solve"), sum(m.get(k, 0.0) for k in (
+                "window_ms", "tensorize_ms", "dispatch_ms", "fence_ms",
+                "epilogue_ms", "respond_ms", "route_ms",
+                "route_signature_ms", "route_ladder_ms"))],
+        "route_ms = harden + carve + unnamed": [
+            m.get("route_ms"), sum(m.get(k, 0.0) for k in (
+                "route_harden_ms", "route_carve_ms", "route_unnamed_ms"))],
+        "gc by span = gc by generation": [
+            sum(gc_by_span.values()), m.get("gc_ms")],
+    }
 
     def mean(key, sub):
         return sum(r[key][sub] for r in timed) / n
@@ -204,12 +254,9 @@ def main(argv=None) -> int:
         "correct": line["correct"], "device": line["device"],
         "metrics": {k: v["value"] for k, v in line["metrics"].items()},
         "sidecar_spans": spans,
-        # beside request_decode: how many of a request's pods were stamped
-        # from a template (a sidecar without the family reads nothing)
-        "request_decode": {
-            "duration_ms": spans.get("request_decode", {}).get("duration_ms"),
-            "pods_per_request": decoded,
-            "hit_share": hit_share(decoded)},
+        "leaves_ms": leaves,
+        "gc_by_span_ms": gc_by_span,
+        "identities": identities,
         # beside the client's encode span: the shapes its table held and the
         # share of pods it wrote from one, means over the window's requests
         "encode": {

@@ -499,13 +499,16 @@ def test_coalesce_pairs_is_declared_as_its_file_says():
         bench = json.load(f)
     with open(os.path.join(BENCH, "metrics", f"{PAIRS}.json")) as f:
         spec = json.load(f)
-    decl = bench["per_layer"][-1]  # appended: nothing that was there moved
+    names = [m["name"] for m in bench["per_layer"]]
+    # appended after what PR 33 left (later PRs append on): nothing moved
+    assert names.index(PAIRS) == names.index("decode_catalog_held") + 1
+    decl = bench["per_layer"][names.index(PAIRS)]
     assert decl == {k: spec[k] for k in ("name", "unit", "better", "source",
                                          "layer", "moves")}
     assert decl == {"name": PAIRS, "unit": "pairs", "better": "lower",
                     "source": "program_counter", "layer": "host epilogues",
                     "moves": "solve_ms"}  # no `workloads`: every cell
-    assert [m["name"] for m in bench["per_layer"]].count(PAIRS) == 1
+    assert names.count(PAIRS) == 1
     assert spec["reader"] == "counter_per_request"
     assert spec["args"] == {"metric": COALESCE, "labels": [{"what": "pairs"}]}
     assert "pairs" in COALESCE_WHAT

@@ -1,6 +1,9 @@
 """Naming the time (ISSUE 25): self time for every span, the spans at the
 sidecar's door and on the client, the tracer's spans on the profiler's
-clock, collector pauses — and all of it off under ``KT_TRACE=0``."""
+clock, collector pauses — and all of it off under ``KT_TRACE=0``.  ISSUE 37:
+the leaves beneath ``bucket`` and ``extract``, the tensorize miss counted
+where it is built, the collector's pauses by the span they stop, what the
+heap holds, and the door's ``await_request``."""
 
 import gc
 import json
@@ -8,12 +11,19 @@ import os
 import subprocess
 import sys
 import threading
+import time
 
 import pytest
 
 from karpenter_tpu.metrics import (
+    ALLOCATED_BLOCKS,
     GC_PAUSE_SECONDS,
+    GC_SPAN_PAUSE_SECONDS,
+    GC_SPANS_ZEROED,
+    REQUEST_CATALOG,
     SLO_LATENCY,
+    TENSORIZE_CACHE_HITS,
+    TENSORIZE_CACHE_MISSES,
     TRACE_SPAN_DURATION,
     TRACE_SPAN_SELF,
     Registry,
@@ -26,7 +36,12 @@ from karpenter_tpu.obs import trace as trace_mod
 from karpenter_tpu.obs.trace import NULL_PHASE
 from karpenter_tpu.service import codec
 from karpenter_tpu.service.client import DeltaSession, RemoteScheduler
-from karpenter_tpu.service.server import SolverService, make_server
+from karpenter_tpu.service.server import (
+    KeptCatalogs,
+    SolverService,
+    _Door,
+    make_server,
+)
 from karpenter_tpu.solver.scheduler import BatchScheduler
 from karpenter_tpu.utils.clock import FakeClock
 
@@ -227,9 +242,11 @@ def test_the_clients_spans_split_its_remote_span(served, small_catalog):
     parts = sum(client[s][0] for s in ("encode", "rpc", "decode"))
     remote = client["remote"][0]
     assert parts <= remote
-    assert parts >= 0.95 * remote, client
-    # and remote's own time is what its children leave
+    # remote's own time is what its children leave, to the microsecond ...
     assert client["remote"][1] == pytest.approx(remote - parts, abs=1e-6)
+    # ... and that is the few statements between them: an absolute bound,
+    # since 5 % of a 30 ms solve is one preemption on a loaded machine
+    assert remote - parts < 0.05, client
 
 
 def _sibling_overlap(span: dict) -> float:
@@ -260,11 +277,82 @@ def test_a_served_solves_self_times_sum_to_its_root(served, small_catalog):
                       if n in ("request_parse", "request_decode"))
     inside = sum(s for _n, _d, s in spans) - before_root
     # `admission` (the queue wait) lies inside `window` (the coalescer's):
-    # the one place the served path has two siblings side by side
-    side_by_side = _sibling_overlap(trace.to_dict())
-    assert side_by_side < 0.05 * trace.duration_s
+    # the one place the served path has two siblings side by side.  How
+    # long the two overlap is the dispatcher's wake-up, not this test's to
+    # bound; that nothing ELSE overlaps is
+    tree = trace.to_dict()
+    kids = {c["name"]: c for c in tree["spans"]}
+    side_by_side = _sibling_overlap(tree)
+    assert side_by_side == pytest.approx(
+        max(0.0, min(kids["admission"]["end"], kids["window"]["end"])
+            - max(kids["admission"]["start"], kids["window"]["start"])),
+        abs=1e-6)
     assert inside == pytest.approx(trace.duration_s + side_by_side,
                                    abs=1e-6)
+
+
+# ---- ISSUE 37: leaves beneath `bucket` and `extract` ----------------------
+
+
+def _find(span: dict, name: str) -> list:
+    """Every span called ``name`` in the tree under ``span``."""
+    out = [span] if span["name"] == name else []
+    for child in span.get("spans", ()):
+        out += _find(child, name)
+    return out
+
+
+def _own_share(span: dict) -> float:
+    covered = sum(c["end"] - c["start"] for c in span.get("spans", ()))
+    return 1.0 - covered / (span["end"] - span["start"])
+
+
+def test_the_bucket_probe_is_leaves_and_counts_its_miss_where_it_is_built(
+        served, small_catalog):
+    misses = served["reg"].counter(TENSORIZE_CACHE_MISSES)
+    hits = served["reg"].counter(TENSORIZE_CACHE_HITS)
+    # a batch large enough that the probe's passes take milliseconds and
+    # the few statements `bucket` keeps for itself are lost beside them
+    m0, h0 = misses.get(), hits.get({"tier": "identity"})
+    _solve(served, small_catalog, n=2000, app="leaf")  # a shape never seen
+    tree = served["flight"].traces()[-1].to_dict()
+    (bucket,) = _find(tree, "bucket")
+    inner = bucket["spans"]
+    # in the order the work happens, each marked as the probe's pass
+    assert [c["name"] for c in inner] == [
+        "harden", "carve", "tensorize", "signature"]
+    assert all(c["attrs"]["where"] == "probe" for c in inner)
+    builds = [c for c in _find(tree, "tensorize")
+              if c["attrs"]["tier"] == "miss"]
+    assert builds == [inner[2]]
+    # the probe's build is the request's one miss; every later `tensorize`
+    # of the request (the solve's, relax's) is handed the probe's tensors
+    # by identity
+    assert misses.get() - m0 == 1
+    later = [c for c in _find(tree, "tensorize") if c is not inner[2]]
+    assert later and all(c["attrs"] == {"tier": "identity"} for c in later)
+    assert hits.get({"tier": "identity"}) - h0 == len(later)
+    # the passes over the batch, countable off the span family: the
+    # probe's and the solve's `harden`; the probe's `carve`, the two of
+    # the solve (`_solve_once`, `_solve_tpu`) and relax's where it scans
+    assert len(_find(tree, "harden")) == 2
+    assert len(_find(tree, "carve")) in (3, 4)
+    # the ladder's depth is asked of every batch whatever it holds; `gang`
+    # stays what it was, the epilogue of a batch that holds one
+    (ladder,) = _find(tree, "ladder")
+    assert ladder["attrs"] == {"depth": 0}
+    assert _find(tree, "gang") == []
+    # `bucket` keeps next to nothing for itself
+    assert _own_share(bucket) < 0.10, bucket
+
+
+def test_extract_is_leaves(served, small_catalog):
+    _solve(served, small_catalog, app="ext")
+    tree = served["flight"].traces()[-1].to_dict()
+    (extract,) = _find(tree, "extract")
+    assert [c["name"] for c in extract["spans"]] == [
+        "readback", "nodes", "assign", "coalesce"]
+    assert extract["spans"][3]["attrs"]["nodes_in"] >= 1
 
 
 def test_a_delta_step_passes_the_door_too(served, small_catalog):
@@ -338,11 +426,29 @@ def test_spans_phases_and_gen2_pauses_reach_the_profilers_host_plane(
                 gc.collect()
         with tracer.phase("response_serialize", detached=True):
             pass
+        # the leaves ISSUE 37 names, as the program opens them
+        with tracer.start("solve") as trace:
+            with trace.span("bucket"):
+                for leaf in ("tensorize", "signature"):
+                    with trace.span(leaf, where="probe"):
+                        pass
+            with trace.span("extract"):
+                for leaf in ("readback", "nodes", "assign"):
+                    with trace.span(leaf):
+                        pass
+        # and the door's wait, begun on this thread and ended on another
+        door = _Door(tracer)
+        door.leave()
+        other = threading.Thread(target=door.arrive)
+        other.start()
+        other.join()
     finally:
         jax.profiler.stop_trace()
     names = _host_event_names(str(tmp_path))
     assert {"request_decode", "reseat", "response_serialize",
             "gc_gen2"} <= names
+    assert {"tensorize", "signature", "readback", "nodes", "assign",
+            "await_request"} <= names
     # the root would cover every gap of the device and name them all
     assert "solve" not in names
 
@@ -362,6 +468,231 @@ def test_collector_pauses_are_counted_by_one_callback_per_process():
         assert reg.counter(GC_PAUSE_SECONDS).get({"generation": "2"}) > 0
 
 
+def test_each_pause_is_put_down_to_the_span_it_stopped():
+    reg = Registry()
+    tracer = Tracer(registry=reg)
+    by_span, by_gen = (reg.counter(GC_SPAN_PAUSE_SECONDS),
+                       reg.counter(GC_PAUSE_SECONDS))
+    # zero-initialised: the spans a metric selects by name, and `none`
+    assert all(by_span.has({"span": s}) for s in GC_SPANS_ZEROED)
+
+    def totals():
+        return sum(by_span.values.values()), sum(by_gen.values.values())
+
+    auto = gc.isenabled()
+    gc.disable()  # the forced collections below are the only ones
+    try:
+        s0, g0 = totals()
+        with tracer.start("solve") as trace:
+            with trace.span("extract"):
+                with trace.span("nodes"):
+                    gc.collect()
+                gc.collect(0)
+            gc.collect()          # on the root's own thread: the root's
+            in_span = {s: by_span.get({"span": s})
+                       for s in ("nodes", "extract", "solve")}
+        s1, g1 = totals()
+        with tracer.phase("response_serialize", detached=True):
+            gc.collect(1)
+        gc.collect()              # outside any
+        # a phase's exit hands the registries nothing: the next trace's
+        # finish does
+        assert totals()[0] == s1
+        with tracer.start("solve"):
+            pass
+        s2, g2 = totals()
+    finally:
+        if auto:
+            gc.enable()
+    # nothing reaches the family before a trace finishes ...
+    assert in_span == {"nodes": 0.0, "extract": 0.0, "solve": 0.0}
+    # ... then each pause is under the innermost span open on its thread,
+    for span in ("nodes", "extract", "solve", "response_serialize", "none"):
+        assert by_span.get({"span": span}) > 0, span
+    # and over `span` the family sums to the per-generation one
+    assert g1 > g0 and s1 - s0 == pytest.approx(g1 - g0, rel=1e-9)
+    assert g2 > g1 and s2 - s0 == pytest.approx(g2 - g0, rel=1e-9)
+
+
+def test_a_pause_on_another_thread_is_not_this_threads_spans():
+    reg = Registry()
+    tracer = Tracer(registry=reg)
+    by_span = reg.counter(GC_SPAN_PAUSE_SECONDS)
+    auto = gc.isenabled()
+    gc.disable()
+    try:
+        with tracer.start("solve") as trace:
+            with trace.span("reseat"):
+                t = threading.Thread(target=gc.collect)
+                t.start()
+                t.join()
+    finally:
+        if auto:
+            gc.enable()
+    assert by_span.get({"span": "reseat"}) == 0.0
+    assert by_span.get({"span": "none"}) > 0
+
+
+def test_a_phase_ended_on_another_thread_stops_no_pause_here_afterwards():
+    """The door's wait begins on the thread that serialised and ends on
+    gRPC's parsing thread: while it is open a pause on the first thread is
+    the wait's, and once it has ended there, no longer."""
+    reg = Registry()
+    tracer = Tracer(registry=reg)
+    by_span = reg.counter(GC_SPAN_PAUSE_SECONDS)
+    door = _Door(tracer)
+    auto = gc.isenabled()
+    gc.disable()
+    try:
+        door.leave()
+        gc.collect()
+        t = threading.Thread(target=door.arrive)
+        t.start()
+        t.join()
+        with tracer.start("solve"):
+            pass
+        waited = by_span.get({"span": "await_request"})
+        none = by_span.get({"span": "none"})
+        gc.collect()
+        with tracer.start("solve"):
+            pass
+    finally:
+        if auto:
+            gc.enable()
+    assert waited > 0
+    assert by_span.get({"span": "await_request"}) == waited
+    assert by_span.get({"span": "none"}) > none
+    assert trace_mod._open_here() == []
+
+
+def test_spans_of_two_traces_interleave_on_one_thread():
+    """The dispatcher works for several requests at once: one stack a
+    thread, and a span's parent is the innermost open span of ITS trace."""
+    _clock, _reg, tracer = fake_tracer()
+    with tracer.start("solve") as a, tracer.start("solve") as b:
+        a_out = a.span("dispatch")
+        b_out = b.span("dispatch")
+        with a.span("fence"):
+            assert a.wire_context() == (a.trace_id, "s3")
+            assert b.wire_context() == (b.trace_id, b_out.span_id)
+        a_out.__exit__(None, None, None)   # closed under b's, still open
+        with b.span("fence"):
+            pass
+        b_out.__exit__(None, None, None)
+        with a.span("extract"):
+            pass
+    for tree in (a.to_dict(), b.to_dict()):
+        (dispatch,) = _find(tree, "dispatch")
+        assert [c["name"] for c in dispatch["spans"]] == ["fence"]
+    assert [c["name"] for c in a.to_dict()["spans"]] == [
+        "dispatch", "extract"]
+    assert trace_mod._open_here() == []
+
+
+def test_a_scrape_says_what_the_heap_holds():
+    reg = Registry()
+    Tracer(registry=reg)
+    blocks = reg.gauge(ALLOCATED_BLOCKS)
+    assert blocks.has() and blocks.get() == 0   # there before any scrape
+    kept = [[] for _ in range(20000)]
+    reg.expose()
+    held = blocks.get()
+    assert held > 20000
+    del kept
+    (line,) = [ln for ln in reg.expose().splitlines()
+               if ln.startswith(ALLOCATED_BLOCKS)]
+    # what is given back shows, and to the block: no ``%g`` rounding
+    assert int(line.split()[1]) == blocks.get() < held - 15000
+
+
+# ---- the door's await_request ---------------------------------------------
+
+
+def test_the_door_waits_only_while_the_sidecar_holds_no_request():
+    clock, reg, tracer = fake_tracer()
+    hist = reg.histogram(TRACE_SPAN_DURATION)
+    door = _Door(tracer)
+    for _client in range(2):      # two clients at once
+        door.arrive()
+        door.enter()
+    clock.advance(1)
+    door.leave()                  # one answered, the other still in hand
+    clock.advance(1)
+    assert hist.count({"span": "await_request"}) == 0
+    door.leave()                  # none left: the wait begins here
+    clock.advance(3)
+    door.arrive()                 # ... and ends at the next parse
+    assert hist.count({"span": "await_request"}) == 1
+    assert hist.sums[(("span", "await_request"),)] == pytest.approx(3.0)
+    assert self_s(reg, "await_request") == pytest.approx(3.0)
+    door.arrive()                 # no wait was open: nothing to close
+    assert hist.count({"span": "await_request"}) == 1
+    assert tracer.flight.traces() == []  # detached: in no trace
+
+
+def test_a_request_parsed_and_never_handled_leaves_no_count_behind(
+        small_catalog):
+    """gRPC cancels an RPC between its deserialiser and the handler: the
+    wait it arrived in has ended, nothing counts it as in hand, and the
+    next answered request opens the wait again."""
+    reg = Registry()
+    svc = SolverService(BatchScheduler(backend="oracle", registry=reg),
+                        registry=reg)
+    hist = reg.histogram(TRACE_SPAN_DURATION)
+    prov = Provisioner(name="default").with_defaults()
+
+    def request(app):
+        return codec.encode_request(
+            batch(3, app), [prov], small_catalog).SerializeToString()
+
+    def answered(app):
+        svc.serialize_response(svc.Solve(svc.parse_request(request(app)),
+                                         None))
+
+    answered("d0")                          # a wait is open after it
+    assert svc._door._idle is not None
+    for k in range(3):
+        svc.parse_request(request(f"lost{k}"))  # parsed, never handled
+    assert hist.count({"span": "await_request"}) == 1
+    assert svc._door._held == 0 and svc._door._idle is None
+    answered("d1")
+    assert svc._door._held == 0 and svc._door._idle is not None
+    answered("d2")
+    assert hist.count({"span": "await_request"}) == 2
+
+
+def test_await_request_runs_from_the_last_serialise_to_the_next_parse(
+        served, small_catalog):
+    hist = served["reg"].histogram(TRACE_SPAN_DURATION)
+    key = (("span", "await_request"),)
+    _solve(served, small_catalog, n=20, app="aw0")  # a wait is open after it
+    n0, s0 = hist.count({"span": "await_request"}), hist.sums[key]
+    time.sleep(0.2)
+    t0 = time.perf_counter()
+    _solve(served, small_catalog, n=20, app="aw1")
+    wall = time.perf_counter() - t0
+    assert hist.count({"span": "await_request"}) == n0 + 1
+    # the client's pause, and no more than that plus the client's own turn
+    assert 0.2 <= hist.sums[key] - s0 < 0.2 + wall
+    # a request that fails at the door leaves it too (no response_serialize
+    # will): the sidecar forgets its catalogs, so the next request by digest
+    # is refused and sent again in full — two parses, two waits closed
+    remote = RemoteScheduler(f"127.0.0.1:{served['port']}", backend="tpu",
+                             registry=served["creg"])
+    prov = Provisioner(name="default").with_defaults()
+    unknown = served["reg"].counter(REQUEST_CATALOG)
+    try:
+        remote.solve(batch(20, "aw2"), [prov], small_catalog)
+        served["svc"].catalogs = KeptCatalogs()
+        u0 = unknown.get({"how": "unknown"})
+        remote.solve(batch(20, "aw3"), [prov], small_catalog)
+        assert unknown.get({"how": "unknown"}) == u0 + 1
+    finally:
+        remote.close()
+    assert hist.count({"span": "await_request"}) == n0 + 4
+    assert served["svc"]._door._held == 0
+
+
 # ---- off is off ----------------------------------------------------------
 
 
@@ -375,6 +706,9 @@ def test_a_disabled_tracer_builds_no_phase_no_annotation_no_sample(
     tracer = Tracer(registry=reg, enabled=False)
     assert tracer.phase("request_decode") is NULL_PHASE
     assert tracer.phase("response_serialize", detached=True) is NULL_PHASE
+    door = _Door(tracer)
+    door.leave()
+    door.enter()  # no wait was begun
     with tracer.phase("request_decode") as ph:
         ph.annotate(bytes=1)
     with tracer.start("solve") as trace:
@@ -383,6 +717,8 @@ def test_a_disabled_tracer_builds_no_phase_no_annotation_no_sample(
     assert reg.histogram(TRACE_SPAN_DURATION).totals == {}
     assert dict(reg.counter(TRACE_SPAN_SELF).values) == {(): 0.0}
     assert GC_PAUSE_SECONDS not in reg.counters
+    assert GC_SPAN_PAUSE_SECONDS not in reg.counters
+    assert ALLOCATED_BLOCKS not in reg.gauges
 
 
 def _python(code, **env):
@@ -431,7 +767,8 @@ def test_kt_trace_0_serves_with_no_new_family_moving_and_no_gc_callback():
         "print(json.dumps({'placed': len(res.assignments),\n"
         "  'callbacks': sum(type(getattr(cb, '__self__', None)).__name__\n"
         "      == '_GcWatch' for cb in gc.callbacks),\n"
-        "  'gc_family': 'karpenter_process_gc_pause' in text,\n"
+        "  'gc_family': 'karpenter_process_gc_pause' in text\n"
+        "      or 'gc_pause' in text or 'allocated_blocks' in text,\n"
         "  'self': [l for l in text.splitlines() if l.startswith(\n"
         "      'karpenter_trace_span_self_seconds_total')],\n"
         "  'spans': [l for l in text.splitlines() if l.startswith(\n"
