@@ -225,11 +225,14 @@ REQUEST_CATALOG_SENT = "karpenter_solver_request_catalog_sent_total"
 REQUEST_CATALOG_SENT_HOW = ("digest", "full", "resent")
 # ---- the device scan's axes (solver/tpu.py TpuSolver._count_scan) --------
 SCAN_AXIS = "karpenter_solver_scan_axis_total"
-#: what a device scan ran at (KT003 zero-init source): 'groups' (serial
-#: steps that carry pods: one per distinct pod shape), 'groups_padded' (the
-#: steps the compiled program takes: the G rung), 'node_slots' (the NR rung
-#: every step carries) and 'nodes_used' (slots in use when the scan ended)
-SCAN_AXES = ("groups", "groups_padded", "node_slots", "nodes_used")
+#: what a device scan ran at (KT003 zero-init source): 'groups' (the batch's
+#: pod groups: one per distinct pod shape), 'groups_padded' (the G rung the
+#: program was compiled at: the shape of its group axis), 'node_slots' (the
+#: NR rung every step carries), 'nodes_used' (slots in use when the scan
+#: ended) and 'steps_run' (the serial steps the program took, as it reports
+#: them: up to the last group that has pods)
+SCAN_AXES = ("groups", "groups_padded", "node_slots", "nodes_used",
+             "steps_run")
 SCAN_SLOT_RETRIES = "karpenter_solver_scan_slot_retries_total"
 # ---- the host's merge pass over a scan's new nodes (solver/coalesce.py) --
 COALESCE = "karpenter_solver_coalesce_total"
@@ -682,14 +685,20 @@ INVENTORY = {
         "and each megabatch slot; a slot retry counts both scans): "
         "'groups' — pod groups of the batch, one serial scan step each "
         "(a group is a set of pods with equal PodSpec.group_key(), so "
-        "every Deployment is its own); 'groups_padded' — the steps the "
-        "compiled program takes (solve_dims' G rung; the difference is "
-        "padding); 'node_slots' — the NR rung, the node rows every step "
-        "carries, from _nr_estimate or the full budget; 'nodes_used' — "
-        "slots in use when the scan ended, existing nodes included.  "
-        "Divide by the solves of the same window for per-solve means; "
-        "node_slots far above nodes_used is an estimate that charges "
-        "device time for rows nothing lands on."),
+        "every Deployment is its own); 'groups_padded' — the G rung the "
+        "program was compiled at (solve_dims): the shape of its group "
+        "axis, what the take matrix and the compile signature are sized "
+        "by, not the steps it takes; 'steps_run' — the serial steps the "
+        "program took, read off the program at the fence: it stops after "
+        "the last group that has pods, so a single solve reads 'groups' "
+        "and a megabatch slot the longest slot's; 'node_slots' — the NR "
+        "rung, the node rows every step carries, from _nr_estimate or "
+        "the full budget; 'nodes_used' — slots in use when the scan "
+        "ended, existing nodes included.  Divide by the solves of the "
+        "same window for per-solve means; node_slots far above "
+        "nodes_used is an estimate that charges device time for rows "
+        "nothing lands on, steps_run above groups a megabatch whose "
+        "slots differ in length."),
     SCAN_SLOT_RETRIES: (
         "counter", (),
         "Device scans whose optimistic node-slot axis (_nr_estimate) ran "

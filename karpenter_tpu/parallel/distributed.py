@@ -144,7 +144,7 @@ def worker_main(argv: Optional[List[str]] = None) -> int:
 
     st = graft._scenario()
     run, init, _ne = TpuSolver().prepare(st, track_assignments=False, mesh=mesh)
-    carry, _ys = run(init)
+    carry, _ys, _steps = run(init)
     infeasible = int(
         __import__("numpy").asarray(replicate_for_host(mesh, carry[-1])).sum()
     )

@@ -18,7 +18,8 @@ Design (tpu-first, not a port of the Go loop):
   (ops/masks.prefix_allocate), topology spread = integer water-fill over
   zones (ops/masks.water_fill), new-node selection = lexicographic argmin
   over (candidate x domain) score tensors.  No data-dependent Python control
-  flow — one traced step, ``lax.scan`` over G.
+  flow — one traced step, in one device loop over the groups that stops
+  after the last group that has pods (:func:`_scan_groups`).
 - **Node state is slot-per-node.**  Preallocated arrays of NR node slots
   (existing nodes first, then creation order), so "first fit in creation
   order" is literally array order.
@@ -128,7 +129,9 @@ def _rung(n: int, quantum: int, linear_max: int, ratio: float = 1.5,
     TOTAL number of distinct rungs (≈ log-many), so a growing cluster stops
     triggering a fresh XLA compile every ``quantum`` of growth — the compile
     ladder becomes warmable.  ``axis_div`` keeps the rung divisible for mesh
-    sharding."""
+    sharding.  A rung is the SHAPE of a tensor axis and so costs bytes; on
+    the group axis it is not the scan's trip count (:func:`_scan_groups`
+    stops after the last group that has pods)."""
     q = max(quantum, axis_div)
     q = ((q + axis_div - 1) // axis_div) * axis_div
 
@@ -937,13 +940,54 @@ def _make_step(
     return step
 
 
+def _last_group(counts):
+    """1 + the index of the last group that has pods (0 where none has):
+    the steps a scan over ``counts`` has to take.  Rows past it are the
+    ``G`` rung's padding (``host_count_arrays`` pads ``counts`` with zeros),
+    and a step for a group of no pods leaves the carry as it found it."""
+    G = counts.shape[0]
+    return jnp.max(jnp.where(counts > 0,
+                             jnp.arange(1, G + 1, dtype=jnp.int32), 0))
+
+
+def _scan_groups(step, init, n_steps, take_shape: tuple, track: bool):
+    """The ONE loop of the device program, for the single solve and the
+    megabatch alike: ``step(carry, g)`` for ``g`` in ``0..n_steps-1``,
+    ``n_steps`` a traced int32 the program read off its own batch — the
+    ``G`` rung is the SHAPE of the group axis, not the trip count.  The take
+    matrix is carried as a zero buffer of ``take_shape`` (group axis second
+    to last) that each step writes its row of in place, so the rows of
+    groups the loop never reached read zero, as a step for a group of no
+    pods would have left them.  Returns ``(carry, takes, steps_run)`` with
+    ``steps_run`` the loop's final index."""
+    axis = len(take_shape) - 2
+
+    def body(state):
+        g, carry, takes = state
+        carry, row = step(carry, g)
+        if track:
+            takes = jax.lax.dynamic_update_index_in_dim(takes, row, g, axis)
+        return g + 1, carry, takes
+
+    # untracked solves keep the [.., G] zeros the scan used to stack
+    takes0 = jnp.zeros(take_shape if track else take_shape[:-1],
+                       dtype=jnp.int32)
+    steps_run, carry, takes = jax.lax.while_loop(
+        lambda state: state[0] < n_steps, body, (jnp.int32(0), init, takes0))
+    return carry, takes, steps_run
+
+
 @partial(jax.jit, static_argnames=("NR", "Z", "track"))
 def _run_scan(consts, init, NR: int, Z: int, track: bool):
     """Module-level jitted scan: the jit cache persists across solves, so
-    bucketed shapes recompile once per signature, not once per call."""
+    bucketed shapes recompile once per signature, not once per call.  It
+    takes a step for every group up to the last one that has pods and
+    returns ``(carry, takes, steps_run)``; the ``G`` rung it was compiled at
+    is the take matrix's first axis."""
     step = _make_step(consts, NR, Z, track)
-    G = consts["counts"].shape[0]
-    return jax.lax.scan(step, init, jnp.arange(G, dtype=jnp.int32))
+    counts = consts["counts"]
+    return _scan_groups(step, init, _last_group(counts),
+                        (counts.shape[0], NR), track)
 
 
 #: megabatch request-slot cap: one vmapped dispatch solves at most this many
@@ -1139,36 +1183,49 @@ def _run_scan_many(consts_b, feas_b, init_b, NR: int, Z: int, track: bool,
                    zone_key: int, ct_key: int):
     """Megabatch kernel: B independent solve requests in ONE device dispatch.
 
-    ``jax.vmap`` over the per-request (consts, feasibility-input, init)
-    pytrees — every slot runs the same feasibility + scan program the single
-    path runs, over its own tensors.  Slots cannot interact by construction:
-    vmap introduces no cross-batch reductions, so a slot's result is a pure
-    function of that slot's inputs (tests/test_megabatch.py pins per-request
-    byte parity with serial solves and adversarial cross-tenant isolation).
-    Feasibility runs inside the program (not eagerly per request) so the
-    whole megabatch costs one dispatch + one fence.
+    ``jax.vmap`` over the per-request (consts, feasibility-input, carry)
+    pytrees — every slot runs the same feasibility + step program the single
+    path runs, over its own tensors, inside the one loop of
+    :func:`_scan_groups`.  Slots cannot interact by construction: the only
+    cross-slot value is the loop's trip count (the longest slot's, and a
+    step past a slot's last group is a no-op for it), so a slot's result is
+    a pure function of that slot's inputs (tests/test_megabatch.py pins
+    per-request byte parity with serial solves and adversarial cross-tenant
+    isolation).  Feasibility runs inside the program (not eagerly per
+    request) so the whole megabatch costs one dispatch + one fence.
 
     SHARDED megabatches need no kernel change: when the caller commits the
     stacked inputs with the slot-axis sharding (``_dispatch_prepared``
     with a mesh — dim 0 one-slot-per-chip, parallel/mesh.py slot_mesh),
     GSPMD partitions this very program on the batch dimension; the
     independence argument above is also why the partitioning introduces
-    zero collectives (tests/test_megabatch_sharded.py pins parity and the
+    no collective but the 4-byte maximum that agrees the trip count, once,
+    before the loop (tests/test_megabatch_sharded.py pins parity and the
     every-chip placement)."""
 
-    def one(consts, feas, init):
-        F, dom_ok = compute_feasibility(
+    def feasibility(consts, feas):
+        return compute_feasibility(
             feas["pm"], consts["requests"], feas["gp_ok"], feas["cand_vw"],
             feas["cand_vb"], consts["cand_alloc"], consts["cand_prov"],
             feas["key_check"], feas["dom_vw"], feas["dom_vb"],
             zone_key, ct_key,
         )
-        consts = dict(consts, F=F, dom_ok=dom_ok)
-        step = _make_step(consts, NR, Z, track)
-        G = consts["counts"].shape[0]
-        return jax.lax.scan(step, init, jnp.arange(G, dtype=jnp.int32))
 
-    return jax.vmap(one)(consts_b, feas_b, init_b)
+    F_b, dom_ok_b = jax.vmap(feasibility)(consts_b, feas_b)
+    consts_b = dict(consts_b, F=F_b, dom_ok=dom_ok_b)
+
+    def step(carry_b, g):
+        return jax.vmap(
+            lambda consts, carry: _make_step(consts, NR, Z, track)(carry, g)
+        )(consts_b, carry_b)
+
+    # ONE trip count for the batch, the longest slot's: the loop's bound
+    # stays a scalar every slot (and every chip of a slot mesh) agrees on,
+    # and a slot that ran out of groups earlier takes no-op steps, as it
+    # did on the rung's padding
+    counts_b = consts_b["counts"]
+    n_steps = jnp.max(jax.vmap(_last_group)(counts_b))
+    return _scan_groups(step, init_b, n_steps, counts_b.shape + (NR,), track)
 
 
 # ---------------------------------------------------------------------------
@@ -1760,8 +1817,10 @@ class TpuSolver:
         mesh=None,
         full_nr: bool = False,
     ):
-        """Build (run_fn, init_carry).  ``mesh`` shards the group/candidate/
-        node-slot axes over a jax.sharding.Mesh (parallel/mesh.py layout)."""
+        """Build (run_fn, init_carry, NE); ``run_fn(init)`` is
+        :func:`_run_scan`'s ``(carry, takes, steps_run)``.  ``mesh`` shards
+        the group/candidate/node-slot axes over a jax.sharding.Mesh
+        (parallel/mesh.py layout)."""
         NE = len(existing_nodes)
         node_budget = _node_budget(st, NE, max_nodes)
         a, b = _mesh_divs(mesh)
@@ -1883,14 +1942,19 @@ class TpuSolver:
         return run, init, NE, est_dims, full_dims, full_nr
 
     def _count_scan(self, st: SolveTensors, dims: dict, n_used: int,
-                    span) -> None:
-        """One finished device scan, by the axes it ran at: the real and the
-        padded number of serial steps, the node slots every step carries and
-        the slots in use when it ended (existing nodes included, as in
-        ``NR``; the host's ``coalesce`` may merge them into fewer nodes).
-        ``dims`` is the program that ran (the estimate's, or the full
-        budget's on a retry) and ``span`` the one that fenced it."""
+                    steps_run: int, span) -> None:
+        """One finished device scan, by the axes it ran at: the batch's
+        groups, the ``G`` rung the program was compiled at (the shape of its
+        group axis, no longer its trip count), the serial steps it TOOK as
+        the program itself reports them (read at the fence beside
+        ``n_used``: up to the last group that has pods; in a megabatch the
+        longest slot's), the node slots every step carries and the slots in
+        use when it ended (existing nodes included, as in ``NR``; the host's
+        ``coalesce`` may merge them into fewer nodes).  ``dims`` is the
+        program that ran (the estimate's, or the full budget's on a retry)
+        and ``span`` the one that fenced it."""
         axes = {"groups": st.G, "groups_padded": dims["G"],
+                "steps_run": steps_run,
                 "node_slots": dims["NR"], "nodes_used": n_used}
         counter = self.registry.counter(SCAN_AXIS)
         for axis in SCAN_AXES:
@@ -1971,7 +2035,7 @@ class TpuSolver:
         with trace.span("device_execute", full_nr=full_nr) as span:
             if self._faults:
                 self._faults.fire("dispatch")     # dispatch_exc raises here
-            carry, ys = run(init)
+            carry, ys, steps = run(init)
             if self._faults:
                 effect = self._faults.fire("fence")  # device_hang raises
                 if effect is not None and effect.kind == "slow_fence":
@@ -1981,7 +2045,7 @@ class TpuSolver:
             # needs the carry on the host anyway
             n_used = int(np.asarray(carry[7]))
             self._count_scan(st, full_dims if full_nr else est_dims, n_used,
-                             span)
+                             int(np.asarray(steps)), span)
         compile_ms = (time.perf_counter() - t0) * 1000.0
         solve_ms = compile_ms
         # mark ready the key of the program that ACTUALLY compiled (a fresh
@@ -2008,7 +2072,7 @@ class TpuSolver:
             # dispatch; chip_smoke.py checks that on the chip) and fenced
             # by the same D2H read as above.
             t1 = time.perf_counter()
-            carry2, _ys2 = run(init)
+            carry2, _ys2, _steps2 = run(init)
             np.asarray(carry2[7])
             solve_ms = (time.perf_counter() - t1) * 1000.0
 
@@ -2049,10 +2113,10 @@ class TpuSolver:
             )
             if self._faults:
                 self._faults.fire("dispatch")  # dispatch_exc raises here
-            carry, ys = run(init)  # async: enqueued, not fenced
+            carry, ys, steps = run(init)  # async: enqueued, not fenced
         return PendingTpuSolve(
             solver=self, st=st, existing_nodes=existing_nodes, NE=NE,
-            carry=carry, ys=ys, t0=t0, track=track_assignments,
+            carry=carry, ys=ys, steps=steps, t0=t0, track=track_assignments,
             est_dims=est_dims, full_dims=full_dims, full_nr=full_nr,
             raise_on_exhaust=raise_on_exhaust,
             solve_kwargs=dict(
@@ -2278,13 +2342,13 @@ class TpuSolver:
         # slot index and the batch occupancy (obs: per-slot attribution of a
         # shared dispatch)
         t_starts = [e["r"]["trace"].now() for e in entries]
-        carry_b, ys_b = _run_scan_many(  # async: enqueued, not fenced
+        carry_b, ys_b, steps = _run_scan_many(  # async: enqueued, not fenced
             consts_b, feas_b, init_b, NR, Z, track, zone_key, ct_key,
         )
         return PendingMegaSolve(
             solver=self, entries=entries, carry_b=carry_b, ys_b=ys_b,
-            t0=t0, t_starts=t_starts, track=track, B=B, B_pad=B_pad,
-            mega_key=mega_key, mesh=mesh, registry=registry,
+            steps=steps, t0=t0, t_starts=t_starts, track=track, B=B,
+            B_pad=B_pad, mega_key=mega_key, mesh=mesh, registry=registry,
         )
 
     def solve_many(
@@ -2508,8 +2572,8 @@ class PendingTpuSolve:
     (including ``raise_on_exhaust`` for the compile-behind contract).
     """
 
-    def __init__(self, solver, st, existing_nodes, NE, carry, ys, t0, track,
-                 est_dims, full_dims, full_nr, raise_on_exhaust,
+    def __init__(self, solver, st, existing_nodes, NE, carry, ys, steps, t0,
+                 track, est_dims, full_dims, full_nr, raise_on_exhaust,
                  solve_kwargs, trace=NULL_TRACE) -> None:
         self.solver = solver
         self.trace = trace
@@ -2518,6 +2582,7 @@ class PendingTpuSolve:
         self.NE = NE
         self.carry = carry
         self.ys = ys
+        self.steps = steps
         self.t0 = t0
         self.track = track
         self.est_dims = est_dims
@@ -2539,7 +2604,8 @@ class PendingTpuSolve:
                     s._faults.sleep(effect)
             n_used = int(np.asarray(self.carry[7]))  # the one D2H fence
             s._count_scan(self.st, self.full_dims if self.full_nr
-                          else self.est_dims, n_used, span)
+                          else self.est_dims, n_used,
+                          int(np.asarray(self.steps)), span)
         elapsed_ms = (time.perf_counter() - self.t0) * 1000.0
         s._mark_ready(_dims_key(self.full_dims if self.full_nr
                                 else self.est_dims))
@@ -2575,12 +2641,14 @@ class PendingMegaSolve:
     forwarding shim routes those to the owning host.  Idempotent; per-slot
     slot-exhaustion semantics match ``solve_many``."""
 
-    def __init__(self, solver, entries, carry_b, ys_b, t0, t_starts, track,
-                 B, B_pad, mega_key, mesh=None, registry=None) -> None:
+    def __init__(self, solver, entries, carry_b, ys_b, steps, t0, t_starts,
+                 track, B, B_pad, mega_key, mesh=None, registry=None) -> None:
         self.solver = solver
         self.entries = entries
         self.carry_b = carry_b
         self.ys_b = ys_b
+        #: the steps the ONE loop took (a scalar: every slot ran them)
+        self.steps = steps
         self.t0 = t0
         self.t_starts = t_starts
         self.track = track
@@ -2628,6 +2696,7 @@ class PendingMegaSolve:
         rows7, br, bt = read_slot_rows([self.carry_b[7]],
                                        local_only=per_host)
         elapsed_ms = (time.perf_counter() - self.t0) * 1000.0
+        steps_run = int(np.asarray(self.steps))
         s._mark_ready(self.mega_key)
         rest = [x for k, x in enumerate(self.carry_b) if k != 7]
         if self.track:
@@ -2666,7 +2735,8 @@ class PendingMegaSolve:
             carry_i = tuple(x[i] for x in carry_rows)
             ys_i = ys_rows[i] if ys_rows is not None else None
             s._count_scan(r["st"], e["full_dims"] if e["full_nr"]
-                          else e["est_dims"], int(carry_i[7]), span)
+                          else e["est_dims"], int(carry_i[7]), steps_run,
+                          span)
             try:
                 retried = s._maybe_retry_exhausted(
                     carry_i, e["est_dims"], e["full_dims"], e["full_nr"],

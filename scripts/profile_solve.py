@@ -294,13 +294,13 @@ def main(argv=None) -> int:
     solver = TpuSolver()
     run, init, _ne = solver.prepare(st, track_assignments=False)
     t0 = time.perf_counter()
-    carry, _ys = run(init)
+    carry, _ys, _steps = run(init)
     np.asarray(carry[7])
     out["first_call_ms"] = round((time.perf_counter() - t0) * 1000.0, 1)
     times = []
     for r in range(args.repeats):
         t0 = time.perf_counter()
-        c2, _ = run(init)
+        c2, _, _ = run(init)
         np.asarray(c2[7])
         times.append((time.perf_counter() - t0) * 1000.0)
     out["solve_ms"] = {"min": round(min(times), 1),
@@ -325,7 +325,7 @@ def main(argv=None) -> int:
     # 5. profiler trace of one solve
     os.makedirs(args.trace_dir, exist_ok=True)
     with jax.profiler.trace(args.trace_dir):
-        c3, _ = run(init)
+        c3, _, _ = run(init)
         np.asarray(c3[7])
     paths = sorted(glob.glob(
         os.path.join(args.trace_dir, "**", "*.xplane.pb"), recursive=True),

@@ -41,7 +41,9 @@ SEEDS = (11, 2 ** 31 + 4099, 977)
 #: this PR's per-layer metrics -> the label of the family each reads
 NEW_METRICS = {"scan_groups": "groups", "scan_groups_padded": "groups_padded",
                "scan_node_slots": "node_slots",
-               "scan_nodes_used": "nodes_used", "scan_slot_retries": None}
+               "scan_nodes_used": "nodes_used", "scan_slot_retries": None,
+               # PR 38: the steps the program took, as it reports them
+               "scan_steps_run": "steps_run"}
 
 
 def _load(path, name):
@@ -355,6 +357,9 @@ def test_a_solve_raises_the_axes_by_the_dims_it_ran_at(harness, namespace, k):
     solve = namespace["solves"][k]
     moved = {a: _moved(harness, solve, a) for a in SCAN_AXES}
     assert moved["groups"] == 328 and moved["groups_padded"] == 432
+    # the rung is the shape the program was compiled at; it stopped after
+    # the last group that has pods
+    assert moved["steps_run"] == 328
     assert moved["node_slots"] == 1_024
     assert 328 < moved["nodes_used"] < 1_024
     # the span that fenced the scan says the same, and the selector axis
@@ -411,6 +416,11 @@ def test_the_benchmarks_metric_file_reads_a_real_scrape(harness, namespace,
         want = sum(_moved(harness, s, axis)
                    for s in namespace["solves"]) / len(SEEDS)
         assert got == want > 0
-    without = [s for s in ctx["after"] if s[0] != family]
-    assert reader.read({**ctx, "before": without, "after": without},
-                       **spec["args"]) == 0.0
+    # ... and what a program with the family but not this label reads (the
+    # parent of the PR that adds an axis)
+    for without in ([s for s in ctx["after"] if s[0] != family],
+                    [s for s in ctx["after"]
+                     if s[0] != family or s[1].get("axis") != axis]):
+        assert len(without) < len(ctx["after"])
+        assert reader.read({**ctx, "before": without, "after": without},
+                           **spec["args"]) == 0.0
