@@ -318,7 +318,7 @@ def _member_zones(members: Sequence[PodSpec], zone_names: Sequence[str]) -> List
 def _try_pack(result, gid: str, members: Sequence[PodSpec], *,
               provisioners, instance_types, daemonsets, unavailable,
               allow_new_nodes, max_new_nodes, in_band: Callable,
-              old_penalty: float) -> bool:
+              old_penalty: float, registry=None) -> bool:
     """One gang's co-location repack: what-if the members pinned to each
     candidate zone against everything else placed, adopt the first
     strictly-cheaper (node cost + weighted spread) answer.  Never-worse
@@ -381,7 +381,7 @@ def _try_pack(result, gid: str, members: Sequence[PodSpec], *,
                 pinned, provisioners, instance_types,
                 existing_nodes=base, daemonsets=daemonsets,
                 unavailable=unavailable, allow_new_nodes=allow_new_nodes,
-                max_new_nodes=budget,
+                max_new_nodes=budget, registry=registry,
             )
         # ktlint: allow[KT005] a failed what-if keeps the valid incumbent —
         # the packing rung is strictly opportunistic
@@ -527,7 +527,7 @@ def run_epilogue(
                     daemonsets=daemonsets, unavailable=unavailable,
                     allow_new_nodes=allow_new_nodes,
                     max_new_nodes=max_new_nodes, in_band=in_band,
-                    old_penalty=penalty,
+                    old_penalty=penalty, registry=registry,
                 ):
                     outcome = "packed"
                     seats = _gang_nodes(result, members) or seats

@@ -240,6 +240,11 @@ COALESCE = "karpenter_solver_coalesce_total"
 #: the scan opened), 'merges' (each takes one node off the answer) and
 #: 'pairs' (pair verdicts computed to find them)
 COALESCE_WHAT = ("nodes_in", "merges", "pairs")
+# ---- the oracle's topology seed (solver/reference.py _Solver) ------------
+ORACLE_SEED = "karpenter_solver_oracle_seed_total"
+#: what a seed did (KT003 zero-init source): 'pods' (standing pods tallied)
+#: and 'matches' (selector x label-set evaluations)
+ORACLE_SEED_WHAT = ("pods", "matches")
 TRACE_RING_EVICTIONS = "karpenter_trace_ring_evictions_total"
 FLIGHT_DUMPS = "karpenter_trace_flight_recorder_dumps_total"
 # ---- fleet-wide tracing (ISSUE 15: wire-propagated trace context) -------
@@ -724,6 +729,20 @@ INVENTORY = {
         "nodes_in is a scan that opened a node per tiny group and left "
         "the packing to the host, and pairs far above merges is a pass "
         "whose windows hold few pairs that can merge."),
+    ORACLE_SEED: (
+        "counter", ("what",),
+        "The sequential oracle's topology seed (solver/reference.py "
+        "_Solver, one increment per solve: the oracle backend, small and "
+        "ct-spread batches, cold and residue waves, the capped-node "
+        "reseat, gang and deprovisioning what-ifs): the counts of the "
+        "existing nodes' pods under the spread and (anti-)affinity "
+        "selectors of the pods being placed.  'pods' — standing pods "
+        "tallied (0 when the placed pods carry no such selector: the walk "
+        "is skipped); 'matches' — selector x label-set evaluations, each "
+        "distinct label set matched once per selector per solve.  A "
+        "cluster of few deployments reads matches far below pods; "
+        "matches near pods times the selectors is a cluster whose pods "
+        "all carry distinct labels."),
     TRACE_RING_EVICTIONS: (
         "counter", (),
         "Traces evicted from the flight recorder's bounded ring to admit "

@@ -28,9 +28,10 @@ from __future__ import annotations
 
 import heapq
 import time
-from collections import defaultdict
+from collections import Counter, defaultdict
 from typing import Dict, List, Mapping, Optional, Sequence, Set, Tuple
 
+from ..metrics import ORACLE_SEED, Registry, registry as default_registry
 from ..models import labels as L
 from ..models.instancetype import InstanceType
 from ..models.pod import PodSpec
@@ -74,6 +75,35 @@ def _selector_table(pods: Sequence[PodSpec]) -> Dict[tuple, object]:
     return out
 
 
+class _LabelSetHits(dict):
+    """Label set (``tuple(labels.items())``) -> index into :attr:`of`, the
+    selector keys it matches.  A label set is matched once, on its first
+    lookup; label sets that match the same keys share an index, so the pods
+    of a node tally to a few indices (one increment of ``topo`` per index,
+    not per pod).  Equal items in another order are another label set:
+    matched once more, counted the same."""
+
+    def __init__(self, selectors: Dict[tuple, object]) -> None:
+        super().__init__()
+        self.sels = list(selectors.items())
+        #: index -> the keys of the selectors that match
+        self.of: List[tuple] = []
+        self._index: Dict[tuple, int] = {}
+        #: selector x label-set evaluations made
+        self.matches = 0
+
+    def __missing__(self, lset: tuple) -> int:
+        labels = dict(lset)
+        keys = tuple(k for k, sel in self.sels if sel.matches(labels))
+        self.matches += len(self.sels)
+        i = self._index.get(keys)
+        if i is None:
+            i = self._index[keys] = len(self.of)
+            self.of.append(keys)
+        self[lset] = i
+        return i
+
+
 class _Solver:
     def __init__(
         self,
@@ -92,10 +122,16 @@ class _Solver:
         self.unavailable = unavailable
         self.allow_new_nodes = allow_new_nodes
         self.max_new_nodes = max_new_nodes
-        self.selectors = _selector_table(
-            list(pods) + [p for n in existing_nodes for p in n.pods]
-        )
+        # the selectors of the pods being placed, not of the standing pods:
+        # every read of self.topo is keyed by a constraint of the placed
+        # group's own pod (_zone_allowed, _ct_allowed, _host_cap,
+        # _new_node_host_cap), so a selector only a standing pod carries
+        # would be counted and never read
+        self.selectors = _selector_table(pods)
         self.topo = _TopologyState()
+        #: what the topology seed did (karpenter_solver_oracle_seed_total)
+        self.seed_pods = 0
+        self.seed_matches = 0
         self.nodes: List[SimNode] = list(existing_nodes)  # creation order
         self.new_nodes: List[SimNode] = []
         self.assignments: Dict[str, str] = {}
@@ -139,9 +175,30 @@ class _Solver:
                 {L.RESOURCE_CPU: cap.get(L.RESOURCE_CPU, 0.0),
                  L.RESOURCE_MEMORY: cap.get(L.RESOURCE_MEMORY, 0.0)},
             )
-            for p in n.pods:
-                self.topo.observe(p, n.zone, n.name, self.selectors,
-                                  ct=n.capacity_type)
+        if self.selectors:
+            self._seed_topology(existing_nodes)
+
+    def _seed_topology(self, existing_nodes: Sequence[SimNode]) -> None:
+        """Count the standing pods into ``self.topo``: each distinct label
+        set is matched against each selector once per solve, and each node's
+        pods are tallied by the keys they match.  The counts are those of
+        :meth:`_TopologyState.observe` on every pod; a standing cluster holds
+        a few label sets (one per deployment) over many pods."""
+        hits = _LabelSetHits(self.selectors)
+        topo = self.topo
+        for n in existing_nodes:
+            if not n.pods:
+                continue
+            self.seed_pods += len(n.pods)
+            for i, c in Counter(
+                    [hits[tuple(p.labels.items())] for p in n.pods]).items():
+                for key in hits.of[i]:
+                    topo.zone[key][n.zone] += c
+                    topo.node[key][n.name] += c
+                    if n.capacity_type:
+                        topo.ct[key][n.capacity_type] += c
+                    topo.total[key] += c
+        self.seed_matches = hits.matches
 
     # ---- per-(group,node-shape) caches --------------------------------
     def _node_sig(self, node: SimNode) -> tuple:
@@ -606,11 +663,13 @@ def solve(
     unavailable: Optional[Set[tuple]] = None,
     allow_new_nodes: bool = True,
     max_new_nodes: Optional[int] = None,
+    registry: Optional[Registry] = None,
 ) -> SolveResult:
     """Run the sequential FFD pack.  ``existing_nodes`` are tried first-fit
     before any new node is proposed (provisioning hot path SURVEY §3.2 step 3;
     the consolidation what-if reuses this with ``allow_new_nodes``/
-    ``max_new_nodes`` — §3.3)."""
+    ``max_new_nodes`` — §3.3).  The topology seed's work is counted in
+    ``registry`` (the process default if None)."""
     t0 = time.perf_counter()
     # snapshots: simulated placements must not leak into the caller's nodes
     existing = [n.snapshot() for n in existing_nodes]
@@ -618,6 +677,9 @@ def solve(
         pods, provisioners, instance_types, existing, list(daemonsets),
         unavailable or set(), allow_new_nodes, max_new_nodes,
     )
+    seed = (registry or default_registry).counter(ORACLE_SEED)
+    seed.inc({"what": "pods"}, value=s.seed_pods)
+    seed.inc({"what": "matches"}, value=s.seed_matches)
     s.run()
     return SolveResult(
         nodes=s.new_nodes,

@@ -27,6 +27,8 @@ from ..metrics import (
     MULTIHOST_SLOT_OWNERSHIP,
     MULTIHOST_SLOTS,
     MULTIHOST_UNIFIED,
+    ORACLE_SEED,
+    ORACLE_SEED_WHAT,
     PRECOMPILE_DURATION,
     SCHEDULING_DURATION,
     SOLVER_BACKEND_DURATION,
@@ -568,6 +570,9 @@ class BatchScheduler:
         from ..gang import zero_init_gang_metrics as _gang_zero_init
 
         _gang_zero_init(self.registry)
+        # the oracle's topology-seed series exist before its first solve
+        for what in ORACLE_SEED_WHAT:
+            self.registry.counter(ORACLE_SEED).inc({"what": what}, value=0.0)
         # hierarchical re-entrancy depth: repair solves issued from inside
         # solve_hierarchical must never route hierarchically themselves
         self._hier_depth = 0
@@ -1184,7 +1189,7 @@ class BatchScheduler:
             waste_pods, provisioners, instance_types,
             existing_nodes=others, daemonsets=daemonsets,
             unavailable=unavailable, allow_new_nodes=True,
-            max_new_nodes=budget,
+            max_new_nodes=budget, registry=self.registry,
         )
         old_cost = sum(n.price for n in waste)
         if re.infeasible or re.new_node_cost >= old_cost - 1e-9:
@@ -1509,7 +1514,7 @@ class BatchScheduler:
                     pods, provisioners, instance_types,
                     existing_nodes=existing_nodes, daemonsets=daemonsets,
                     unavailable=unavailable, allow_new_nodes=allow_new_nodes,
-                    max_new_nodes=max_new_nodes,
+                    max_new_nodes=max_new_nodes, registry=self.registry,
                 )
             finally:
                 self.registry.histogram(SOLVER_BACKEND_DURATION).observe(
@@ -1784,7 +1789,7 @@ class BatchScheduler:
             tpu_pods, provisioners, instance_types,
             existing_nodes=all_existing, daemonsets=daemonsets,
             unavailable=unavailable, allow_new_nodes=allow_new_nodes,
-            max_new_nodes=max_new_nodes,
+            max_new_nodes=max_new_nodes, registry=self.registry,
         )
         return res, "oracle"
 
@@ -1920,7 +1925,7 @@ class BatchScheduler:
                 cpu_pods, provisioners, instance_types,
                 existing_nodes=cur_existing, daemonsets=daemonsets,
                 unavailable=unavailable, allow_new_nodes=allow_new_nodes,
-                max_new_nodes=max_new_nodes,
+                max_new_nodes=max_new_nodes, registry=self.registry,
             )
             chain(res0)
             assignments.update(res0.assignments)
@@ -1942,6 +1947,7 @@ class BatchScheduler:
                     daemonsets=daemonsets, unavailable=unavailable,
                     allow_new_nodes=allow_new_nodes,
                     max_new_nodes=None if max_new_nodes is None else max(0, max_new_nodes - len(nodes)),
+                    registry=self.registry,
                 )
                 self.registry.histogram(SOLVER_BACKEND_DURATION).observe(
                     time.perf_counter() - t0c, {"backend": "oracle"}

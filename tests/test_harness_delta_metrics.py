@@ -67,9 +67,12 @@ def test_a_program_without_the_families_reads_zero_and_never_raises(name):
 
 def test_the_declared_entries_are_the_files_appended_at_the_end(bench):
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(NEW):] == list(NEW)
-    earlier = {m["layer"] for m in bench["per_layer"][:-len(NEW)]}
-    for decl in bench["per_layer"][-len(NEW):]:
+    # appended together, in this order; later metrics may follow them
+    at = names.index("delta_ms")
+    block = bench["per_layer"][at:at + len(NEW)]
+    assert [m["name"] for m in block] == list(NEW)
+    earlier = {m["layer"] for m in bench["per_layer"][:at]}
+    for decl in block:
         s = spec(decl["name"])
         assert decl == {**{k: s[k] for k in ("name", "unit", "better",
                                              "source", "layer", "moves")},
