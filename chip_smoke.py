@@ -212,8 +212,8 @@ def client_phase(target: str, metrics_url: str, *, scenario=None,
         t0 = time.perf_counter()
         res = remote.solve(batch, provs, catalog)
         wall_ms = (time.perf_counter() - t0) * 1000.0
-        for node in res.nodes:  # the validator reads allocatable + labels
-            hydrate_node(node, it_by_name)
+        for node in res.nodes:  # the validator reads allocatable, labels
+            hydrate_node(node, it_by_name, provs)  # and taints
         after = scrape(metrics_url)
         row = {
             "request": label, "pods": len(batch),

@@ -73,6 +73,15 @@ class Histogram:
     def count(self, labels: Optional[Dict[str, str]] = None) -> int:
         return self.totals.get(_lkey(labels), 0)
 
+    def zero(self, labels: Optional[Dict[str, str]] = None) -> "Histogram":
+        """Create the series at 0, so that it is exposed before its first
+        observation (KT003's zero-init, as a counter's ``inc(value=0.0)``)."""
+        key = _lkey(labels)
+        self.counts.setdefault(key, [0] * (len(self.buckets) + 1))
+        self.sums.setdefault(key, 0.0)
+        self.totals.setdefault(key, 0)
+        return self
+
 
 class Registry:
     def __init__(self) -> None:
@@ -234,6 +243,11 @@ SCAN_AXIS = "karpenter_solver_scan_axis_total"
 SCAN_AXES = ("groups", "groups_padded", "node_slots", "nodes_used",
              "steps_run")
 SCAN_SLOT_RETRIES = "karpenter_solver_scan_slot_retries_total"
+# ---- existing nodes seeded into a device scan (TpuSolver._host_arrays) --
+SCAN_EXISTING = "karpenter_solver_scan_existing_total"
+#: what the seed did (KT003 zero-init source): 'nodes' (existing nodes
+#: seeded) and 'taint_closed' ((group, existing node) pairs a taint closed)
+SCAN_EXISTING_WHAT = ("nodes", "taint_closed")
 # ---- the host's merge pass over a scan's new nodes (solver/coalesce.py) --
 COALESCE = "karpenter_solver_coalesce_total"
 #: what a pass saw and did (KT003 zero-init source): 'nodes_in' (new nodes
@@ -705,6 +719,19 @@ INVENTORY = {
         "nodes_used is an estimate that charges device time for rows "
         "nothing lands on, steps_run above groups a megabatch whose "
         "slots differ in length."),
+    SCAN_EXISTING: (
+        "counter", ("what",),
+        "Existing nodes seeded into device scans (TpuSolver._host_arrays, "
+        "under the `seed_existing` span: one increment per host build of a "
+        "scan that meets existing nodes — a session's `scan` step, a wave "
+        "chained on an earlier wave's nodes, a consolidation what-if; a "
+        "slot retry counts both builds): 'nodes' — existing nodes whose "
+        "room, zone, price and pods' selector counts the scan starts "
+        "from; 'taint_closed' — (pod group, existing node) pairs closed "
+        "because a taint of the node blocks the group.  A cluster with a "
+        "tainted provisioner reads taint_closed > 0 once the nodes it "
+        "bought come back as existing nodes; 0 there is a node that lost "
+        "its provisioner's taints."),
     SCAN_SLOT_RETRIES: (
         "counter", (),
         "Device scans whose optimistic node-slot axis (_nr_estimate) ran "

@@ -110,8 +110,9 @@ class SolveTensors:
     key_check: np.ndarray    # [K] bool — keys checked on the C axis (zone/ct excluded)
     gp_ok: np.ndarray        # [G, P] bool — group tolerates prov taints & reqs intersect
 
-    # provisioner axis
-    prov_names: List[str]
+    # provisioner axis (weight order); a node bought from candidate ci is
+    # stamped by provisioners[cand_prov[ci]] (SimNode.bought)
+    provisioners: List[Provisioner]
     prov_weight: np.ndarray  # [P] f32
     prov_limits: np.ndarray  # [P, R] f32 (+inf where unset)
 
@@ -143,6 +144,10 @@ class SolveTensors:
     # union-find joins equal tags so a gang is never split across blocks) —
     # the device scan never sees it, so gang-free tensors stay byte-stable
     g_gang: np.ndarray = field(default_factory=lambda: np.zeros(0, dtype=np.int32))
+
+    @property
+    def prov_names(self) -> List[str]:
+        return [p.name for p in self.provisioners]
 
     @property
     def G(self) -> int:
@@ -849,7 +854,7 @@ def tensorize(
         cand_avail=cand_avail,
         key_check=key_check,
         gp_ok=gp_ok,
-        prov_names=[p.name for p in ordered_provs],
+        provisioners=list(ordered_provs),
         prov_weight=prov_weight,
         prov_limits=prov_limits,
         dom_zone=dom_zone,

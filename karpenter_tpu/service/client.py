@@ -943,13 +943,21 @@ class RemoteScheduler:
         self.client.close()
 
 
-def hydrate_node(node: SimNode, it_by_name: Dict[str, InstanceType]) -> SimNode:
-    """Re-hydrate a node decoded off the wire from the caller's catalog: the
-    wire's NewNode is placement-only (type/zone/ct/price/pod names), but
-    callers — and the ground-truth validator — read allocatable and labels."""
+def hydrate_node(node: SimNode, it_by_name: Dict[str, InstanceType],
+                 provisioners: Sequence[Provisioner]) -> SimNode:
+    """Re-hydrate a node decoded off the wire from the caller's catalog and
+    provisioners: the wire's NewNode is placement-only (type/zone/ct/price/
+    pod names), but callers — and the ground-truth validator — read
+    allocatable, labels and taints, the provisioner's among them as the
+    sidecar's own node carries them (``SimNode.bought``)."""
     it = it_by_name.get(node.instance_type)
     if it is not None and not node.allocatable:
         node.allocatable = dict(it.allocatable)
+    prov = next((p for p in provisioners if p.name == node.provisioner), None)
+    if prov is not None and not node.taints:
+        node.taints = list(prov.taints)
+        for k, v in prov.labels.items():
+            node.labels.setdefault(k, v)
     node.stamp_labels()
     return node
 
@@ -1390,9 +1398,9 @@ class DeltaSession:
     def _attach(self, node: SimNode) -> SimNode:
         """Re-attach the ledger's real PodSpecs (the wire carries names)
         and re-hydrate node fidelity from the ledger's catalog
-        (:func:`hydrate_node`)."""
+        and provisioners (:func:`hydrate_node`)."""
         node.pods = [self._pods.get(p.name, p) for p in node.pods]
-        return hydrate_node(node, self._it_by_name)
+        return hydrate_node(node, self._it_by_name, self._provisioners)
 
     def _apply_full(self, reply) -> None:
         self._assignments = dict(reply.assignments)

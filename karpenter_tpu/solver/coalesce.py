@@ -506,13 +506,12 @@ def coalesce_new_nodes(
                 out.append(group[x])
                 continue
             ci = int(cand_ix[merged[x][2]])
-            node = SimNode(
-                instance_type=st.cand_names[ci][1], provisioner=prov,
+            node = SimNode.bought(
+                st.provisioners[pi], instance_type=st.cand_names[ci][1],
                 zone=zone, capacity_type=ct, price=float(-tab[x, R]),
                 allocatable={st.vocab.resources[r]: float(st.cand_alloc[ci, r])
                              for r in range(R)},
-                existing=False, name=name)
-            node.stamp_labels()
+                name=name)
             node.pods = pods_of(x)
             out.append(node)
     # forward every absorbed name to the node that finally holds its pods:

@@ -309,11 +309,10 @@ def solve_tensors_native(
         ci = int(slot_cand[s])
         if ci < 0:
             continue
-        prov_name, type_name = st.cand_names[ci]
         di = int(slot_dom[s])
-        node = SimNode(
-            instance_type=type_name,
-            provisioner=prov_name,
+        node = SimNode.bought(
+            st.provisioners[int(st.cand_prov[ci])],
+            instance_type=st.cand_names[ci][1],
             zone=st.zone_names[di // n_ct] if st.zone_names else "",
             capacity_type=st.ct_names[di % n_ct] if st.ct_names else "",
             price=float(slot_price[s]),
@@ -321,7 +320,6 @@ def solve_tensors_native(
                 st.vocab.resources[r]: float(st.cand_alloc[ci, r]) for r in range(R)
             },
         )
-        node.stamp_labels()
         nodes.append(node)
         slot_to_node[s] = node
 

@@ -573,21 +573,11 @@ class _Solver:
         for r in merged.to_list() + pod_reqs.to_list():
             if r.operator == IN and len(r.values) == 1 and r.key not in labels:
                 labels[r.key] = r.values[0]
-        node = SimNode(
-            instance_type=it.name,
-            provisioner=prov.name,
-            zone=o.zone,
-            capacity_type=o.capacity_type,
-            price=o.price,
-            allocatable=eff_alloc,
-            labels=labels,
-            taints=list(prov.taints),
+        node = SimNode.bought(
+            prov, instance_type=it.name, zone=o.zone,
+            capacity_type=o.capacity_type, price=o.price,
+            allocatable=eff_alloc, labels=labels,
         )
-        node.labels[L.ZONE] = o.zone
-        node.labels[L.CAPACITY_TYPE] = o.capacity_type
-        node.labels[L.PROVISIONER_NAME] = prov.name
-        node.labels[L.INSTANCE_TYPE] = it.name
-        node.labels[L.HOSTNAME] = node.name
         self.nodes.append(node)
         self.new_nodes.append(node)
         self.prov_usage[prov.name] = add(

@@ -712,12 +712,11 @@ def _materialize(st, rounding: _Rounding,
     n_ct = max(1, len(st.ct_names))
     nodes: List[SimNode] = []
     for ci in rounding.node_cand:
-        prov_name, type_name = st.cand_names[ci]
         di = int(d_c[ci])
         zone = st.zone_names[int(st.dom_zone[di])] if st.zone_names else ""
-        node = SimNode(
-            instance_type=type_name,
-            provisioner=prov_name,
+        node = SimNode.bought(
+            st.provisioners[int(st.cand_prov[ci])],
+            instance_type=st.cand_names[ci][1],
             zone=zone,
             capacity_type=st.ct_names[di % n_ct] if st.ct_names else "",
             price=float(pr[ci, di]),
@@ -725,9 +724,7 @@ def _materialize(st, rounding: _Rounding,
                 st.vocab.resources[r]: float(st.cand_alloc[ci, r])
                 for r in range(st.cand_alloc.shape[1])
             },
-            existing=False,
         )
-        node.stamp_labels()
         nodes.append(node)
 
     per_group: Dict[int, List[Tuple[int, int]]] = {}

@@ -516,12 +516,15 @@ class BatchScheduler:
         # registration phase, on the pipeline dispatcher thread — _solve_tpu
         # routes first device waves through it instead of dispatching
         self._mega_collect: Optional[_MegaCollector] = None
-        # register the megabatch/precompile families so the documented
-        # metrics are visible before the first megabatch lands; every flush
+        # register the megabatch/precompile/compile families so the
+        # documented metrics are visible before the first megabatch lands
+        # and on a scheduler that never compiles behind (a session of
+        # constrained pods warms nothing in the background); every flush
         # reason exists at 0 from construction (KT003 — the pipeline
         # re-zero-inits too, for facade schedulers without this init)
         self.registry.histogram(MEGABATCH_SLOTS)
         self.registry.histogram(PRECOMPILE_DURATION)
+        self.registry.histogram(SOLVER_COMPILE_DURATION).zero()
         for reason in MEGABATCH_FLUSH_REASONS:
             self.registry.counter(MEGABATCH_FLUSH).inc(
                 {"reason": reason}, value=0.0)

@@ -60,6 +60,8 @@ from ..metrics import (
     COALESCE_WHAT,
     SCAN_AXES,
     SCAN_AXIS,
+    SCAN_EXISTING,
+    SCAN_EXISTING_WHAT,
     SCAN_SLOT_RETRIES,
     Registry,
     registry as default_registry,
@@ -1377,6 +1379,8 @@ class TpuSolver:
         for axis in SCAN_AXES:
             self.registry.counter(SCAN_AXIS).inc({"axis": axis}, value=0.0)
         self.registry.counter(SCAN_SLOT_RETRIES).inc(value=0.0)
+        for what in SCAN_EXISTING_WHAT:
+            self.registry.counter(SCAN_EXISTING).inc({"what": what}, value=0.0)
         for what in COALESCE_WHAT:
             self.registry.counter(COALESCE).inc({"what": what}, value=0.0)
         # persistent compile cache: every process that constructs a solver
@@ -1665,6 +1669,7 @@ class TpuSolver:
         a: int = 1,
         b: int = 1,
         dims: Optional[dict] = None,
+        trace=NULL_TRACE,
     ):
         """Pure-host (numpy) build of one solve's padded tensors: returns
         ``(np_consts, feas, np_init, dims)`` with every value a numpy array.
@@ -1734,35 +1739,49 @@ class TpuSolver:
         ex_sel = np.zeros((NR, S), dtype=np.int32)
         ex_ok = np.zeros((G, NE_pad), dtype=bool)
         ex_price = np.zeros(NR, dtype=np.float32)
-        zone_index = {z: i for i, z in enumerate(st.zone_names)}
         zc0 = np.zeros((S, Z), dtype=np.int32)
         tot0 = np.zeros(S, dtype=np.int32)
         prov_used0 = np.zeros((P_pad, R), dtype=np.float32)
-        prov_index = {n: i for i, n in enumerate(st.prov_names)}
-
-        # limits bind on raw machine CAPACITY (st.capacity_row; the
-        # independent validator agrees) — fuzz seed 23
-        for ni, node in enumerate(existing_nodes):
-            ex_res[ni] = st.vocab.resources_to_row(node.remaining()).astype(np.float32)
-            ex_zone[ni] = zone_index.get(node.zone, 0)
-            ex_price[ni] = node.price
-            pi = prov_index.get(node.provisioner)
-            if pi is not None:
-                prov_used0[pi] += st.capacity_row(node.instance_type,
-                                                  node.allocatable)
-            for gi, g in enumerate(st.groups):
-                rep = g.pods[0]
-                ex_ok[gi, ni] = (
-                    not any(t.blocks(rep.tolerations) for t in node.taints)
-                    and g.requirements.compatible(node.labels) is None
-                )
-        # selector counts on existing nodes + zone counters
-        for si, (sel, topo, kind) in enumerate(st.selector_defs):
-            for ni, node in enumerate(existing_nodes):
-                n_match = sum(1 for p in node.pods if sel.matches(p.labels))
-                ex_sel[ni, si] = n_match
-                zc0[si, zone_index.get(node.zone, 0)] += n_match
-                tot0[si] += n_match
+        if NE:
+            # the existing nodes' rows of the carry: their room, zone, price
+            # and provisioner usage, the groups each is open to (no taint
+            # blocks the group, its requirements match the node's labels)
+            # and their pods' counts under each selector; the (group, node)
+            # pairs a taint closed are counted beside the nodes
+            zone_index = {z: i for i, z in enumerate(st.zone_names)}
+            prov_index = {n: i for i, n in enumerate(st.prov_names)}
+            closed = 0
+            with trace.span("seed_existing") as span:
+                # limits bind on raw machine CAPACITY (st.capacity_row; the
+                # independent validator agrees) — fuzz seed 23
+                for ni, node in enumerate(existing_nodes):
+                    ex_res[ni] = st.vocab.resources_to_row(
+                        node.remaining()).astype(np.float32)
+                    ex_zone[ni] = zone_index.get(node.zone, 0)
+                    ex_price[ni] = node.price
+                    pi = prov_index.get(node.provisioner)
+                    if pi is not None:
+                        prov_used0[pi] += st.capacity_row(node.instance_type,
+                                                          node.allocatable)
+                    for gi, g in enumerate(st.groups):
+                        rep = g.pods[0]
+                        if any(t.blocks(rep.tolerations) for t in node.taints):
+                            closed += 1
+                        else:
+                            ex_ok[gi, ni] = (
+                                g.requirements.compatible(node.labels) is None)
+                # selector counts on existing nodes + zone counters
+                for si, (sel, topo, kind) in enumerate(st.selector_defs):
+                    for ni, node in enumerate(existing_nodes):
+                        n_match = sum(1 for p in node.pods
+                                      if sel.matches(p.labels))
+                        ex_sel[ni, si] = n_match
+                        zc0[si, zone_index.get(node.zone, 0)] += n_match
+                        tot0[si] += n_match
+                span.annotate(nodes=NE, taint_closed=closed)
+            counter = self.registry.counter(SCAN_EXISTING)
+            counter.inc({"what": "nodes"}, value=float(NE))
+            counter.inc({"what": "taint_closed"}, value=float(closed))
 
         np_consts = dict(
             counts=np_counts,
@@ -1826,17 +1845,20 @@ class TpuSolver:
         track_assignments: bool = True,
         mesh=None,
         full_nr: bool = False,
+        dims: Optional[dict] = None,
+        trace=NULL_TRACE,
     ):
         """Build (run_fn, init_carry, NE); ``run_fn(init)`` is
         :func:`_run_scan`'s ``(carry, takes, steps_run)``.  ``mesh`` shards
         the group/candidate/node-slot axes over a jax.sharding.Mesh
-        (parallel/mesh.py layout)."""
+        (parallel/mesh.py layout); ``dims`` as ``_host_arrays`` takes it."""
         NE = len(existing_nodes)
         node_budget = _node_budget(st, NE, max_nodes)
         a, b = _mesh_divs(mesh)
         np_consts, feas, np_init, dims = self._host_arrays(
             st, existing_nodes, node_budget=node_budget,
             track_assignments=track_assignments, full_nr=full_nr, a=a, b=b,
+            dims=dims, trace=trace,
         )
         NR, Z = dims["NR"], dims["Z"]
 
@@ -1925,7 +1947,7 @@ class TpuSolver:
 
     def _prepare_dispatch(
         self, st: SolveTensors, existing_nodes, max_nodes,
-        track_assignments: bool, mesh, full_nr: bool,
+        track_assignments: bool, mesh, full_nr: bool, trace=NULL_TRACE,
     ):
         """Shared dispatch preamble for ``solve`` and ``solve_async`` —
         the SINGLE source of the dims/bucketing/exhausted-promotion steps,
@@ -1945,11 +1967,41 @@ class TpuSolver:
             # straight to the full program (see _nr_exhausted)
             with self._lock:
                 full_nr = _dims_key(est_dims) in self._nr_exhausted
+        if full_nr:
+            full_dims = self._ready_dims(full_dims)
+        else:
+            est_dims = self._ready_dims(est_dims)
         run, init, NE = self.prepare(
             st, existing_nodes=existing_nodes, max_nodes=max_nodes,
             track_assignments=track_assignments, mesh=mesh, full_nr=full_nr,
+            dims=full_dims if full_nr else est_dims, trace=trace,
         )
         return run, init, NE, est_dims, full_dims, full_nr
+
+    def _ready_dims(self, dims: dict) -> dict:
+        """``dims``, or, where no program is compiled at them, the ready
+        program that differs from them only in a larger existing-node rung
+        ``NE_pad`` (the smallest such).  ``NE_pad`` is the shape of
+        ``ex_ok`` alone: the scan reads it for the existing rows, whose
+        count is a value, so a solve padded up to it takes the same steps
+        on the same rows.  A session whose standing cluster shrinks below a
+        rung it grew past (a ``full`` step packs anew, a service's nodes
+        come and go) then runs on the program the larger cluster compiled
+        instead of stalling its step on a compile."""
+        with self._lock:
+            if _dims_key(dims) in self._ready:
+                return dims
+            best = None
+            for sig in self._ready:
+                other = dict(sig)
+                if (other.keys() == dims.keys()
+                        and other["NE_pad"] > dims["NE_pad"]
+                        and all(other[k] == v for k, v in dims.items()
+                                if k != "NE_pad")
+                        and (best is None
+                             or other["NE_pad"] < best["NE_pad"])):
+                    best = other
+        return dims if best is None else best
 
     def _count_scan(self, st: SolveTensors, dims: dict, n_used: int,
                     steps_run: int, span) -> None:
@@ -2041,6 +2093,7 @@ class TpuSolver:
         with trace.span("device_prepare"):
             run, init, NE, est_dims, full_dims, full_nr = self._prepare_dispatch(
                 st, existing_nodes, max_nodes, track_assignments, mesh, full_nr,
+                trace,
             )
         with trace.span("device_execute", full_nr=full_nr) as span:
             if self._faults:
@@ -2119,7 +2172,7 @@ class TpuSolver:
         with trace.span("device_dispatch"):
             run, init, NE, est_dims, full_dims, full_nr = self._prepare_dispatch(
                 st, existing_nodes, max_nodes, track_assignments, mesh,
-                full_nr=False,
+                full_nr=False, trace=trace,
             )
             if self._faults:
                 self._faults.fire("dispatch")  # dispatch_exc raises here
@@ -2468,12 +2521,11 @@ class TpuSolver:
                 ci = int(row_cand[si])
                 if ci < 0 or not active[si]:
                     continue
-                prov_name, type_name = st.cand_names[ci]
                 zone = (st.zone_names[int(row_zone[si])]
                         if st.zone_names else "")
-                node = SimNode(
-                    instance_type=type_name,
-                    provisioner=prov_name,
+                node = SimNode.bought(
+                    st.provisioners[int(st.cand_prov[ci])],
+                    instance_type=st.cand_names[ci][1],
                     zone=zone,
                     capacity_type=self._ct_of_dom(st, int(row_dom[si])),
                     price=float(row_price[si]),
@@ -2481,9 +2533,7 @@ class TpuSolver:
                         st.vocab.resources[r]: float(st.cand_alloc[ci, r])
                         for r in range(st.cand_alloc.shape[1])
                     },
-                    existing=False,
                 )
-                node.stamp_labels()
                 new_nodes.append(node)
                 slot_to_node[si] = node
 

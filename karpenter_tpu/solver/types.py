@@ -10,6 +10,7 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 from ..models import labels as L
 from ..models.instancetype import InstanceType
 from ..models.pod import PodSpec, Taint
+from ..models.provisioner import Provisioner
 from ..models.resources import ResourceList, add, fits, subtract
 
 _node_lock = threading.Lock()
@@ -86,21 +87,52 @@ class SimNode:
         req.setdefault(L.RESOURCE_PODS, 1.0)
         return fits(req, self.remaining())
 
-    def stamp_labels(self) -> "SimNode":
-        """Stamp the node's own fields as labels (zone/capacity-type/type/
-        provisioner/hostname), mirroring what the oracle's _create_node and
-        real node objects carry — solver-built nodes must be judged by later
-        waves' label-compat checks the same way labeled cluster nodes are
-        (a label-less node reads as 'absent' for every selector)."""
-        for k, v in (
+    @classmethod
+    def bought(
+        cls,
+        prov: Provisioner,
+        *,
+        instance_type: str,
+        zone: str,
+        capacity_type: str,
+        price: float,
+        allocatable: ResourceList,
+        labels: Optional[Dict[str, str]] = None,
+        name: str = "",
+    ) -> "SimNode":
+        """A node a solver buys from ``prov``, the one constructor every
+        backend uses: it carries what the provisioner puts on each node it
+        launches — its taints and its labels, over ``labels`` — and its own
+        fields as labels.  A later solve that meets it as an existing node
+        (a session's next step, a consolidation what-if) judges tolerations
+        and selectors against these, so a tainted provisioner's node stays
+        closed to pods that do not tolerate it."""
+        node = cls(
+            instance_type=instance_type, provisioner=prov.name, zone=zone,
+            capacity_type=capacity_type, price=price, allocatable=allocatable,
+            labels={**(labels or {}), **prov.labels},
+            taints=list(prov.taints), name=name,
+        )
+        node.labels.update(node._own_labels())
+        return node
+
+    def _own_labels(self) -> List[Tuple[str, str]]:
+        return [(k, v) for k, v in (
             (L.ZONE, self.zone),
             (L.CAPACITY_TYPE, self.capacity_type),
             (L.INSTANCE_TYPE, self.instance_type),
             (L.PROVISIONER_NAME, self.provisioner),
             (L.HOSTNAME, self.name),
-        ):
-            if v:
-                self.labels.setdefault(k, v)
+        ) if v]
+
+    def stamp_labels(self) -> "SimNode":
+        """Stamp the node's own fields as labels (zone/capacity-type/type/
+        provisioner/hostname) where it has none of them, as a node decoded
+        off the wire needs — solver-built nodes must be judged by later
+        waves' label-compat checks the same way labeled cluster nodes are
+        (a label-less node reads as 'absent' for every selector)."""
+        for k, v in self._own_labels():
+            self.labels.setdefault(k, v)
         return self
 
     def snapshot(self) -> "SimNode":
