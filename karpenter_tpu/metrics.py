@@ -210,15 +210,16 @@ ALLOCATED_BLOCKS = "karpenter_process_allocated_blocks"
 REQUEST_DECODE_PODS = "karpenter_solver_request_decode_pods_total"
 #: how a pod of a request became a PodSpec (KT003 zero-init source):
 #: 'templated' (stamped from the first pod of its shape) vs 'plain' (built
-#: field by field: the first pod of each shape, a pod whose bytes give no
-#: shape key, and every pod after the table gave up on its request)
+#: field by field: the first pod of each shape, whether the request sent it
+#: by shape or not, and on the old wire a pod whose bytes give no shape key
+#: and every pod after the table gave up on its request)
 REQUEST_DECODE_HOW = ("templated", "plain")
 # ---- the client's side of the same wire (service/codec.py PodShapes) ----
 REQUEST_ENCODE_PODS = "karpenter_solver_request_encode_pods_total"
 #: how a pod of a request became a pb.Pod (KT003 zero-init source):
-#: 'templated' (its name plus the bytes of the first pod of its shape) vs
-#: 'plain' (built field by field: the first pod of each shape, a pod whose
-#: values give no shape key, and every pod after the table gave up)
+#: 'templated' (its name plus the shape of an earlier pod) vs 'plain'
+#: (built field by field: the first pod of each shape, a pod whose values
+#: give no shape key, and every pod after the table gave up)
 REQUEST_ENCODE_HOW = ("templated", "plain")
 # ---- the catalog by name (service/server.py SolverService.Solve) ---------
 REQUEST_CATALOG = "karpenter_solver_request_catalog_total"
@@ -649,23 +650,31 @@ INVENTORY = {
         "counter", ("how",),
         "Pods the sidecar decoded off Solve requests (pending pods, "
         "daemonsets and the pods of existing nodes), by how: 'templated' "
-        "— the pod's bytes after its name equal an earlier pod's of the "
-        "same request, so its PodSpec was stamped from that pod's field "
-        "values (own name, next uid, containers shared); 'plain' — built "
-        "field by field by decode_pod: the first pod of each shape, a pod "
-        "whose bytes give no shape key, and every pod after the request's "
-        "table found fewer than half hits in its first 512 pods and gave "
-        "up.  Pods of one deployment differ in name only, so a healthy "
-        "provisioning batch reads almost all 'templated' (50,000 pods in "
-        "20 deployments: 49,980).  No table outlives its request."),
+        "— its PodSpec was stamped from the field values of the first pod "
+        "of its shape (own name, next uid, containers shared): a pod the "
+        "request carries by shape (pod_shapes / pod_names / pod_shape) "
+        "after the first of its shape, or on the old wire (one pb.Pod a "
+        "pod: `pods`, the pods of existing nodes, daemonsets) a pod whose "
+        "bytes after its name equal an earlier pod's of the same request; "
+        "'plain' — built field by field by decode_pod: the first pod of "
+        "each shape, and on the old wire a pod whose bytes give no shape "
+        "key and every pod after the request's table found fewer than "
+        "half hits in its first 512 pods and gave up.  Pods of one "
+        "deployment differ in name only, so a healthy provisioning batch "
+        "reads almost all 'templated' (50,000 pods in 20 deployments: "
+        "49,980), and a batch of all-distinct pods none.  No table "
+        "outlives its request."),
     REQUEST_ENCODE_PODS: (
         "counter", ("how",),
         "Pods a RemoteScheduler encoded onto Solve requests (pending pods, "
         "daemonsets and the pods of existing nodes), on the CLIENT's "
         "registry, by how: 'templated' — every field encode_pod reads but "
         "the name equals an earlier pod's of the same request, so the pod "
-        "went out as its name plus that pod's serialized bytes; 'plain' — "
-        "built field by field by encode_pod: the first pod of each shape, "
+        "went out as its name and the index of that pod's shape (the "
+        "request's own pods, by shape) or as a copy of that shape's "
+        "message with its own name (the pods of existing nodes, "
+        "daemonsets); 'plain' — built field by field by encode_pod, and "
+        "sent as a shape of its own: the first pod of each shape, "
         "a pod whose values give no shape key, and every pod after the "
         "request's table found fewer than half hits in its first 512 pods "
         "and gave up.  The mirror of "

@@ -131,6 +131,9 @@ class SolverClient:
         self._faults = faults_mod.plane()
         self._registry = registry or default_registry
         faults_mod.zero_init_recovery(self._registry)
+        #: what the last reply said (``SolveResponse.pods_by_shape``): the
+        #: sidecar behind this channel reads a request's pods by shape
+        self.pods_by_shape = False
         self._connect()
 
     def _connect(self) -> None:
@@ -170,6 +173,31 @@ class SolverClient:
 
     def solve_raw(self, request: pb.SolveRequest,
                   timeout: Optional[float] = None) -> pb.SolveResponse:
+        """One Solve RPC (:meth:`_send`) on the wire the sidecar reads.
+
+        A request's pods by shape (``SolveRequest.pod_shapes``) go as such
+        only while the sidecar's last reply said it reads them; until the
+        first reply, and to a sidecar older than those fields, they go one
+        message a pod (:func:`codec.pods_one_by_one`), which every sidecar
+        reads.  A reply that says otherwise to a request sent by shape
+        comes from such a sidecar (one rolled back under this client,
+        another replica behind the address): it solved none of the pods,
+        and the request goes once more, one message a pod.  A delta step
+        sent twice meets an epoch its first send moved on, so the session
+        re-establishes by one full solve and never diverges."""
+        if request.pod_names and not self.pods_by_shape:
+            codec.pods_one_by_one(request)
+        resp = self._send(request, timeout)
+        if request.pod_names and not resp.pods_by_shape:
+            logger.warning("solver %s reads no pods by shape; sending them "
+                           "one message a pod", self.target)
+            codec.pods_one_by_one(request)
+            resp = self._send(request, timeout)
+        self.pods_by_shape = resp.pods_by_shape
+        return resp
+
+    def _send(self, request: pb.SolveRequest,
+              timeout: Optional[float]) -> pb.SolveResponse:
         """One Solve RPC with restart ride-through (ISSUE 12 satellite):
         transport UNAVAILABLE — the exact shape of a replica restart —
         retries ONCE per budget unit (KT_RPC_RETRIES, default 1) after a
@@ -442,7 +470,8 @@ class FleetClient:
         compile buckets and tensorize-cache shapes, so routing repeat
         shapes to one replica rides its warm programs; a proxy collision
         merely shares a replica, never a wrong result."""
-        n_pods = len(getattr(request, "pods", ()) or ())
+        n_pods = (len(getattr(request, "pods", ()) or ())
+                  + len(getattr(request, "pod_names", ()) or ()))
         n_types = len(getattr(request, "instance_types", ()) or ())
         n_provs = len(getattr(request, "provisioners", ()) or ())
         g = 1 << (n_pods - 1).bit_length() if n_pods > 0 else 0

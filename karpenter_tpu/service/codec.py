@@ -76,30 +76,6 @@ def encode_pod(p: PodSpec) -> pb.Pod:
     return out
 
 
-#: the one-byte varints (a pod's name is nearly always under 128 bytes)
-_VARINT1 = tuple(bytes((n,)) for n in range(0x80))
-
-
-def _varint(n: int) -> bytes:
-    if n < 0x80:
-        return _VARINT1[n]
-    out = bytearray()
-    while n > 0x7F:
-        out.append((n & 0x7F) | 0x80)
-        n >>= 7
-    out.append(n)
-    return bytes(out)
-
-
-def _name_field(name: str) -> bytes:
-    """``pb.Pod.name`` on the wire: field 1, length-delimited — and nothing
-    at all for an empty name (proto3 leaves a default off the wire)."""
-    if not name:
-        return b""
-    raw = name.encode()
-    return b"\n" + _varint(len(raw)) + raw
-
-
 def _shape_key(p: PodSpec) -> tuple:
     """The value of every field :func:`encode_pod` reads except ``name``,
     as far apart as the wire holds them: a dict in its own order (a map's
@@ -118,34 +94,46 @@ def _shape_key(p: PodSpec) -> tuple:
     )
 
 
+def _nameless(p: PodSpec) -> pb.Pod:
+    """:func:`encode_pod` of ``p`` with the name left off: its shape."""
+    out = encode_pod(p)
+    out.name = ""
+    return out
+
+
 class PodShapes:
     """The pod shapes of ONE request on its way out: a ``pb.Pod`` is built
-    once per distinct shape, not once per pod — the client's half of what
-    :class:`PodTemplates` does at the sidecar's door.
+    once per distinct :func:`_shape_key`, not once per pod — the client's
+    half of what :class:`PodTemplates` does at the sidecar's door.
 
-    The first pod of a :func:`_shape_key` goes through :func:`encode_pod`,
-    which stays the only code that turns a ``PodSpec``'s fields into a
-    ``pb.Pod``'s; its serialized bytes after the leading ``name`` field are
-    the key's template, and every later pod of the key is its own name
-    field plus those bytes.  The pods of one repeated field are parsed
-    into their message in one merge, in the order given: the message is
-    equal to the one ``encode_pod`` per pod builds, and each pod's bytes
-    still start with its name, which is what ``PodTemplates._key`` reads.
+    The request's own pods go out by shape (:meth:`index`): every shape
+    once in ``pod_shapes``, nameless, and every pod as its name in
+    ``pod_names`` beside its shape's index in ``pod_shape``, in the
+    caller's order.  The pods of ``existing_nodes`` and ``daemonsets`` stay
+    one ``pb.Pod`` each (:meth:`extend`): a copy of their shape's message
+    with their own name.  Either way :func:`encode_pod` stays the only code
+    that turns a ``PodSpec``'s fields into a ``pb.Pod``'s.
 
     The table watches its own hit share as ``PodTemplates`` does: fewer
-    than half hits ``PROBE`` pods into the request and the rest encode
-    plainly.  One table serves one request — ``pods``, the pods of
-    ``existing_nodes``, ``daemonsets`` — and is dropped with it; nothing is
-    kept between requests and nothing is memoised on a pod."""
+    than half hits ``PROBE`` pods into the request and every later pod is a
+    shape of its own, so a request of all-distinct pods costs what the
+    plain build costs, and one index a pod.  One table serves one request —
+    ``pods``, the pods of ``existing_nodes``, ``daemonsets`` — and is
+    dropped with it; nothing is kept between requests and nothing is
+    memoised on a pod."""
 
     #: pods a request encodes before the table judges its hit share
     PROBE = 512
 
     def __init__(self) -> None:
-        self._tails: Dict[tuple, bytes] = {}
+        self._index: Dict[tuple, int] = {}
+        #: every shape met, nameless, in the order met: what ``pod_shape``
+        #: indexes
+        self._built: List[pb.Pod] = []
         self._seen = 0
         self._live = True
-        #: pods written from a template / pods that went through encode_pod
+        #: pods written from a shape met before / pods that went through
+        #: encode_pod
         self.templated_pods = 0
         self.plain_pods = 0
 
@@ -153,48 +141,75 @@ class PodShapes:
     def shapes(self) -> int:
         """Distinct shapes the table holds (it stops adding to them once
         it has given up on the request)."""
-        return len(self._tails)
+        return len(self._index)
+
+    def _shape_of(self, pods: Sequence[PodSpec]) -> List[int]:
+        """Each pod's index into the shapes, in order; a shape not met
+        before is built and added."""
+        index, built, key_of = self._index, self._built, _shape_key
+        seen, hits = self._seen, 0
+        at: List[int] = []
+        it = iter(pods)
+        if self._live:
+            for p in it:
+                try:
+                    key = key_of(p)
+                    i = index.get(key)
+                except TypeError:
+                    # a value that does not hash: a shape of its own
+                    key = i = None
+                if i is None:
+                    i = len(built)
+                    built.append(_nameless(p))
+                    if key is not None:
+                        index[key] = i
+                else:
+                    hits += 1
+                at.append(i)
+                seen += 1
+                if seen == self.PROBE and 2 * (self.templated_pods + hits) < seen:
+                    self._live = False
+                    break
+            self._seen = seen
+        for p in it:
+            at.append(len(built))
+            built.append(_nameless(p))
+        self.templated_pods += hits
+        self.plain_pods += len(at) - hits
+        return at
+
+    def index(self, req: pb.SolveRequest, pods: Sequence[PodSpec]) -> None:
+        """The pods of ``req``, by shape: what ``req.pods.extend(encode_pod(p)
+        for p in pods)`` would carry, in ``pod_shapes`` / ``pod_names`` /
+        ``pod_shape``."""
+        at = self._shape_of(pods)
+        req.pod_shapes.extend(self._built)
+        req.pod_names.extend([p.name for p in pods])
+        req.pod_shape.extend(at)
 
     def extend(self, msg, field: str, pods: Sequence[PodSpec]) -> None:
         """``getattr(msg, field).extend(encode_pod(p) for p in pods)``,
-        by template."""
-        self.plain_pods += len(pods)
-        if not self._live:
-            getattr(msg, field).extend(encode_pod(p) for p in pods)
-            return
-        # every pod is one length-delimited (wire type 2) entry of `field`
-        tag = _varint(msg.DESCRIPTOR.fields_by_name[field].number << 3 | 2)
-        tails, key_of, head_of, varint = (
-            self._tails, _shape_key, _name_field, _varint)
-        seen, hits = self._seen, 0
-        parts: List[bytes] = []
-        it = iter(pods)
-        for p in it:
-            try:
-                key = key_of(p)
-                tail = tails.get(key)
-                head = head_of(p.name)
-            except (TypeError, AttributeError, UnicodeError):
-                # a value that does not hash, a name that is no str: the
-                # plain build says what the wire makes of them
-                key = tail = None
-            if tail is not None:
-                parts += (tag, varint(len(head) + len(tail)), head, tail)
-                hits += 1
-            else:
-                data = encode_pod(p).SerializeToString()
-                parts += (tag, varint(len(data)), data)
-                if key is not None and data.startswith(head):
-                    tails[key] = data[len(head):]
-            seen += 1
-            if seen == self.PROBE and 2 * (self.templated_pods + hits) < seen:
-                self._live = False
-                break
-        self._seen = seen
-        self.templated_pods += hits
-        self.plain_pods -= hits
-        msg.MergeFromString(b"".join(parts))
-        getattr(msg, field).extend(encode_pod(q) for q in it)
+        one copy of a shape's message a pod."""
+        out, built = getattr(msg, field), self._built
+        for p, i in zip(pods, self._shape_of(pods)):
+            pod = out.add()
+            pod.CopyFrom(built[i])
+            pod.name = p.name
+
+
+def pods_one_by_one(req: pb.SolveRequest) -> None:
+    """Moves ``req``'s pods by shape onto ``pods``, one message a pod, in
+    the order the sidecar decodes them: the wire of a sidecar older than
+    ``pod_shapes``, which every sidecar reads."""
+    shapes, rest = req.pod_shapes, list(req.pods)
+    req.ClearField("pods")
+    for name, i in zip(req.pod_names, req.pod_shape):
+        pod = req.pods.add()
+        pod.CopyFrom(shapes[i])
+        pod.name = name
+    req.pods.extend(rest)
+    for field in ("pod_shapes", "pod_names", "pod_shape"):
+        req.ClearField(field)
 
 
 def encode_instance_type(it: InstanceType) -> pb.InstanceType:
@@ -290,8 +305,8 @@ def encode_request(
     # default class / deadline, so an old client is indistinguishable from
     # one that sent nothing.  The delta-session fields (ARCHITECTURE.md
     # round 14) default the same way: an empty session_id is a classic
-    # full solve; delta=True reuses `pods` for the ADDED pods and
-    # `unavailable` for the newly ICE'd offerings.  The trace context
+    # full solve; delta=True reuses the request's pods for the ADDED pods
+    # and `unavailable` for the newly ICE'd offerings.  The trace context
     # (ISSUE 15) defaults to "no context": the server roots locally.
     req = pb.SolveRequest(allow_new_nodes=allow_new_nodes, backend=backend,
                           priority_class=priority or "",
@@ -308,7 +323,7 @@ def encode_request(
     req.reclaimed_nodes.extend(reclaimed_nodes)
     if shapes is None:
         shapes = PodShapes()
-    shapes.extend(req, "pods", pods)
+    shapes.index(req, pods)
     req.provisioners.extend(encode_provisioner(p) for p in provisioners)
     if not catalog_digest:
         req.instance_types.extend(
@@ -405,25 +420,33 @@ def decode_pod(p: pb.Pod) -> PodSpec:
     )
 
 
+class MalformedRequest(ValueError):
+    """A request whose pods by shape do not add up: ``pod_names`` and
+    ``pod_shape`` of unequal length, or an index past ``pod_shapes``."""
+
+
 class PodTemplates:
     """The pod shapes of ONE request: a ``PodSpec`` is built once per
     distinct shape, not once per pod.
 
     A Solve carries the pods of a few deployments (20 in a 50,000-pod
-    batch), and pods of one deployment differ in ``name`` only.  A pod's
-    shape key is its own serialized bytes after the leading ``name`` field:
-    equal keys mean equal bytes, so equal fields.  The first pod of a key
-    goes through :func:`decode_pod`; every later one is stamped from that
-    pod's field values (``PodSpec.like``) with its own name and the next
-    uid, and SHARES the first one's containers — the copy-on-write
-    convention stated at ``PodSpec``.  A key that differs for two equal
-    pods (map order) costs a plain decode, never a wrong answer.
+    batch), and pods of one deployment differ in ``name`` only.  A client
+    that says so sends each shape once (:meth:`stamp`).  On the old wire,
+    one ``pb.Pod`` a pod (:meth:`decode`), a pod's shape key is its own
+    serialized bytes after the leading ``name`` field: equal keys mean
+    equal bytes, so equal fields.  Either way the first pod of a shape goes
+    through :func:`decode_pod`; every later one is stamped from that pod's
+    field values (``PodSpec.like``) with its own name and the next uid, and
+    SHARES the first one's containers — the copy-on-write convention stated
+    at ``PodSpec``.  On the old wire a key that differs for two equal pods
+    (map order) costs a plain decode, never a wrong answer.
 
-    The table watches its own hit share: if, ``PROBE`` pods into the
-    request, fewer than half were hits, the rest decode plainly (a batch
-    of all-distinct pods would only pay for the keys).  One table serves
-    one request — ``pods``, the pods of ``existing_nodes``, ``daemonsets``
-    — and is dropped with it; nothing is kept between requests."""
+    On the old wire the table watches its own hit share: if, ``PROBE`` pods
+    into the request, fewer than half were hits, the rest decode plainly (a
+    batch of all-distinct pods would only pay for the keys).  One table
+    serves one request — its pods by shape, ``pods``, the pods of
+    ``existing_nodes``, ``daemonsets`` — and is dropped with it; nothing is
+    kept between requests."""
 
     #: pods a request decodes before the table judges its hit share
     PROBE = 512
@@ -431,16 +454,54 @@ class PodTemplates:
     def __init__(self) -> None:
         self._fields: Dict[bytes, dict] = {}
         self._seen = 0
+        self._hits = 0
         self._live = True
+        #: shapes decoded by :meth:`stamp`
+        self._stamped = 0
         #: pods stamped from a template / pods that went through decode_pod
+        #: (the first pod of each shape, by shape or not)
         self.templated_pods = 0
         self.plain_pods = 0
 
     @property
     def templates(self) -> int:
-        """Distinct shapes the table holds (it stops adding to them once
-        it has given up on the request)."""
-        return len(self._fields)
+        """Distinct shapes the table holds (on the old wire it stops adding
+        to them once it has given up on the request)."""
+        return len(self._fields) + self._stamped
+
+    def stamp(self, shapes, names, index) -> List[PodSpec]:
+        """The pods of ``pod_shapes`` / ``pod_names`` / ``pod_shape``, in
+        order: ``[decode_pod(p) for p in pods]`` over the plain wire, field
+        for field and uid for uid.  A shape is decoded when its first pod
+        comes; an empty name takes the constructor's rule (``pod-<uid>``)."""
+        if len(names) != len(index):
+            raise MalformedRequest(
+                f"{len(names)} pod_names against {len(index)} pod_shape")
+        fields: List[Optional[dict]] = [None] * len(shapes)
+        like = PodSpec.like
+        out: List[PodSpec] = []
+        decoded = 0
+        for name, i in zip(names, index):
+            try:
+                shape = fields[i]
+            except IndexError:
+                raise MalformedRequest(
+                    f"pod_shape {i} past the {len(shapes)} pod_shapes"
+                ) from None
+            if shape is None:
+                pod = decode_pod(shapes[i])
+                pod.name = name or f"pod-{pod.uid}"
+                fields[i] = pod.template()
+                decoded += 1
+            else:
+                pod = like(shape, name)
+                if not name:
+                    pod.name = f"pod-{pod.uid}"
+            out.append(pod)
+        self._stamped += decoded
+        self.templated_pods += len(out) - decoded
+        self.plain_pods += decoded
+        return out
 
     @staticmethod
     def _key(p: pb.Pod, name: str) -> Optional[bytes]:
@@ -486,11 +547,12 @@ class PodTemplates:
                     fields[key] = pod.template()
                 out.append(pod)
             seen += 1
-            if seen == self.PROBE and 2 * (self.templated_pods + hits) < seen:
+            if seen == self.PROBE and 2 * (self._hits + hits) < seen:
                 self._live = False
                 out.extend(decode_pod(q) for q in it)
                 break
         self._seen = seen
+        self._hits += hits
         self.templated_pods += hits
         self.plain_pods += len(out) - hits
         return out
@@ -589,8 +651,11 @@ def decode_request(req: pb.SolveRequest,
     request left out."""
     if templates is None:
         templates = PodTemplates()
+    # the pods by shape, in the client's order, then an old client's
+    pods = templates.stamp(req.pod_shapes, req.pod_names, req.pod_shape)
+    pods += templates.decode(req.pods)
     return dict(
-        pods=templates.decode(req.pods),
+        pods=pods,
         provisioners=[decode_provisioner(p) for p in req.provisioners],
         instance_types=(catalog if catalog is not None else
                         [decode_instance_type(t) for t in req.instance_types]),

@@ -1743,8 +1743,13 @@ class SolverService:
                     context.abort(grpc.StatusCode.FAILED_PRECONDITION, msg)
             # one table of pod shapes per request, dropped with it
             shapes = codec.PodTemplates()
-            kwargs = codec.decode_request(
-                request, shapes, None if held is None else list(held))
+            try:
+                kwargs = codec.decode_request(
+                    request, shapes, None if held is None else list(held))
+            except codec.MalformedRequest as err:
+                if context is None:
+                    raise
+                context.abort(grpc.StatusCode.INVALID_ARGUMENT, str(err))
             if held is None:
                 # a list on the request wins over a digest beside it; this
                 # sidecar names what it decoded and keeps it under the name
@@ -1858,6 +1863,9 @@ class SolverService:
                     # this on their "remote" span, and offline dump
                     # correlation keys on it
                     resp.replica_id = self.tracer.replica
+                    # this sidecar reads a request's pods by shape: the
+                    # client may send them so from now on
+                    resp.pods_by_shape = True
             slo_outcome = "ok"
             # door to door on the tracer's clock: start of request_decode
             # to the close of the root.  (result.solve_ms is the host

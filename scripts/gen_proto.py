@@ -31,8 +31,9 @@ from google.protobuf import descriptor_pb2  # noqa: E402
 OUT = ROOT / "karpenter_tpu" / "service" / "solver_pb2.py"
 
 F = descriptor_pb2.FieldDescriptorProto
-#: message -> [(number, name, type, label)] — the schema deltas this repo
-#: has accrued past the original solver.proto; append here and re-run.
+#: message -> [(number, name, type, label[, type_name])] — the schema
+#: deltas this repo has accrued past the original solver.proto; append here
+#: and re-run.  A message field names its type fully (".karpenter.tpu.Pod").
 NEW_FIELDS = {
     # delta serving (ISSUE 10): session identity + the perturbation payload.
     # A delta request reuses `pods` for the ADDED pods and `unavailable` for
@@ -62,6 +63,15 @@ NEW_FIELDS = {
         # (old clients) decodes in full; an old sidecar never hands a
         # digest out (SolveResponse 11), so it is never sent one.
         (22, "catalog_digest", F.TYPE_STRING, F.LABEL_OPTIONAL),
+        # the pods by shape: each distinct pod once, nameless, and a name
+        # a pod with its index into them.  An old sidecar skips the tags,
+        # so a client sends them only to a sidecar whose last reply said
+        # it reads them (SolveResponse 12); an old client's `pods` still
+        # decode as before.
+        (23, "pod_shapes", F.TYPE_MESSAGE, F.LABEL_REPEATED,
+         ".karpenter.tpu.Pod"),
+        (24, "pod_names", F.TYPE_STRING, F.LABEL_REPEATED),
+        (25, "pod_shape", F.TYPE_UINT32, F.LABEL_REPEATED),
     ],
     # session ack + delta-shaped responses: `assignments`/`nodes` carry only
     # the step's changes when `delta_mode` is an incremental tier;
@@ -80,6 +90,9 @@ NEW_FIELDS = {
         # the sidecar's name for the catalog it solved on (ISSUE 33, see
         # SolveRequest 22)
         (11, "catalog_digest", F.TYPE_STRING, F.LABEL_OPTIONAL),
+        # true from a sidecar that reads a request's pods by shape
+        # (SolveRequest 23-25); false from an old one, which reads `pods`
+        (12, "pods_by_shape", F.TYPE_BOOL, F.LABEL_OPTIONAL),
     ],
     # gang scheduling (ISSUE 20, docs/GANGS.md): members of one gang share
     # a gang_id and declare the gang's total size.  Old bytes decode to
@@ -98,7 +111,7 @@ def _apply(fdp: descriptor_pb2.FileDescriptorProto) -> int:
     for msg_name, fields in NEW_FIELDS.items():
         msg = by_name[msg_name]
         have = {f.number for f in msg.field}
-        for number, name, ftype, label in fields:
+        for number, name, ftype, label, *type_name in fields:
             if number in have:
                 continue
             fld = msg.field.add()
@@ -106,6 +119,8 @@ def _apply(fdp: descriptor_pb2.FileDescriptorProto) -> int:
             fld.number = number
             fld.type = ftype
             fld.label = label
+            if type_name:
+                fld.type_name, = type_name
             # json_name matches protoc's lowerCamelCase derivation
             parts = name.split("_")
             fld.json_name = parts[0] + "".join(p.title() for p in parts[1:])

@@ -158,7 +158,7 @@ def test_full_send_then_the_digest_alone_and_the_same_answer(
     digest = codec.catalog_digest(one.instance_types)
     assert remote._catalog_acked[1] == digest
     assert len(two.instance_types) == 0 and two.catalog_digest == digest
-    assert len(two.pods) == len(one.pods) == 100
+    assert len(two.pod_names) == len(one.pod_names) == 100
     assert two.ByteSize() < one.ByteSize() - sum(
         t.ByteSize() for t in one.instance_types)
     assert (sent("full"), sent("digest"), sent("resent")) == (1, 1, 0)

@@ -2,17 +2,17 @@
 ISSUE 30).
 
 The contract under test: ``encode_request`` builds one ``pb.Pod`` per
-distinct pod shape and writes the rest as their name plus that pod's bytes,
-and nobody can tell — the request equals the one ``encode_pod`` per pod
-builds, pod for pod and byte for byte, so the sidecar's ``PodTemplates``
-hits exactly as before; two pods that differ in anything ``encode_pod``
-reads but ``name`` never share a template; a request of all-distinct pods
-pays for a short probe only; nothing is remembered from one request to the
-next; ``decode_response`` seats the caller's own pods; and the client says
-how many pods it wrote from a template.
+distinct pod shape and sends the request's pods as those shapes plus a
+name and an index a pod, and nobody can tell — the pods it carries are,
+pod for pod and in order, the ones ``encode_pod`` per pod builds, so the
+sidecar decodes what it decoded from the plain build; the pods of existing
+nodes and daemonsets are still one ``pb.Pod`` each, equal to the plain
+build's; two pods that differ in anything ``encode_pod`` reads but
+``name`` never share a shape; a request of all-distinct pods pays for a
+short probe only; nothing is remembered from one request to the next;
+``decode_response`` seats the caller's own pods; and the client says how
+many pods it wrote from a shape.
 """
-
-import dataclasses
 
 import pytest
 from test_codec_templates import (
@@ -23,8 +23,12 @@ from test_codec_templates import (
     default_prov,
     deployments,
     distinct,
+    fields,
     fuzzed,
     node_with,
+    old_wire,
+    plain_node,
+    plain_pods,
     replicas,
 )
 
@@ -57,39 +61,41 @@ PROBE = codec.PodShapes.PROBE
 # ---- the reference: one encode_pod per pod, as the parent built it --------
 
 
-def plain_node(n) -> pb.ExistingNode:
-    bare = dataclasses.replace(n, pods=[])
-    bare.name = n.name
-    out = codec.encode_node(bare)
-    out.pods.extend(codec.encode_pod(p) for p in n.pods)
+def wire_pods(req) -> list:
+    return (plain_pods(req)
+            + [p for n in req.existing_nodes for p in n.pods]
+            + list(req.daemonsets))
+
+
+def envelope(req) -> pb.SolveRequest:
+    """``req`` without its pods, by shape or not."""
+    out = pb.SolveRequest()
+    out.CopyFrom(req)
+    for f in ("pods", "pod_shapes", "pod_names", "pod_shape"):
+        out.ClearField(f)
     return out
 
 
-def plain_request(pods, provisioners, instance_types, existing_nodes=(),
-                  daemonsets=(), **kw) -> pb.SolveRequest:
-    """What ``encode_request`` built before it knew pod shapes: the
-    envelope from the codec, every pod through ``encode_pod``."""
-    req = codec.encode_request([], provisioners, instance_types, **kw)
-    req.pods.extend(codec.encode_pod(p) for p in pods)
-    req.existing_nodes.extend(plain_node(n) for n in existing_nodes)
-    req.daemonsets.extend(codec.encode_pod(p) for p in daemonsets)
-    return req
-
-
-def wire_pods(req) -> list:
-    return (list(req.pods) + [p for n in req.existing_nodes for p in n.pods]
-            + list(req.daemonsets))
+def same_request(got, want) -> None:
+    """``got`` carries what ``want``, the plain build, carries: the same
+    envelope and the same pods, pod for pod, in order."""
+    assert not got.pods
+    assert envelope(got) == envelope(want)
+    assert [p.name for p in wire_pods(got)] == [
+        p.name for p in wire_pods(want)]
+    assert wire_pods(got) == wire_pods(want)
 
 
 def parsed(req):
     return type(req).FromString(req.SerializeToString())
 
 
-def door_counts(req) -> tuple:
-    """What the sidecar's table makes of ``req`` as it arrives."""
-    shapes = codec.PodTemplates()
-    codec.decode_request(parsed(req), shapes)
-    return shapes.templates, shapes.templated_pods, shapes.plain_pods
+def door_pods(req) -> list:
+    """What the sidecar decodes of ``req`` as it arrives, in its order:
+    the request's pods, the pods of its nodes, its daemonsets."""
+    got = codec.decode_request(parsed(req))
+    return (got["pods"] + [p for n in got["existing_nodes"] for p in n.pods]
+            + got["daemonsets"])
 
 
 # ---- (a) the request equals the plain build -------------------------------
@@ -124,20 +130,22 @@ def test_by_shape_equals_the_plain_build(build, small_catalog):
     kw = build(small_catalog)
     shapes = codec.PodShapes()
     got = codec.encode_request(**kw, shapes=shapes)
-    want = plain_request(**kw)
-    assert got == want
-    # pod for pod, in the same order, and byte for byte
-    got_pods, want_pods = wire_pods(got), wire_pods(want)
-    assert [p.name for p in got_pods] == [p.name for p in want_pods]
-    for a, b in zip(got_pods, want_pods):
-        assert a.SerializeToString() == b.SerializeToString()
-        assert a.SerializeToString().startswith(codec._name_field(a.name))
-    assert got.SerializeToString() == want.SerializeToString()
-    assert shapes.templated_pods + shapes.plain_pods == len(want_pods)
+    want = old_wire(**kw)
+    same_request(got, want)
+    # every shape once, nameless, and one name and index a pod
+    assert len(got.pod_names) == len(got.pod_shape) == len(kw["pods"])
+    assert all(not s.name for s in got.pod_shapes)
+    assert len(got.pod_shapes) == len(set(got.pod_shape)) <= shapes.plain_pods
+    assert shapes.templated_pods + shapes.plain_pods == len(wire_pods(want))
     assert shapes.templated_pods > 0
     assert shapes.shapes <= shapes.plain_pods
-    # the sidecar saw the same request
-    assert door_counts(got) == door_counts(want)
+    # the sidecar decodes the same pods, field for field, uid for uid
+    got_pods, want_pods = door_pods(got), door_pods(want)
+    assert [fields(p) for p in got_pods] == [fields(p) for p in want_pods]
+    uids = [p.uid for p in got_pods]
+    assert uids == list(range(uids[0], uids[0] + len(uids)))
+    # and the request's pods cross in fewer bytes
+    assert len(got.SerializeToString()) < len(want.SerializeToString())
 
 
 def test_a_warm_request_and_a_lone_node_encode_by_shape_too():
@@ -150,7 +158,7 @@ def test_a_warm_request_and_a_lone_node_encode_by_shape_too():
     want.daemonsets.extend(codec.encode_pod(p) for p in ds)
     want.existing_nodes.extend(plain_node(n) for n in nodes)
     assert got == want
-    assert got.SerializeToString() == want.SerializeToString()
+    assert parsed(got) == want
     assert codec.encode_node(nodes[1]) == plain_node(nodes[1])
     # a node is everything encode_node wrote before, and its pods
     assert codec.encode_node(nodes[0]).labels == nodes[0].labels
@@ -238,28 +246,35 @@ def test_pods_one_field_apart_never_share_a_shape(field, change):
     b.ClearField(field)
     a.ClearField(field)
     assert a == b
+    want = [codec.encode_pod(p) for p in pods]
     shapes = codec.PodShapes()
-    got = pb.SolveRequest()
-    shapes.extend(got, "pods", pods)
-    assert list(got.pods) == [codec.encode_pod(p) for p in pods]
+    got = codec.encode_request(pods, [], [], shapes=shapes)
+    assert plain_pods(got) == want
+    assert list(got.pod_shape) == [0, 1, 0]
     assert shapes.shapes == 2
     assert (shapes.templated_pods, shapes.plain_pods) == (1, 2)
+    # and the same table writes one pb.Pod a pod onto a node
+    node = pb.ExistingNode()
+    shapes.extend(node, "pods", pods)
+    assert list(node.pods) == want
+    assert (shapes.templated_pods, shapes.plain_pods) == (4, 2)
 
 
-def test_labels_in_another_order_keep_their_own_bytes():
-    """A map's bytes follow its order; the messages are equal either way.
-    The key keeps the two apart, so each pod's bytes are its own build's."""
+def test_labels_in_another_order_are_shapes_of_their_own():
+    """A map's bytes follow its order, so the key keeps the two apart; the
+    messages are equal either way, and each pod carries its own labels."""
     pods = [base_pod(name="a-0"),
             base_pod(name="b-0", labels={"tier": "web", "app": "x"}),
             base_pod(name="a-1")]
     shapes = codec.PodShapes()
-    got = pb.SolveRequest()
-    shapes.extend(got, "pods", pods)
+    got = codec.encode_request(pods, [], [], shapes=shapes)
     want = [codec.encode_pod(p) for p in pods]
-    assert list(got.pods) == want
-    assert ([p.SerializeToString() for p in got.pods]
-            == [p.SerializeToString() for p in want])
+    assert plain_pods(got) == want
+    assert list(got.pod_shape) == [0, 1, 0]
+    assert len(got.pod_shapes) == 2
     assert (shapes.shapes, shapes.templated_pods) == (2, 1)
+    assert ([fields(p) for p in door_pods(got)]
+            == [fields(codec.decode_pod(p)) for p in want])
 
 
 def test_a_value_that_does_not_hash_encodes_plainly():
@@ -270,15 +285,16 @@ def test_a_value_that_does_not_hash_encodes_plainly():
     with pytest.raises(TypeError):
         hash(codec._shape_key(pods[0]))
     shapes = codec.PodShapes()
-    got = pb.SolveRequest()
-    shapes.extend(got, "pods", pods)
-    assert list(got.pods) == [codec.encode_pod(p) for p in pods]
+    got = codec.encode_request(pods, [], [], shapes=shapes)
+    assert plain_pods(got) == [codec.encode_pod(p) for p in pods]
+    # each pod a shape of its own
+    assert list(got.pod_shape) == [0, 1, 2]
     assert (shapes.shapes, shapes.templated_pods, shapes.plain_pods) == (
         0, 0, 3)
 
 
 @pytest.mark.parametrize("name", [
-    "",                   # proto3 leaves the field out
+    "",                   # the sidecar names the pod by its uid
     "p",
     "n" * 127,            # the longest one-byte length
     "n" * 128,            # the first two-byte varint
@@ -286,35 +302,37 @@ def test_a_value_that_does_not_hash_encodes_plainly():
     "n" * 16384,          # the first three-byte one
     "pod-é中",            # the length counts bytes, not characters
     "é" * 64,             # 128 bytes in 64 characters
-    b"raw",               # upb takes bytes for a string field: plainly
+    b"raw",               # upb takes bytes for a string field
 ], ids=lambda v: f"{len(v)}{'b' if isinstance(v, bytes) else 'ch'}")
 def test_a_name_of_any_length_encodes_right(name):
     pods = [base_pod(name=n or "unnamed") for n in ("first", name, name,
                                                     "last")]
     pods[1].name = pods[2].name = name
     shapes = codec.PodShapes()
-    got = pb.SolveRequest()
-    shapes.extend(got, "pods", pods)
+    got = codec.encode_request(pods, [], [], shapes=shapes)
     want = [codec.encode_pod(p) for p in pods]
-    assert list(got.pods) == want
-    assert ([p.SerializeToString() for p in got.pods]
-            == [p.SerializeToString() for p in want])
-    templated = 1 if isinstance(name, bytes) else 3
+    assert plain_pods(got) == want
+    assert list(got.pod_shape) == [0, 0, 0, 0]
     assert (shapes.shapes, shapes.templated_pods, shapes.plain_pods) == (
-        1, templated, 4 - templated)
-    # and the sidecar's key is cut from the same bytes as the parent's
-    key = codec.PodTemplates._key
-    assert ([key(p, p.name) for p in parsed(got).pods]
-            == [key(p, p.name) for p in want])
+        1, 3, 1)
+    # the sidecar's pods are the plain wire's, field for field, uid for uid
+    door = door_pods(got)
+    plain = [codec.decode_pod(p) for p in want]
+    if name:
+        assert [fields(p) for p in door] == [fields(p) for p in plain]
+    else:
+        assert [p.name for p in door] == [
+            "first", f"pod-{door[1].uid}", f"pod-{door[2].uid}", "last"]
+    assert [p.uid - door[0].uid for p in door] == [0, 1, 2, 3]
 
 
 def test_a_first_pod_without_a_name_is_a_template_too():
     pods = [base_pod(name=f"p-{i}") for i in range(3)]
     pods[0].name = ""
-    got = pb.SolveRequest()
-    codec.PodShapes().extend(got, "pods", pods)
-    assert list(got.pods) == [codec.encode_pod(p) for p in pods]
-    assert [p.name for p in got.pods] == ["", "p-1", "p-2"]
+    got = codec.encode_request(pods, [], [])
+    assert plain_pods(got) == [codec.encode_pod(p) for p in pods]
+    assert [p.name for p in plain_pods(got)] == ["", "p-1", "p-2"]
+    assert list(got.pod_shape) == [0, 0, 0]
 
 
 # ---- (c) all-distinct pods fall back to the plain build -------------------
@@ -340,16 +358,17 @@ def test_a_first_pod_without_a_name_is_a_template_too():
 def test_the_table_watches_its_own_hit_share(lead, tail, templated, n_shapes):
     pods = lead + tail
     shapes = codec.PodShapes()
-    got = pb.SolveRequest()
-    shapes.extend(got, "pods", pods)
+    got = codec.encode_request(pods, [], [], shapes=shapes)
     want = [codec.encode_pod(p) for p in pods]
-    assert list(got.pods) == want
+    assert plain_pods(got) == want
     assert shapes.templated_pods == templated
     assert shapes.plain_pods == len(pods) - templated
     assert shapes.shapes == n_shapes
-    # the probe is the door's own: both sides judge the same pods
+    # every pod built plainly is a shape on the wire, in the caller's order
+    assert len(got.pod_shapes) == len(pods) - templated
+    # the probe is the old wire's door's own: both judge the same pods
     door = codec.PodTemplates()
-    door.decode(parsed(got).pods)
+    door.decode(want)
     assert (door.templated_pods, door.templates) == (templated, n_shapes)
 
 
@@ -359,7 +378,7 @@ def test_a_table_that_gave_up_stays_plain_for_the_rest_of_its_request():
               daemonsets=replicas(5, "ds"))
     shapes = codec.PodShapes()
     got = codec.encode_request(**kw, shapes=shapes)
-    assert got == plain_request(**kw)
+    same_request(got, old_wire(**kw))
     assert (shapes.templated_pods, shapes.plain_pods) == (0, PROBE + 35)
     # no table outlives its request: the next one starts over
     again = codec.PodShapes()
@@ -395,9 +414,9 @@ def test_a_second_encode_sees_a_field_rebound_since_the_first(field, value):
                    for p in pods for k in vars(p))
     shapes = codec.PodShapes()
     second = codec.encode_request(pods, [], [], shapes=shapes)
-    assert second == plain_request(pods, [], [])
+    same_request(second, old_wire(pods, [], []))
     assert second != first
-    assert second.pods[2] == codec.encode_pod(pods[2])
+    assert plain_pods(second)[2] == codec.encode_pod(pods[2])
     assert (shapes.shapes, shapes.templated_pods) == (2, 2)
 
 
@@ -407,8 +426,8 @@ def test_a_container_written_in_place_between_two_encodes_shows_too():
     codec.encode_request(pods, [], [])
     pods[1].labels["tier"] = "api"
     again = codec.encode_request(pods, [], [])
-    assert again == plain_request(pods, [], [])
-    assert dict(again.pods[1].labels) == {"app": "x", "tier": "api"}
+    same_request(again, old_wire(pods, [], []))
+    assert dict(plain_pods(again)[1].labels) == {"app": "x", "tier": "api"}
 
 
 # ---- (e) decode_response seats the caller's pods --------------------------
@@ -521,7 +540,7 @@ def test_a_solve_over_grpc_returns_what_the_parent_returned(
         with tracer.start("provision") as trace:
             got = remote.solve(pods, provs, small_catalog, trace=trace)
         # the parent's client: plain request, stubs, then the re-attach loop
-        resp = remote.client.solve_raw(plain_request(
+        resp = remote.client.solve_raw(old_wire(
             pods, provs, small_catalog, backend="oracle"))
     finally:
         remote.close()

@@ -123,3 +123,41 @@ class TestCodecFuzz:
         assert back["pods"][0].requests == pods[0].requests
         assert back["pods"][-1].name == "p49999"
         assert len(back["instance_types"]) == len(full_catalog)
+
+    def test_50k_by_shape_roundtrip(self, full_catalog):
+        """The north-star batch crosses as its shapes plus names: each
+        distinct pod once, every pod back field for field and in order, as
+        ``decode_pod`` over one ``pb.Pod`` a pod gives it."""
+        rng = np.random.default_rng(1)
+        pods = [
+            PodSpec(name=f"p{i}",
+                    requests={"cpu": float(rng.choice([0.25, 0.5, 1.0, 2.0])),
+                              "memory": float(rng.choice([1, 2, 4])) * 2**30},
+                    owner_key=f"d{i % 20}")
+            for i in range(50_000)
+        ]
+        req = codec.encode_request(pods, [], full_catalog)
+        back = pb.SolveRequest.FromString(req.SerializeToString())
+        assert not back.pods and len(back.pod_names) == 50_000
+        assert len(back.pod_shapes) == len({
+            (p.owner_key, tuple(p.requests.items())) for p in pods})
+        plain = pb.SolveRequest()
+        plain.pods.extend(codec.encode_pod(p) for p in pods)
+        # the pods alone cross in under a quarter of the plain build's bytes
+        alone = pb.SolveRequest()
+        alone.CopyFrom(back)
+        alone.ClearField("instance_types")
+        alone.ClearField("provisioners")
+        assert 4 * len(alone.SerializeToString()) < len(
+            plain.SerializeToString())
+        got = codec.decode_request(back)["pods"]
+        want = [codec.decode_pod(p) for p in plain.pods]
+        assert [p.name for p in got] == [p.name for p in pods]
+        assert all(vars(a).keys() == vars(b).keys() for a, b in zip(got, want))
+        drop = ("uid",)
+        assert [{k: v for k, v in vars(a).items() if k not in drop}
+                for a in got] == [
+            {k: v for k, v in vars(b).items() if k not in drop}
+            for b in want]
+        uids = [p.uid for p in got]
+        assert uids == list(range(uids[0], uids[0] + 50_000))

@@ -2,9 +2,10 @@
 
 The contract under test: ``decode_request`` builds one ``PodSpec`` per
 distinct pod shape and stamps the rest from it, and nobody can tell — every
-pod equals what ``decode_pod`` gives, in the same order, with the same uids;
-two pods that differ in anything but ``name`` never share a template; pods
-that share field containers are never written through one another; a
+pod equals what ``decode_pod`` gives over the plain wire (one ``pb.Pod`` a
+pod), in the same order, with the same uids; two pods that differ in
+anything but ``name`` never share a template; pods that share field
+containers are never written through one another; on the old wire a
 request of all-distinct pods pays for a short probe only; and the door
 says how many pods it stamped.
 """
@@ -63,6 +64,39 @@ def on_the_wire(**kw) -> pb.SolveRequest:
     parsed."""
     return pb.SolveRequest.FromString(
         codec.encode_request(**kw).SerializeToString())
+
+
+def plain_node(n) -> pb.ExistingNode:
+    """``n`` on the wire, one ``encode_pod`` a pod."""
+    bare = dataclasses.replace(n, pods=[])
+    bare.name = n.name
+    out = codec.encode_node(bare)
+    out.pods.extend(codec.encode_pod(p) for p in n.pods)
+    return out
+
+
+def old_wire(pods, provisioners, instance_types, existing_nodes=(),
+             daemonsets=(), **kw) -> pb.SolveRequest:
+    """The request as a client without the pods by shape sends it, and as
+    the sidecar's handler sees it: one ``encode_pod`` a pod, the request's
+    own in ``pods``."""
+    req = codec.encode_request([], provisioners, instance_types, **kw)
+    req.pods.extend(codec.encode_pod(p) for p in pods)
+    req.existing_nodes.extend(plain_node(n) for n in existing_nodes)
+    req.daemonsets.extend(codec.encode_pod(p) for p in daemonsets)
+    return pb.SolveRequest.FromString(req.SerializeToString())
+
+
+def plain_pods(req) -> list:
+    """The request's own pods as the plain wire carries them: its pods by
+    shape, one ``pb.Pod`` a pod, then ``pods``."""
+    out = []
+    for name, i in zip(req.pod_names, req.pod_shape):
+        pod = pb.Pod()
+        pod.CopyFrom(req.pod_shapes[i])
+        pod.name = name
+        out.append(pod)
+    return out + list(req.pods)
 
 
 def default_prov():
@@ -127,7 +161,7 @@ def test_by_template_equals_decode_pod_field_for_field(build, small_catalog):
     got_pods = (got["pods"] + [p for n in got["existing_nodes"]
                                for p in n.pods] + got["daemonsets"])
     want_pods = [codec.decode_pod(p) for p in (
-        list(req.pods) + [p for n in req.existing_nodes for p in n.pods]
+        plain_pods(req) + [p for n in req.existing_nodes for p in n.pods]
         + list(req.daemonsets))]
     assert [p.name for p in got_pods] == [p.name for p in want_pods]
     for a, b in zip(got_pods, want_pods):
@@ -147,7 +181,7 @@ def test_by_template_equals_decode_pod_field_for_field(build, small_catalog):
 
 def test_nothing_memoised_on_a_handed_out_pod_leaks_through_the_table():
     pods = spread_deployments(1, 3, tag="memo")
-    req = on_the_wire(pods=pods, provisioners=[], instance_types=[])
+    req = old_wire(pods=pods, provisioners=[], instance_types=[])
     shapes = codec.PodTemplates()
     first, = shapes.decode(req.pods[:1])
     first.group_key()                      # memoised on the first pod
@@ -435,7 +469,7 @@ def test_the_table_watches_its_own_hit_share(lead, tail, templated,
 
 
 def test_a_table_that_gave_up_stays_plain_for_the_rest_of_its_request():
-    req = on_the_wire(
+    req = old_wire(
         pods=distinct(PROBE + 10), provisioners=[], instance_types=[],
         existing_nodes=[node_with(replicas(20, "on-node"))],
         daemonsets=replicas(5, "ds"))
@@ -448,7 +482,7 @@ def test_a_table_that_gave_up_stays_plain_for_the_rest_of_its_request():
     assert uids == list(range(uids[0], uids[0] + PROBE + 35))
     # no table outlives its request: the next one starts over
     again = codec.PodTemplates()
-    codec.decode_request(on_the_wire(
+    codec.decode_request(old_wire(
         pods=replicas(30), provisioners=[], instance_types=[]), again)
     assert (again.templates, again.templated_pods) == (1, 29)
 
@@ -481,30 +515,40 @@ def deployments(nd, per, tag):
             for d in range(nd) for i in range(per)]
 
 
-@pytest.mark.parametrize("pods,templates,templated", [
-    (deployments(5, 40, "dep"), 5, 195),
-    (deployments(1, 1, "lone"), 1, 0),
-    (distinct(PROBE + 88, "odd"), PROBE, 0),
+@pytest.mark.parametrize("pods,first,again", [
+    (deployments(5, 40, "dep"), (5, 195), (5, 195)),
+    (deployments(1, 1, "lone"), (1, 0), (1, 0)),
+    # the old wire's table gave up at its probe; by shape the client did,
+    # and every later pod is a shape of its own
+    (distinct(PROBE + 88, "odd"), (PROBE, 0), (PROBE + 88, 0)),
 ], ids=["5x40", "one_pod", "all_distinct"])
 def test_the_door_counts_what_it_stamped(served, small_catalog, pods,
-                                         templates, templated):
+                                         first, again):
+    """(templates, templated pods) at the door: the client's first request
+    goes one message a pod (it has not heard the sidecar yet), the next by
+    shape; the first pod of each shape is built plainly either way."""
     decoded = served["reg"].counter(REQUEST_DECODE_PODS)
-    before = {how: decoded.get({"how": how}) for how in REQUEST_DECODE_HOW}
     remote = RemoteScheduler(f"127.0.0.1:{served['port']}", backend="oracle",
                              registry=Registry())
     try:
-        res = remote.solve(pods, [default_prov()], small_catalog)
+        for want, catalog in ((first, "decoded"), (again, "held")):
+            templates, templated = want
+            before = {how: decoded.get({"how": how})
+                      for how in REQUEST_DECODE_HOW}
+            res = remote.solve(pods, [default_prov()], small_catalog)
+            assert not res.infeasible and len(res.assignments) == len(pods)
+            moved = {how: decoded.get({"how": how}) - before[how]
+                     for how in REQUEST_DECODE_HOW}
+            assert moved == {"templated": templated,
+                             "plain": len(pods) - templated}
+            tree = served["flight"].traces()[-1].to_dict()
+            door = {c["name"]: c
+                    for c in tree["spans"]}["request_decode"]["attrs"]
+            assert door == {"n_pods": len(pods), "templates": templates,
+                            "templated_pods": templated,
+                            "catalog": catalog}
     finally:
         remote.close()
-    assert not res.infeasible and len(res.assignments) == len(pods)
-    moved = {how: decoded.get({"how": how}) - before[how]
-             for how in REQUEST_DECODE_HOW}
-    assert moved == {"templated": templated,
-                     "plain": len(pods) - templated}
-    tree = served["flight"].traces()[-1].to_dict()
-    door = {c["name"]: c for c in tree["spans"]}["request_decode"]["attrs"]
-    assert door == {"n_pods": len(pods), "templates": templates,
-                    "templated_pods": templated, "catalog": "decoded"}
     text = served["reg"].expose()
     assert f'{REQUEST_DECODE_PODS}{{how="templated"}}' in text
     assert f'{REQUEST_DECODE_PODS}{{how="plain"}}' in text
